@@ -1,0 +1,259 @@
+"""chip_probe.py — the small on-chip measurements the defaults and notes quote.
+
+    python scripts/chip_probe.py [sync] [link] [upload] [prefix]
+
+With no section named it runs all four. One process, one chip, one JSON
+object per line, every reading on the host's clock around a
+``block_until_ready`` (on an attached chip that waits for completion):
+
+- ``sync``   one blocking host read of a device scalar — what
+             ``spark.rapids.sql.cost.deviceSyncFloorMs`` stands for;
+- ``link``   ``device_put`` and read-back bandwidth at 1, 16 and 256 MB;
+- ``upload`` the wire upload's shapes on a lineitem-like batch of 2^20
+             rows: the host pack copy, ``device_put`` of the encoded
+             arrays as they are, of the staging buffer's typed views (what
+             ``wire.upload_packed`` does) and of the staging buffer whole
+             (what crossed the link until PR 21); then eight tiny batches
+             through ``upload_packed`` one by one against
+             ``upload_packed_group`` (ROADMAP A2: do the pack and the
+             grouping still pay?);
+- ``prefix`` the grouped aggregate's prefix sums at 786,432 x 4, float64,
+             int64 and int32, each through ``aggregate._prefix_sums`` and
+             through the formulation it forks away from (first call with
+             compile, then the median of 20).
+
+Like ``chip_smoke.py`` it refuses any backend but a TPU unless
+``--cpu-rehearsal`` is given, which runs the control flow at a tiny size
+on the CPU and says so on every line: a CPU reading is not a device number.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import statistics
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+SECTIONS = ("sync", "link", "upload", "prefix")
+LABEL = {}
+
+
+def emit(section: str, **facts) -> None:
+    print(json.dumps({"probe": section, **LABEL, **facts}), flush=True)
+
+
+def quartiles(xs):
+    xs = sorted(xs)
+    return {"n": len(xs), "min": xs[0], "p25": xs[len(xs) // 4],
+            "median": statistics.median(xs), "p75": xs[(3 * len(xs)) // 4],
+            "max": xs[-1]}
+
+
+def timed(fn, n: int):
+    """Seconds of ``n`` calls of ``fn``, each waited for."""
+    import jax
+    out = []
+    for _ in range(n):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn())
+        out.append(time.perf_counter() - t0)
+    return out
+
+
+def probe_sync(jax, small: bool) -> None:
+    import jax.numpy as jnp
+    import numpy as np
+    f = jax.jit(lambda x: x + 1)
+    x = jnp.asarray(0, jnp.int32)
+    f(x).block_until_ready()
+    n = 20 if small else 300
+    ready, dispatch = [], []
+    for _ in range(n):
+        y = f(x)
+        y.block_until_ready()
+        t0 = time.perf_counter_ns()
+        np.asarray(y)
+        ready.append((time.perf_counter_ns() - t0) / 1e3)
+    for _ in range(n):
+        t0 = time.perf_counter_ns()
+        np.asarray(f(x))
+        dispatch.append((time.perf_counter_ns() - t0) / 1e3)
+    from spark_rapids_tpu import config as C
+    emit("sync", ready_scalar_read_us=quartiles(ready),
+         dispatch_and_read_us=quartiles(dispatch),
+         cost_deviceSyncFloorMs_default=C.COST_SYNC_FLOOR_MS.default)
+
+
+def probe_link(jax, small: bool) -> None:
+    import numpy as np
+    for mb in ((1, 2) if small else (1, 16, 256)):
+        host = np.random.default_rng(mb).integers(
+            0, 255, mb << 20, dtype=np.uint8)
+        dev = jax.device_put(host)
+        dev.block_until_ready()
+        n = 3 if small else (5 if mb == 256 else 20)
+        up = timed(lambda: jax.device_put(host), n)
+        down = []
+        for _ in range(n):
+            # A fresh device array each time: np.asarray caches the host
+            # copy on the array it read.
+            d = jax.device_put(host)
+            d.block_until_ready()
+            t0 = time.perf_counter()
+            np.asarray(d)
+            down.append(time.perf_counter() - t0)
+        gbps = lambda secs: [host.nbytes / s / 1e9 for s in secs]
+        emit("link", mb=mb, up_GBps=quartiles(gbps(up)),
+             down_GBps=quartiles(gbps(down)),
+             down_GBps_in_order=[round(g, 3) for g in gbps(down)])
+
+
+def _lineitem_like(rows: int, seed: int):
+    """A scan batch shaped like q1/q6's pruned lineitem: dictionary-coded
+    flags and small decimals, a date, a wide float, an id."""
+    import numpy as np
+    from spark_rapids_tpu.columnar import dtypes as dt
+    from spark_rapids_tpu.columnar.host import HostBatch, HostColumn
+    rng = np.random.default_rng(seed)
+    cols = [
+        ("l_orderkey", dt.INT64, np.sort(rng.integers(1, 6_000_000, rows))),
+        ("l_quantity", dt.FLOAT64,
+         rng.integers(1, 51, rows).astype(np.float64)),
+        ("l_extendedprice", dt.FLOAT64,
+         np.round(rng.uniform(900, 105_000, rows), 2)),
+        ("l_discount", dt.FLOAT64, rng.integers(0, 11, rows) / 100.0),
+        ("l_shipdate", dt.INT32,
+         rng.integers(8036, 10_562, rows).astype(np.int32)),
+        ("l_linenumber", dt.INT32,
+         rng.integers(1, 8, rows).astype(np.int32))]
+    valid = np.ones(rows, np.bool_)
+    return HostBatch(tuple(n for n, _, _ in cols),
+                     [HostColumn(t, v, valid) for _, t, v in cols])
+
+
+def probe_upload(jax, small: bool) -> None:
+    import numpy as np
+    from spark_rapids_tpu.columnar import wire
+    rows = 1 << (12 if small else 20)
+    n = 3 if small else 20
+    hb = _lineitem_like(rows, 0)
+    arrays, specs, nrows, cap = wire.encode_batch(hb)
+    arrays = [np.asarray(a) for a in arrays]
+    pack = []
+    for _ in range(n):
+        t0 = time.perf_counter()
+        enc = wire.pack_encoded(arrays, specs, nrows, cap)
+        pack.append(time.perf_counter() - t0)
+    views = wire._staged_views(enc)
+    nbytes = sum(a.nbytes for a in arrays)
+    shapes = {
+        "encoded_arrays_as_they_are": lambda: jax.device_put(arrays),
+        "staging_views__upload_packed": lambda: jax.device_put(views),
+        "staging_buffer_whole__until_pr21": lambda: jax.device_put(
+            enc.staging),
+    }
+    for fn in shapes.values():
+        jax.block_until_ready(fn())
+    ms = lambda secs: quartiles([s * 1e3 for s in secs])
+    emit("upload", rows=rows, wire_arrays=len(arrays),
+         array_bytes=sorted(a.nbytes for a in arrays),
+         encoded_bytes=nbytes, staging_bytes=int(enc.nbytes),
+         host_pack_copy_ms=ms(pack),
+         device_put_ms={k: ms(timed(fn, n)) for k, fn in shapes.items()})
+    wire.upload_packed(enc)                 # compile the decode program
+    emit("upload_and_decode", rows=rows,
+         upload_packed_ms=ms(timed(
+             lambda: wire.upload_packed(enc).columns[0].data, n)))
+
+    tiny_rows = 1 << (6 if small else 13)
+    tiny = [wire.pack_batch(_lineitem_like(tiny_rows, i + 1))
+            for i in range(8)]
+    last = lambda batches: [b.columns[0].data for b in batches]
+    solo = lambda: last([wire.upload_packed(e) for e in tiny])
+    grouped = lambda: last(wire.upload_packed_group(tiny))
+    jax.block_until_ready(solo())
+    jax.block_until_ready(grouped())
+    emit("upload_grouping", batches=len(tiny), rows_each=tiny_rows,
+         staging_bytes_each=sorted(int(e.nbytes) for e in tiny),
+         one_call_each_ms=ms(timed(solo, n)),
+         one_call_for_all_ms=ms(timed(grouped, n)))
+
+
+def probe_prefix(jax, small: bool) -> None:
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.ops.aggregate import (_prefix_sums,
+                                                _two_level_prefix_sums)
+    rows, k = (4096 if small else 786432), 4
+    rng = np.random.default_rng(0)
+    for dtn in ("float64", "int64", "int32"):
+        if dtn == "float64":
+            host = rng.uniform(0, 1e5, (rows, k))
+        else:
+            host = rng.integers(0, 1000, (rows, k)).astype(dtn)
+        M = jnp.asarray(host)
+        want = np.cumsum(host, axis=0)
+        # What the engine runs for this dtype, then what it forks away
+        # from: jnp.cumsum for floats, the two-level scan for ints.
+        if dtn == "float64":
+            other = ("jnp_cumsum", lambda m: jnp.cumsum(m, axis=0))
+        else:
+            other = ("two_level_assoc_scan", _two_level_prefix_sums)
+        for name, fn in (("engine__prefix_sums", _prefix_sums), other):
+            f = jax.jit(fn)
+            t0 = time.perf_counter()
+            out = f(M)
+            out.block_until_ready()
+            first = time.perf_counter() - t0
+            steady = timed(lambda: f(M), 3 if small else 20)
+            got = np.asarray(out)
+            if dtn == "float64":
+                err = float(np.max(np.abs(got - want) / want))
+            else:
+                err = int(np.max(np.abs(got - want)))
+            emit("prefix", dtype=dtn, shape=[rows, k], formulation=name,
+                 first_call_s=round(first, 3),
+                 steady_ms=quartiles([s * 1e3 for s in steady]),
+                 max_err_vs_numpy=err)
+
+
+PROBES = {"sync": probe_sync, "link": probe_link, "upload": probe_upload,
+          "prefix": probe_prefix}
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("sections", nargs="*", metavar="section",
+                    help=f"which of {', '.join(SECTIONS)} to run "
+                         f"(default: all)")
+    ap.add_argument("--cpu-rehearsal", action="store_true",
+                    help="control flow at a tiny size on the CPU backend")
+    args = ap.parse_args(argv)
+    unknown = set(args.sections) - set(SECTIONS)
+    if unknown:
+        ap.error(f"unknown section(s) {sorted(unknown)}")
+    if args.cpu_rehearsal:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        LABEL["cpu_rehearsal"] = True
+    import jax
+    d = jax.devices()[0]
+    want = "cpu" if args.cpu_rehearsal else "tpu"
+    if d.platform != want:
+        print(f"chip_probe: need a {want} backend, JAX found "
+              f"{d.platform} ({d.device_kind})", file=sys.stderr)
+        return 2
+    import spark_rapids_tpu  # noqa: F401  (x64, compile cache directory)
+    emit("device", platform=d.platform, kind=d.device_kind,
+         count=len(jax.devices()), host_cpus=os.cpu_count(),
+         jax=jax.__version__)
+    for name in args.sections or SECTIONS:
+        PROBES[name](jax, args.cpu_rehearsal)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

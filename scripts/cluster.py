@@ -39,11 +39,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
-def spawn_workers(addr, n, heartbeat_ms=None, prefix="w"):
-    """Spawn n worker subprocesses against coordinator ``addr``."""
-    env = dict(os.environ)
-    # Fault schedules are per-experiment: never inherit one into a pool.
-    env.pop("SRT_FAULTS", None)
+def spawn_workers(addr, n, heartbeat_ms=None, prefix="w", devices="cpu"):
+    """Spawn n worker subprocesses against coordinator ``addr``, each
+    told its device: ``devices`` is ``"cpu"`` (all on the CPU) or
+    ``"tpu"`` (worker i holds chip i of this host). This launcher never
+    initializes a JAX backend itself: a chip belongs to one process."""
+    from spark_rapids_tpu.parallel.cluster.worker import worker_env
     procs = []
     for i in range(n):
         cmd = [sys.executable, "-m",
@@ -51,6 +52,7 @@ def spawn_workers(addr, n, heartbeat_ms=None, prefix="w"):
                "--coordinator", addr, "--worker-id", f"{prefix}{i}"]
         if heartbeat_ms:
             cmd += ["--heartbeat-ms", str(heartbeat_ms)]
+        env = worker_env("cpu" if devices == "cpu" else f"tpu:{i}")
         procs.append(subprocess.Popen(cmd, env=env, cwd=ROOT))
     return procs
 
@@ -68,7 +70,8 @@ def reap(procs, timeout_s=15):
 
 def run_pool(args):
     procs = spawn_workers(args.coordinator, args.workers,
-                          args.heartbeat_ms, args.prefix)
+                          args.heartbeat_ms, args.prefix,
+                          args.worker_devices)
     stop = []
 
     def on_signal(signum, frame):
@@ -159,8 +162,11 @@ def run_demo(args):
     s = session(cluster=True)
     co = CL.get_coordinator(s.conf)
     addr = f"{co.addr[0]}:{co.addr[1]}"
+    # The demo's driver runs queries itself, so it holds whatever
+    # accelerator JAX found: its workers go to the CPU whatever
+    # --worker-devices says.
     procs = spawn_workers(addr, args.workers, args.heartbeat_ms,
-                          args.prefix)
+                          args.prefix, "cpu")
     try:
         df = tpch.QUERIES[args.query](s, d)
         t0 = time.perf_counter()
@@ -192,6 +198,11 @@ def main(argv=None):
     ap.add_argument("--heartbeat-ms", type=int, default=None)
     ap.add_argument("--prefix", default="w",
                     help="worker-id prefix (ids are <prefix>0..N-1)")
+    ap.add_argument("--worker-devices", choices=("cpu", "tpu"),
+                    default="cpu",
+                    help="cpu: every worker on the CPU; tpu: worker i "
+                         "holds chip i of this host (the driver must "
+                         "then run elsewhere or on the CPU)")
     ap.add_argument("--demo", action="store_true",
                     help="self-contained: coordinator + pool + one query")
     ap.add_argument("--supervise", action="store_true",

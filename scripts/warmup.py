@@ -14,10 +14,11 @@ warmup compiles against the REAL data's batch capacities, which is what
 makes the persistent-cache entries reusable by serving traffic).
 
 Replaying a shape does one ``prepare()`` (template into the plan cache)
-and one ``collect()`` (kernels traced + compiled + serialized into
-``spark.rapids.sql.kernelCache.persistentDir``). A process restarted
-with the same persistentDir then deserializes (~ms) instead of
-recompiling (~s), and its first collect of each shape is bind-only.
+and one ``collect()`` (kernels traced + compiled + serialized into the
+persistent compile cache: ``JAX_COMPILATION_CACHE_DIR``, else
+``<checkout>/.jax_cache``). A process restarted with the same directory
+then deserializes (~ms) instead of recompiling (~s), and its first
+collect of each shape is bind-only.
 
 Usage::
 
@@ -71,12 +72,12 @@ def main(argv=None):
     ap.add_argument("--manifest", help="shape manifest JSON to replay")
     ap.add_argument("--dump-manifest",
                     help="write the default shape manifest here and exit")
-    ap.add_argument("--persistent-dir",
-                    default=os.environ.get(
-                        "SRT_KERNEL_CACHE_DIR",
-                        "/tmp/srt_bench_kernel_cache"),
-                    help="persistent kernel cache directory (empty "
-                         "disables the on-disk half)")
+    ap.add_argument("--persistent-dir", default="",
+                    help="move the persistent compile cache here "
+                         "(spark.rapids.sql.kernelCache.persistentDir); "
+                         "default: keep JAX_COMPILATION_CACHE_DIR, else "
+                         "<checkout>/.jax_cache. Ignored while "
+                         "JAX_COMPILATION_CACHE_DIR is set")
     args = ap.parse_args(argv)
 
     if args.dump_manifest:
@@ -133,7 +134,7 @@ def main(argv=None):
             k: pc.counters().get(k, 0) - pc0.get(k, 0)
             for k in ("planCacheHits", "planCacheMisses")},
         "kernel_compiles": kc1["misses"] - kc0["misses"],
-        "persistent_dir": args.persistent_dir or None,
+        "persistent_dir": kc.persistent_stats()["dir"],
         "persistent_hits":
             kc1.get("persistentCacheHits", 0)
             - kc0.get("persistentCacheHits", 0),

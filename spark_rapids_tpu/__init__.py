@@ -30,10 +30,13 @@ import jax as _jax
 # planner keeps hot paths in 32-bit/bfloat16 where Spark semantics allow.
 _jax.config.update("jax_enable_x64", True)
 
-# Persistent compilation cache: remote-TPU compiles can take minutes per
-# program; the disk cache makes every shape/kernel a one-time cost across
-# processes (the engine's capacity-bucket ladder keeps the program count
-# bounded, so the cache converges quickly).
+# Persistent compilation cache: the TPU compiler takes seconds to minutes
+# per program (64-bit types are emulated); the disk cache makes every
+# shape/kernel a one-time cost across processes. The directory is
+# JAX_COMPILATION_CACHE_DIR when set — then no code of this repo sets
+# another (ops/kernel_cache.py configure_persistent) — else one fixed path
+# in the checkout: the path is part of the cache key, so a directory that
+# moves never hits.
 if not _os.environ.get("SRT_NO_COMPILE_CACHE"):
     _default_cache = _os.path.join(
         _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),

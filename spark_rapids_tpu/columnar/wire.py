@@ -27,7 +27,8 @@ to them.
 
 The device side widens back to the logical dtype inside ONE jitted decode
 program per (capacity, spec) -- a few fused casts, so HBM traffic is the
-only cost there. The transfer link (PCIe / a tunneled remote device) is the
+only cost there. The host->device link (PCIe: 4.7 GB/s for a 256 MB
+device_put on the attached v5e, PR 21, against 819 GB/s of HBM) is the
 scarce resource this trades against; reconstruction is bit-exact by
 construction, so every engine invariant (zeroed padding, validity masking)
 is preserved.
@@ -52,14 +53,23 @@ from one cheap host stats pass by smallest wire size:
 ships the logical dtypes untransformed (the transport-transparency
 baseline the dual-engine parity suite pins).
 
-All of a batch's wire arrays are additionally PACKED into one contiguous
-8-byte-aligned staging buffer with a static offset table, so an upload is
-ONE ``jax.device_put`` transfer + one jitted unpack-and-decode program --
-not one dispatch per buffer. Consecutive tiny batches (below
-``spark.rapids.sql.wire.minUploadBytes``) can ride a single transfer via
-:func:`upload_packed_group`. The pack half is pure CPU work, so pipeline
-prefetch threads stage whole partitions while the device consumes earlier
-ones; the ordered consumer only dispatches.
+All of a batch's wire arrays are additionally PACKED, on the host, into
+one contiguous 8-byte-aligned staging buffer with a static offset table
+(pure CPU work, done on prefetch threads). An upload is one
+``jax.device_put`` CALL over the typed, zero-copy VIEWS of that buffer --
+one host-to-device transfer PER wire array, not one per batch -- plus one
+jitted decode program; the device never sees the byte image. (Until PR 21
+the raw uint8 buffer crossed the link once and a jitted program sliced and
+bitcast it apart on the device; the v5e compiler turned those
+``u8[n, itemsize]`` reshapes into 343 MB of code in 698 s for q6's batch
+shape alone.) Consecutive tiny batches (below
+``spark.rapids.sql.wire.minUploadBytes``) share a ``device_put`` call via
+``upload_packed_group``: that saves call overhead, not transfers.
+
+Since the buffer no longer crosses the link whole, packing is a host copy
+that buys nothing the encoded arrays do not already give ``device_put``;
+whether the pack, the layout table and the grouping go is ROADMAP A2's to
+measure (``scripts/chip_probe.py`` times the three upload shapes).
 """
 
 from __future__ import annotations
@@ -159,9 +169,11 @@ def maybe_configure(conf) -> None:
 # Process-global transport counters (bench.py's ``wire`` JSON block):
 # rawBytes = decoded device footprint the plain codec would have shipped,
 # encodedBytes = wire arrays actually produced, stagingBytes = packed
-# staging buffers built, uploadTransfers vs uploadedBatches = how many
-# device_put calls served how many batches (grouping wins show as
-# transfers < batches), codecCols.<kind> = per-codec column counts.
+# staging buffers built, uploadTransfers = arrays handed to device_put
+# (each is its own host-to-device transfer), uploadCalls vs
+# uploadedBatches = how many device_put calls served how many batches
+# (grouping shows as calls < batches), codecCols.<kind> = per-codec
+# column counts.
 _WIRE_LOCK = threading.Lock()
 _WIRE_COUNTERS: Dict[str, float] = {}
 
@@ -180,10 +192,10 @@ def counters() -> Dict[str, float]:
             raw / max(out.get("encodedBytes", raw), 1), 4)
     batches = out.get("uploadedBatches", 0)
     if batches > 0:
-        # Fraction of batches that shared a staging transfer with a
-        # neighbor (0 = every batch paid its own device_put).
+        # Fraction of batches that shared a device_put call with a
+        # neighbor (0 = every batch paid its own call).
         out["stagingHitRate"] = round(
-            1.0 - out.get("uploadTransfers", batches) / batches, 4)
+            1.0 - out.get("uploadCalls", batches) / batches, 4)
     return out
 
 
@@ -716,9 +728,8 @@ def encode_batch(batch, capacity: Optional[int] = None,
 # ---------------------------------------------------------------------------
 # Staging buffer: all of a batch's wire arrays packed into ONE contiguous
 # uint8 buffer with a static, 8-byte-aligned offset table derived purely
-# from (capacity, specs) — so a batch upload is a single device_put
-# transfer and the unpack (static slices + bitcasts) fuses into the same
-# jitted decode program. The pack half is pure CPU (prefetch threads).
+# from (capacity, specs). The pack half is pure CPU (prefetch threads);
+# the upload half hands jax.device_put the buffer's typed views.
 # ---------------------------------------------------------------------------
 
 def _align8(off: int) -> int:
@@ -807,8 +818,8 @@ def pack_encoded(arrays, specs, n: int, cap: int) -> EncodedBatch:
         adt = "bool" if name == "bool" else name
         assert a.dtype == np.dtype(adt) and a.shape == tuple(shape), \
             f"wire array {a.dtype}{a.shape} != layout {name}{shape}"
-        # 8-byte alignment is load-bearing: a misaligned view silently
-        # forces a copy on the device side instead of a bitcast.
+        # 8-byte alignment is load-bearing: the upload half takes typed
+        # numpy views of this buffer at these offsets.
         assert off % 8 == 0, f"staging offset {off} not 8-byte aligned"
         if nbytes:
             buf[off:off + nbytes] = np.frombuffer(a.tobytes(), np.uint8)
@@ -827,116 +838,99 @@ def pack_batch(batch, capacity: Optional[int] = None,
         return pack_encoded(*encode_batch(batch, capacity, string_widths))
 
 
-def _unpack_array(staged, off: int, name: str, shape, nbytes: int):
-    seg = jax.lax.slice(staged, (off,), (off + nbytes,)) if nbytes \
-        else staged[:0]
-    d = np.dtype(np.bool_) if name == "bool" else np.dtype(name)
-    if name == "bool":
-        return seg.reshape(shape) != 0
-    if name == "uint8":
-        return seg.reshape(shape)
-    if d.itemsize == 1:
-        return jax.lax.bitcast_convert_type(seg, d).reshape(shape)
-    return jax.lax.bitcast_convert_type(
-        seg.reshape(tuple(shape) + (d.itemsize,)), d)
+def _staged_views(enc: EncodedBatch) -> List[np.ndarray]:
+    """The staging buffer as its typed wire arrays plus the trailing
+    num_rows scalar: zero-copy views (every offset is 8-byte aligned)."""
+    entries, _total = _batch_layout(enc.cap, enc.specs)
+    return [np.frombuffer(enc.staging,
+                          np.bool_ if name == "bool" else np.dtype(name),
+                          nbytes // np.dtype(name).itemsize,
+                          off).reshape(shape)
+            for off, name, shape, nbytes in entries]
 
 
-def _packed_fn(cap: int, specs: tuple):
-    """One jitted program: unpack the staging buffer (static slices +
-    bitcasts — bit-exact by definition) and widen to the logical
-    layout."""
-    entries, _total = _batch_layout(cap, specs)
-    decode = _decode_fn(cap, specs)
-
-    def run(staged):
-        arrays = [_unpack_array(staged, off, name, shape, nbytes)
-                  for off, name, shape, nbytes in entries]
-        return decode(arrays[:-1], arrays[-1])
-    return run
-
-
-def _packed_jit(cap: int, specs: tuple):
+def _decode_jit(cap: int, specs: tuple):
     # The native fingerprint keys the cache like the kernel cache does:
     # toggling a native gate must never serve a decode traced under the
     # other setting (the RLE branch dispatches differently).
     from spark_rapids_tpu.ops import native
-    key = ("packed", cap, specs, native.fingerprint())
+    key = ("decode", cap, specs, native.fingerprint())
     fn = _DECODE_JIT_CACHE.get(key)
     if fn is None:
         with _DECODE_JIT_LOCK:
             fn = _DECODE_JIT_CACHE.get(key)
             if fn is None:
-                fn = jax.jit(_packed_fn(cap, specs))
+                fn = jax.jit(_decode_fn(cap, specs))
                 _DECODE_JIT_CACHE[key] = fn
     return fn
 
 
+def _decode(enc: EncodedBatch, arrays) -> DeviceBatch:
+    out = _decode_jit(enc.cap, enc.specs)(arrays[:-1], arrays[-1])
+    out.rows_hint = enc.n
+    return out
+
+
 def upload_packed(enc: EncodedBatch) -> DeviceBatch:
-    """Device half: ONE device_put of the staging buffer + one jitted
-    unpack-and-decode dispatch. The largest single allocations in the
-    engine happen here, so the dispatch runs under OOM->spill->retry
-    (memory/oom.py)."""
+    """Device half: one device_put call over the staging buffer's typed
+    views (a transfer per view) + one jitted decode dispatch. The largest
+    single allocations in the engine happen here, so the dispatch runs
+    under OOM->spill->retry (memory/oom.py)."""
     from spark_rapids_tpu.memory.oom import retry_on_oom
+    views = _staged_views(enc)
 
     def put_and_decode():
         # Injection site INSIDE the retried dispatch: an injected OOM
         # here exercises the same escalation ladder a real allocation
         # failure would (tests/test_chaos.py).
         faults.fault_point("upload")
-        staged = jax.device_put(enc.staging)
-        return _packed_jit(enc.cap, enc.specs)(staged)
+        return _decode(enc, jax.device_put(views))
 
     from spark_rapids_tpu import monitoring
     with monitoring.span("upload", "upload",
                          args={"bytes": int(enc.nbytes), "rows": enc.n}):
         out = retry_on_oom(put_and_decode)
-    out.rows_hint = enc.n
-    _wrecord("uploadTransfers")
+    _wrecord("uploadCalls")
+    _wrecord("uploadTransfers", len(views))
     _wrecord("uploadedBatches")
     return out
 
 
 def upload_packed_group(encs: Sequence[EncodedBatch]) -> List[DeviceBatch]:
-    """Upload SEVERAL packed batches in one device_put transfer (the
-    tiny-batch coalescing path, wire.minUploadBytes): staging buffers
-    concatenate (each already 8-aligned), cross the link once, and each
-    member decodes off its on-device slice — same bytes, same decode
-    program, bit-identical to per-batch uploads."""
+    """Upload SEVERAL packed batches in one device_put call (the
+    tiny-batch coalescing path, wire.minUploadBytes): every member's
+    views go out together — still a transfer per view, one call's
+    overhead for all — and each member decodes off its own arrays: same
+    bytes, same decode program, bit-identical to per-batch uploads."""
     from spark_rapids_tpu.memory.oom import retry_on_oom
     encs = list(encs)
     if not encs:
         return []
     if len(encs) == 1:
         return [upload_packed(encs[0])]
-    combined = np.concatenate([e.staging for e in encs])
+    views = [_staged_views(e) for e in encs]
 
     def put_all():
         faults.fault_point("upload")
-        return jax.device_put(combined)
+        return jax.device_put(views)
 
     from spark_rapids_tpu import monitoring
     with monitoring.span("upload-group", "upload",
-                         args={"bytes": int(combined.nbytes),
+                         args={"bytes": sum(int(e.nbytes) for e in encs),
                                "batches": len(encs)}):
         staged_all = retry_on_oom(put_all)
-    _wrecord("uploadTransfers")
+    _wrecord("uploadCalls")
+    _wrecord("uploadTransfers", sum(len(v) for v in views))
     _wrecord("uploadedBatches", len(encs))
     _wrecord("groupedUploads")
-    outs: List[DeviceBatch] = []
-    off = 0
-    for enc in encs:
-        seg = jax.lax.slice(staged_all, (off,), (off + enc.nbytes,))
-        out = retry_on_oom(_packed_jit(enc.cap, enc.specs), seg)
-        out.rows_hint = enc.n
-        outs.append(out)
-        off += enc.nbytes
-    return outs
+    return [retry_on_oom(_decode, enc, arrays)
+            for enc, arrays in zip(encs, staged_all)]
 
 
 def plan_upload_groups(sizes: Sequence[int],
                        min_bytes: int) -> List[List[int]]:
     """Group consecutive upload indices so members below ``min_bytes``
-    share a transfer: tiny batches accumulate until the group reaches the
+    share a device_put call: tiny batches accumulate until the group reaches the
     threshold; a batch at/above it always ships alone. Deterministic —
     depends only on the sizes, never on prefetch timing."""
     groups: List[List[int]] = []
@@ -961,7 +955,7 @@ def plan_upload_groups(sizes: Sequence[int],
 
 def upload_encoded(arrays, specs, n: int, cap: int) -> DeviceBatch:
     """Back-compat device half over unpacked wire arrays: pack + single
-    transfer. Accepts an :class:`EncodedBatch` in the first position
+    device_put call. Accepts an :class:`EncodedBatch` in the first position
     too (already-packed prefetch payloads)."""
     if isinstance(arrays, EncodedBatch):
         return upload_packed(arrays)
@@ -970,5 +964,5 @@ def upload_encoded(arrays, specs, n: int, cap: int) -> DeviceBatch:
 
 def upload(batch, capacity: Optional[int] = None,
            string_widths: Optional[dict] = None) -> DeviceBatch:
-    """Encode + pack + single device_put + jitted on-device widen."""
+    """Encode + pack + one device_put call + jitted on-device widen."""
     return upload_packed(pack_batch(batch, capacity, string_widths))

@@ -96,9 +96,8 @@ from typing import Dict, List, Optional, Tuple
 
 
 class InjectedOomError(RuntimeError):
-    """Synthetic device allocation failure. The message carries the
-    backend's RESOURCE_EXHAUSTED marker so ``is_oom_error`` routes it
-    into the spill/retry ladder exactly like the real thing."""
+    """Synthetic device allocation failure: ``is_oom_error`` routes the
+    type into the spill/retry ladder exactly like the real thing."""
 
     def __init__(self, site: str):
         super().__init__(
@@ -108,7 +107,7 @@ class InjectedOomError(RuntimeError):
 
 
 class InjectedTransientError(RuntimeError):
-    """Synthetic backend/tunnel failure. Carries the UNAVAILABLE marker
+    """Synthetic backend failure. Carries the UNAVAILABLE marker
     so ``is_transient_error`` routes it into the whole-query retry."""
 
     def __init__(self, site: str):

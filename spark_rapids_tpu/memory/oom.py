@@ -83,14 +83,35 @@ class OomRetryExhausted(RuntimeError):
         self.rungs = rungs
 
 
+# What a backend says when an allocation fails WHILE a program or a
+# transfer runs. First two: the TPU runtime, as the attached v5e printed
+# them (PR 21: "RESOURCE_EXHAUSTED: Error allocating device buffer:
+# Attempting to allocate 3.00G. That was not possible. There are 693.97M
+# free.; (0x0x0_HBM0)"; loading a program whose scratch does not fit what
+# is free says "Attempting to reserve"). The rest: XLA's BFC allocator on
+# other backends. The compiler's refusals carry RESOURCE_EXHAUSTED too
+# ("Allocation (size=..) would exceed memory (size=..) :: .. space=hbm" /
+# "space=vmem", "Ran out of memory in memory space ..") and are NOT here:
+# no amount of spilling makes a program that does not fit compile, and
+# retrying it down to the host engine would hide that the device path is
+# broken.
+_RUNTIME_OOM_MARKERS = (
+    "Attempting to allocate", "Attempting to reserve",
+    "Out of memory while trying to allocate", "Out of memory allocating",
+    "Failed to allocate")
+
+
 def is_oom_error(e: BaseException) -> bool:
+    """A run-time device allocation failure (or its injected stand-in),
+    and nothing else: a compile-time or lowering error propagates."""
     if isinstance(e, OomRetryExhausted):
         return False
-    s = f"{type(e).__name__}: {e}"
+    if isinstance(e, faults.InjectedOomError):
+        return True
     # Deliberately narrow: a spurious match triggers a full
     # spill-everything pass plus a duplicate dispatch of the failing op.
-    return ("RESOURCE_EXHAUSTED" in s or "Out of memory" in s
-            or "out of memory" in s)
+    s = str(e)
+    return any(m in s for m in _RUNTIME_OOM_MARKERS)
 
 
 # -- degraded batch target (rung 3) -----------------------------------------
@@ -217,7 +238,7 @@ def retry_on_oom(fn: Callable[..., T], *args, **kwargs) -> T:
 # -- transient failures -------------------------------------------------------
 
 def is_transient_error(e: BaseException) -> bool:
-    """Backend/tunnel failures worth retrying the whole query (SURVEY
+    """Backend failures worth retrying the whole query (SURVEY
     §5.3 failure detection: the reference leans on Spark task retry; this
     engine owns the retry itself — with exponential backoff and a
     per-query budget, plan/planner.py). Deliberately narrow —

@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import collections
 import hashlib
+import os
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -336,16 +337,21 @@ def call(entry: CompiledKernel, metrics, *args, **kwargs):
 # ---------------------------------------------------------------------------
 #
 # The in-memory LRU above survives re-planning but not process restarts:
-# a fresh server pays first_run_s (trace + XLA compile) for every kernel
-# again. ``spark.rapids.sql.kernelCache.persistentDir`` points JAX's
-# persistent compilation cache at a directory so compiled executables
-# serialize to disk and a restarted process deserializes (~ms) instead of
-# recompiling (~s). Hits/misses are counted via jax's monitoring events
-# and surface through :meth:`KernelCache.stats` as persistentCacheHits /
-# persistentCacheMisses (bench.py's kernel_cache JSON block).
+# a fresh process pays first_run_s (trace + XLA compile) for every kernel
+# again, and a chip call is always a fresh process. JAX's persistent
+# compilation cache serializes compiled executables to disk so a restarted
+# process deserializes (~ms) instead of recompiling (~s..min on a TPU).
+#
+# Exactly one rule picks the directory (package ``__init__`` applies it at
+# import): ``JAX_COMPILATION_CACHE_DIR`` when set, else
+# ``<checkout>/.jax_cache``. ``spark.rapids.sql.kernelCache.persistentDir``
+# may move it only while the environment variable is unset; with the
+# variable set the key just reports whether it names the active directory.
+# Hits/misses are counted via jax's monitoring events and surface through
+# :meth:`KernelCache.stats` as persistentCacheHits / persistentCacheMisses.
 
 _PERSISTENT_LOCK = threading.Lock()
-_PERSISTENT = {"dir": None, "hits": 0, "misses": 0, "listener": False}
+_PERSISTENT = {"hits": 0, "misses": 0, "listener": False}
 
 
 def _on_cache_event(event: str, **kwargs) -> None:
@@ -357,55 +363,48 @@ def _on_cache_event(event: str, **kwargs) -> None:
             _PERSISTENT["misses"] += 1
 
 
+def _ensure_cache_listener() -> None:
+    with _PERSISTENT_LOCK:
+        if not _PERSISTENT["listener"]:
+            import jax
+            jax.monitoring.register_event_listener(_on_cache_event)
+            _PERSISTENT["listener"] = True
+
+
 def configure_persistent(path: Optional[str]) -> bool:
-    """Enable JAX's persistent compilation cache at ``path`` (idempotent;
-    empty/None disables nothing — the cache cannot be torn down once jax
-    has initialized it, so the first non-empty dir of the process wins).
-    Returns True when the cache is active at ``path``."""
+    """Adopt ``spark.rapids.sql.kernelCache.persistentDir`` (idempotent).
+    Empty/None changes nothing: the directory stays where package import
+    put it. With ``JAX_COMPILATION_CACHE_DIR`` set the environment owns
+    the directory and this never moves it. Returns True when the cache is
+    active at ``path``."""
+    import jax
+    _ensure_cache_listener()
     path = (path or "").strip()
     if not path:
         return False
-    with _PERSISTENT_LOCK:
-        if _PERSISTENT["dir"] == path:
-            return True
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", path)
-        # The engine's kernels compile in ms on warm backends; without
-        # these floors jax would skip persisting exactly the cheap
-        # kernels whose aggregate retrace cost dominates first_run_s.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        try:
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", -1)
-        except Exception:   # older jax: flag absent, default persists all
-            pass
-        try:
-            # jax latches "is the cache usable" on the FIRST compile of
-            # the process; a kernel compiled before this conf arrived
-            # would leave that latch stuck at disabled. Reset it so the
-            # newly-configured dir takes effect mid-process.
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception:   # pragma: no cover - jax-version dependent
-            pass
-        with _PERSISTENT_LOCK:
-            if not _PERSISTENT["listener"]:
-                from jax._src import monitoring
-                monitoring.register_event_listener(_on_cache_event)
-                _PERSISTENT["listener"] = True
-            _PERSISTENT["dir"] = path
-        return True
-    except Exception as e:      # pragma: no cover - jax-version dependent
-        import logging
-        logging.getLogger("spark_rapids_tpu").warning(
-            "persistent kernel cache unavailable at %r: %s", path, e)
-        return False
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
+            jax.config.jax_compilation_cache_dir == path:
+        return jax.config.jax_compilation_cache_dir == path
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_compilation_cache_dir", path)
+    # The engine's kernels compile in ms on warm backends; without
+    # these floors jax would skip persisting exactly the cheap
+    # kernels whose aggregate retrace cost dominates first_run_s.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # jax latches "is the cache usable" (and where) on the FIRST compile
+    # of the process; reset it so the new dir takes effect mid-process.
+    compilation_cache.reset_cache()
+    return True
 
 
 def persistent_stats() -> Dict[str, Any]:
+    """The ACTIVE persistent-cache directory (None when off) and the
+    hits/misses seen since the listener was registered (first plan)."""
+    import jax
     with _PERSISTENT_LOCK:
-        return {"dir": _PERSISTENT["dir"], "hits": _PERSISTENT["hits"],
+        return {"dir": jax.config.jax_compilation_cache_dir or None,
+                "hits": _PERSISTENT["hits"],
                 "misses": _PERSISTENT["misses"]}
 
 

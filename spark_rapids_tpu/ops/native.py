@@ -30,13 +30,20 @@ Contracts (mirroring every other gate in this engine):
   including -0.0/NaN float edge cases. Where bit identity cannot be
   guaranteed (float SUM reduction order, the unstable-first sort
   relaxation), the native path simply does not engage.
+- **Default off until it compiles for the chip.** A kernel's
+  ``native.<kernel>.enabled`` gate defaults on only when
+  tests/test_chip_compile.py compiles it for v5e at real width and it has
+  been seen bit-identical to its twin on a chip. Today none does: Mosaic
+  refuses all four as written (ROADMAP A5 records the compiler's words),
+  so all four gates default OFF on every backend. Turning one on by conf
+  on a TPU backend raises the lowering error at trace time — there is no
+  catch-and-fall-through to the twin.
 - **Kill switches.** ``spark.rapids.sql.native.enabled`` is the master
-  gate; per-kernel ``native.<kernel>.enabled`` keys disable one kernel.
-  ``SRT_NATIVE=0`` disables for a whole process. Off restores today's
-  code paths byte-for-byte.
-- **Backend.** Mosaic only compiles on TPU. On CPU the layer no-ops to
-  the fallback; ``SRT_NATIVE_INTERPRET=1`` (or :func:`forced`) runs the
-  kernels through the Pallas interpreter so the CPU CI can prove parity.
+  gate; ``SRT_NATIVE=0`` disables for a whole process. Off restores the
+  jax.numpy code paths byte-for-byte.
+- **Backend.** Mosaic only compiles on TPU. On any other backend the
+  layer no-ops to the twin; the Pallas interpreter is reachable ONLY
+  through the test hook :func:`forced` (the CPU parity suite).
 - **Cache coherence.** :func:`fingerprint` folds the enabled-kernel set
   into every kernel-cache key (ops/kernel_cache.py ``lookup``) and the
   wire decode-jit cache, so toggling a gate never serves a stale
@@ -57,7 +64,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from spark_rapids_tpu import config as C
+
 KERNELS = ("radixSort", "joinProbe", "rleDecode", "segmentReduce")
+_ENTRIES = {"master": C.NATIVE_ENABLED, "radixSort": C.NATIVE_RADIX_SORT,
+            "joinProbe": C.NATIVE_JOIN_PROBE,
+            "rleDecode": C.NATIVE_RLE_DECODE,
+            "segmentReduce": C.NATIVE_SEGMENT_REDUCE}
 
 _LOCK = threading.Lock()
 # Conf-adopted overrides: None = fall through to env/default.
@@ -71,28 +84,16 @@ _FORCED: Optional[Dict[str, bool]] = None     # tests: forced() context
 _COUNTERS: Dict[str, float] = {}
 
 
-def _env_true(name: str, default: bool) -> bool:
-    v = os.environ.get(name)
-    if v is None:
-        return default
-    return v.strip() not in ("0", "false", "no", "")
-
-
-def interpret_forced() -> bool:
-    """Pallas interpreter forced (the CPU parity-suite hook)."""
-    return _env_true("SRT_NATIVE_INTERPRET", False)
-
-
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Pallas interpreter: only inside :func:`forced` on a non-TPU
+    backend. No env var, conf key or backend check turns it on."""
+    return _FORCED is not None and jax.default_backend() != "tpu"
 
 
 def available() -> bool:
     """Native kernels can run at all: a real TPU backend compiles them
-    through Mosaic; anything else needs the interpreter forced."""
-    if jax.default_backend() == "tpu":
-        return True
-    return interpret_forced()
+    through Mosaic; anything else only under :func:`forced`."""
+    return jax.default_backend() == "tpu" or _FORCED is not None
 
 
 def maybe_configure(conf) -> None:
@@ -100,13 +101,8 @@ def maybe_configure(conf) -> None:
     process (unset keys clear back to env/default), mirroring the wire
     codec's process-global adoption."""
     global _MAX_RUNS_OVERRIDE
-    from spark_rapids_tpu import config as C
-    entries = {"master": C.NATIVE_ENABLED, "radixSort": C.NATIVE_RADIX_SORT,
-               "joinProbe": C.NATIVE_JOIN_PROBE,
-               "rleDecode": C.NATIVE_RLE_DECODE,
-               "segmentReduce": C.NATIVE_SEGMENT_REDUCE}
     with _LOCK:
-        for name, entry in entries.items():
+        for name, entry in _ENTRIES.items():
             raw = conf.raw.get(entry.key)
             _OVERRIDE[name] = None if raw is None else bool(entry.get(conf))
         raw = conf.raw.get(C.NATIVE_RLE_MAX_RUNS.key)
@@ -121,7 +117,19 @@ def master_enabled() -> bool:
         ov = _OVERRIDE["master"]
     if ov is not None:
         return ov
-    return _env_true("SRT_NATIVE", True)
+    v = os.environ.get("SRT_NATIVE")
+    if v is not None:
+        return v.strip() not in ("0", "false", "no", "")
+    return bool(_ENTRIES["master"].default)
+
+
+def gate_enabled(name: str) -> bool:
+    """One kernel's own gate: adopted conf key, else its registered
+    default (off for every kernel Mosaic refuses today)."""
+    assert name in KERNELS, name
+    with _LOCK:
+        ov = _OVERRIDE[name]
+    return ov if ov is not None else bool(_ENTRIES[name].default)
 
 
 def kernel_enabled(name: str) -> bool:
@@ -130,21 +138,14 @@ def kernel_enabled(name: str) -> bool:
     assert name in KERNELS, name
     if _FORCED is not None:
         return bool(_FORCED.get("master", True)) and \
-            bool(_FORCED.get(name, True)) and available()
-    if not master_enabled() or not available():
-        return False
-    with _LOCK:
-        ov = _OVERRIDE[name]
-    if ov is not None:
-        return ov
-    return _env_true(f"SRT_NATIVE_{name.upper()}", True)
+            bool(_FORCED.get(name, True))
+    return master_enabled() and available() and gate_enabled(name)
 
 
 def rle_max_runs() -> int:
     with _LOCK:
         if _MAX_RUNS_OVERRIDE is not None:
             return _MAX_RUNS_OVERRIDE
-    from spark_rapids_tpu import config as C
     return int(C.NATIVE_RLE_MAX_RUNS.default)
 
 
@@ -160,33 +161,27 @@ def fingerprint() -> Tuple:
 
 
 class forced:
-    """Test hook: force the native gate state (and the interpreter on
-    non-TPU backends) for a ``with`` scope.
+    """Test hook: force the native gate state for a ``with`` scope — and,
+    on a non-TPU backend, the Pallas interpreter (the only way to reach
+    it).
 
+    ``forced()`` turns every kernel on whatever its default;
     ``forced(radixSort=False)`` keeps the master gate on with one kernel
     off; ``forced(master=False)`` disables everything."""
 
     def __init__(self, **kw: bool):
         self._kw = dict(kw)
         self._prev_forced = None
-        self._prev_env = None
 
     def __enter__(self):
         global _FORCED
         self._prev_forced = _FORCED
         _FORCED = self._kw
-        self._prev_env = os.environ.get("SRT_NATIVE_INTERPRET")
-        if jax.default_backend() != "tpu":
-            os.environ["SRT_NATIVE_INTERPRET"] = "1"
         return self
 
     def __exit__(self, *exc):
         global _FORCED
         _FORCED = self._prev_forced
-        if self._prev_env is None:
-            os.environ.pop("SRT_NATIVE_INTERPRET", None)
-        else:
-            os.environ["SRT_NATIVE_INTERPRET"] = self._prev_env
         return False
 
 

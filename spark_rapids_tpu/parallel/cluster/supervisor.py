@@ -211,12 +211,13 @@ class Supervisor:
                "--coordinator", self.addr, "--worker-id", wid]
         if self.heartbeat_ms:
             cmd += ["--heartbeat-ms", str(self.heartbeat_ms)]
-        env = dict(os.environ)
-        # Fault schedules are per-worker: never inherit one into the
-        # pool — a seeded crash-looper gets its schedule EXPLICITLY
+        # Each worker is told its device (the CPU unless extra_env
+        # carries a worker_env("tpu:<i>")), and never inherits a fault
+        # schedule: a seeded crash-looper gets its schedule EXPLICITLY
         # via extra_env (and keeps it across restarts, which is what
         # makes it loop).
-        env.pop("SRT_FAULTS", None)
+        from spark_rapids_tpu.parallel.cluster.worker import worker_env
+        env = worker_env("cpu")
         env.update(extra_env)
         return subprocess.Popen(cmd, env=env, cwd=root)
 

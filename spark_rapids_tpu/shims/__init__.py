@@ -7,7 +7,10 @@ public API drifts the same way (shard_map's home and kwargs, the tree
 API's module, pytree registration). Every version-sensitive touchpoint
 routes through this package so a JAX upgrade is a one-file change, and
 ``provider()`` names the resolved shim for diagnostics (the
-SparkShimServiceProvider.matchesVersion analog)."""
+SparkShimServiceProvider.matchesVersion analog).
+
+One installation is supported (jax 0.9.0): branches for other versions
+are added when a second one is, not kept in advance."""
 
 from __future__ import annotations
 
@@ -16,43 +19,17 @@ import jax
 
 def provider() -> str:
     """Human-readable name of the resolved shim set."""
-    flavor = "jax-native-shard-map" if hasattr(jax, "shard_map") \
-        else "jax-experimental-shard-map"
-    return f"jax {jax.__version__} ({flavor}, tree={_TREE_FLAVOR})"
+    return f"jax {jax.__version__} (jax-native-shard-map, tree=jax.tree)"
 
-
-# -- shard_map (moved from jax.experimental to jax; kwargs renamed) ------
 
 def shard_map(fn, mesh, in_specs, out_specs):
-    """Version-tolerant shard_map: newer jax exposes jax.shard_map; older
-    versions use jax.experimental.shard_map.shard_map with check_rep."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-    try:
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
-    except TypeError:
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
-# -- tree API (jax.tree since 0.4.25; jax.tree_util before) --------------
-
-if hasattr(jax, "tree") and hasattr(jax.tree, "map"):
-    _TREE_FLAVOR = "jax.tree"
-    tree_map = jax.tree.map
-    tree_flatten = jax.tree.flatten
-    tree_unflatten = jax.tree.unflatten
-else:                                           # pragma: no cover
-    _TREE_FLAVOR = "jax.tree_util"
-    tree_map = jax.tree_util.tree_map
-    tree_flatten = jax.tree_util.tree_flatten
-    tree_unflatten = jax.tree_util.tree_unflatten
+tree_map = jax.tree.map
+tree_flatten = jax.tree.flatten
+tree_unflatten = jax.tree.unflatten
 
 
 def register_pytree_node(cls, flatten, unflatten):

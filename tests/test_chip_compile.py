@@ -1,0 +1,222 @@
+"""Compile the main path's kernels for the chip, without the chip.
+
+The TPU's compiler is installed next to the CPU backend and compiles for a
+chip that is described and not attached (guide: on-chip-measurement §2).
+These tests hold what no interpret-mode or CPU test can see: that the
+programs TPC-H SF1 really dispatches lower for a v5e at the capacities it
+dispatches them at (reader batches 3*2^18..2^20 rows, coalesced ones
+above), and — for every native Pallas kernel whose gate is default-on —
+that Mosaic accepts it and a ``tpu_custom_call`` is in the program.
+
+PR 21 found all four native kernels refused and the packed wire unpack
+program compiling for 698 s; the first are default-off since (ROADMAP A5),
+the second is gone (columnar/wire.py). A compile that passes is not a chip
+run: ``chip_smoke.py`` is.
+
+The topology is described inside a module-scoped fixture, never at import:
+one process at a time may load libtpu, and every xdist worker imports this
+file. Keep every chip-compile test in THIS file for the same reason.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import spark_rapids_tpu  # noqa: F401  (x64)
+from spark_rapids_tpu.columnar import dtypes as dt
+
+CAPS = (1 << 20, 3 << 19)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip (the next run warns and
+    compiles again): keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _shapes(tree, sharding):
+    """``tree`` with every array leaf replaced by its shape on the chip."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _compile(fn, sharding, *args):
+    return jax.jit(fn).lower(*_shapes(args, sharding)).compile()
+
+
+def _lineitem_like(cap):
+    """A q1/q6-shaped batch: two float64 measures, a date, an int64 key."""
+    from spark_rapids_tpu.columnar.batch import DeviceBatch, DeviceColumn
+    ones = np.ones((cap,), np.bool_)
+    cols = (DeviceColumn(dt.FLOAT64, np.zeros((cap,), np.float64), ones),
+            DeviceColumn(dt.FLOAT64, np.zeros((cap,), np.float64), ones),
+            DeviceColumn(dt.DATE, np.zeros((cap,), np.int32), ones),
+            DeviceColumn(dt.INT64, np.zeros((cap,), np.int64), ones))
+    return DeviceBatch(cols, np.asarray(cap, np.int32))
+
+
+# -- native Pallas kernels ------------------------------------------------------
+
+def _native_cases(cap):
+    """kernel name -> (fn, args): one compile case per native kernel.
+    A kernel may be default-on only if it is here and the case passes."""
+    from spark_rapids_tpu.ops import native
+    u32 = np.zeros((cap,), np.uint32)
+    u64 = np.zeros((cap,), np.uint64)
+    i32 = np.zeros((cap,), np.int32)
+    i64 = np.zeros((cap,), np.int64)
+    return {
+        "radixSort": (native.stable_argsort_u32, (u32,)),
+        "joinProbe": (native.searchsorted_u64_pair, (u64, u64)),
+        "rleDecode": (lambda v, e, n: native.rle_decode(v, e, cap, n),
+                      (np.zeros((4096,), np.int64),
+                       np.zeros((4096,), np.int32),
+                       np.asarray(0, np.int32))),
+        "segmentReduce": (lambda v, g: native.segment_sum_sorted(v, g, cap),
+                          (i64, i32)),
+    }
+
+
+@pytest.mark.parametrize("cap", CAPS)
+def test_every_default_on_native_kernel_compiles_for_v5e(cap, one_chip,
+                                                         monkeypatch):
+    from spark_rapids_tpu.config import TpuConf
+    from spark_rapids_tpu.ops import native
+    native.maybe_configure(TpuConf())
+    default_on = [k for k in native.KERNELS if native.gate_enabled(k)]
+    cases = _native_cases(cap)
+    assert set(default_on) <= set(cases), \
+        "a native kernel is default-on without a v5e compile case"
+    # The CPU backend would trace the kernels in interpret mode (under
+    # forced()) or not at all; compile what a TPU backend would.
+    monkeypatch.setattr(native, "_interpret", lambda: False)
+    for name in default_on:
+        fn, args = cases[name]
+        text = _compile(fn, one_chip, *args).as_text()
+        assert "tpu_custom_call" in text, \
+            f"{name}: no Mosaic kernel in the compiled program"
+
+
+def test_refused_native_kernels_are_default_off(one_chip, monkeypatch):
+    """The other half of the rule: what Mosaic refuses today must not be
+    default-on. When a kernel is repaired this test says so — flip its
+    gate then, and it joins the test above."""
+    from spark_rapids_tpu.config import TpuConf
+    from spark_rapids_tpu.ops import native
+    native.maybe_configure(TpuConf())
+    monkeypatch.setattr(native, "_interpret", lambda: False)
+    for name, (fn, args) in _native_cases(CAPS[0]).items():
+        try:
+            _compile(fn, one_chip, *args)
+            compiles = True
+        except Exception:       # whatever Mosaic raised: it refused
+            compiles = False
+        if not compiles:
+            assert not native.gate_enabled(name), \
+                f"{name} does not compile for v5e and is default-on"
+
+
+# -- main-path jax.numpy kernels ------------------------------------------------
+
+@pytest.mark.parametrize("cap", CAPS)
+def test_wire_decode_program(cap, one_chip):
+    """q1's scan batch as the wire ships it: narrowed ints, a plain
+    float64, numeric and string dictionaries — decoded from typed arrays
+    (the device-side byte unpack this replaced took 698 s here)."""
+    from spark_rapids_tpu.columnar import wire
+    specs = (("num", "float64", "int8", "all"),
+             ("num", "float64", "float64", "all"),
+             ("dnum", "float64", "int8", 16, "all"),
+             ("dstr", 8, "int8", 8, "all"),
+             ("rle", "int32", "int8", 8, "all"),
+             ("num", "date", "int16", "all"))
+    entries, _total = wire._batch_layout(cap, specs)
+    arrays = [np.zeros(shape, np.bool_ if name == "bool" else name)
+              for _off, name, shape, _nbytes in entries]
+    out = _compile(wire._decode_fn(cap, specs), one_chip,
+                   arrays[:-1], arrays[-1])
+    assert "tpu_custom_call" not in out.as_text()   # no native kernel live
+
+
+@pytest.mark.parametrize("cap", CAPS)
+def test_filter_and_global_aggregate(cap, one_chip):
+    """q6's device half: the filter predicate and the ungrouped
+    hash-aggregate update/merge/finalize."""
+    from spark_rapids_tpu import exprs as E
+    from spark_rapids_tpu.exprs import base as eb
+    from spark_rapids_tpu.exprs.base import BoundReference as Ref, lit
+    from spark_rapids_tpu.ops import AggSpec, HashAggregateExec, Sum
+    from spark_rapids_tpu.ops.base import InMemorySourceExec
+    schema = (("price", dt.FLOAT64), ("disc", dt.FLOAT64),
+              ("ship", dt.DATE), ("key", dt.INT64))
+    agg = HashAggregateExec(
+        InMemorySourceExec(schema, [[]]), [],
+        [AggSpec("revenue", Sum(E.Multiply(Ref(0, dt.FLOAT64),
+                                           Ref(1, dt.FLOAT64))))])
+
+    def step(batch):
+        cond = eb.as_device_column(
+            E.LessThan(Ref(1, dt.FLOAT64), lit(0.07)).eval(batch), batch)
+        kept = batch.compact(cond.data & cond.validity)
+        partial = agg._update_batch(kept, jnp.asarray(0, jnp.int64))
+        return agg._finalize_batch(agg._merge_batch(partial))
+
+    _compile(step, one_chip, _lineitem_like(cap))
+
+
+def test_radix_permutation(one_chip):
+    """The LSD radix argsort every sort and grouping shares
+    (ops/kernels.py _radix_perm), one key word: two stable u32 argsort
+    passes. One capacity only — each pass takes the chip's compiler
+    ~17 s at 2^20 rows (PR 21), which is ROADMAP A1's business."""
+    from spark_rapids_tpu.ops import kernels
+    cap = CAPS[0]
+    _compile(lambda a, n: kernels.lex_sort_perm([a], n, cap),
+             one_chip, np.zeros((cap,), np.uint32),
+             np.asarray(cap, np.int32))
+
+
+@pytest.mark.parametrize("cap", CAPS)
+def test_sorted_segment_reduce(cap, one_chip):
+    from spark_rapids_tpu.ops import kernels
+    valid = np.ones((cap,), np.bool_)
+    gid = np.zeros((cap,), np.int32)
+    for values, kind in ((np.zeros((cap,), np.float64), "sum"),
+                         (np.zeros((cap,), np.int64), "sum"),
+                         (np.zeros((cap,), np.int32), "min")):
+        _compile(lambda v, ok, g: kernels.segment_reduce(v, ok, g, cap,
+                                                         kind),
+                 one_chip, values, valid, gid)
+
+
+@pytest.mark.parametrize("cap", CAPS)
+def test_join_probe(cap, one_chip):
+    """The sorted-fingerprint probe: per-probe-row match ranges in a
+    built side (two searchsorted passes over uint64 fingerprints)."""
+    from spark_rapids_tpu.ops import join
+    probe = _lineitem_like(cap)
+    built = jax.eval_shape(lambda b: join.build_side(b, [3]),
+                           _lineitem_like(1 << 17))
+    _compile(lambda bs, p: join.probe_ranges(bs, p, [3]), one_chip,
+             built, probe)

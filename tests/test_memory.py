@@ -148,6 +148,29 @@ class TestSemaphore:
         assert max(peak) <= 2
 
 
+# What the attached v5e raised when a device_put found no room
+# (chip run, PR 21) ...
+_V5E_RUNTIME_OOM = (
+    "RESOURCE_EXHAUSTED: Error allocating device buffer: Attempting to "
+    "allocate 3.00G. That was not possible. There are 693.97M free.; "
+    "(0x0x0_HBM0)")
+# ... and what its compiler says of programs that do not fit HBM or a
+# kernel's fast memory (described v5e:2x2 topology, PR 21).
+_V5E_COMPILE_REFUSALS = [
+    "RESOURCE_EXHAUSTED: Allocation (size=68719476736) would exceed "
+    "memory (size=17179869184) :: #allocation7 [shape = "
+    "'f32[131072,1024,128]{1,0,2:T(8,128)}', space=hbm, size = "
+    "0xffffffffffffffff, tag = 'output of fusion@{}']",
+    "RESOURCE_EXHAUSTED: Allocation (size=268435456) would exceed memory "
+    "(size=134217728) :: #allocation2 [shape = 'u8[268435456]{0}', "
+    "space=vmem, size = 0x10000000, tag = 'input window allocation for "
+    "operator input 0.']",
+    "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+    "memory in memory space hbm. Used 20.1G of 15.75G hbm.",
+    "RESOURCE_EXHAUSTED",
+]
+
+
 class TestOomRetry:
     """OOM -> spill -> retry (DeviceMemoryEventHandler.scala:42-69
     analog, memory/oom.py): a RESOURCE_EXHAUSTED dispatch spills every
@@ -182,6 +205,26 @@ class TestOomRetry:
         finally:
             set_active_catalog(None)
 
+    def test_runtime_allocation_failure_is_oom(self):
+        from spark_rapids_tpu.memory.oom import is_oom_error
+        assert is_oom_error(RuntimeError(_V5E_RUNTIME_OOM))
+
+    @pytest.mark.parametrize("msg", _V5E_COMPILE_REFUSALS)
+    def test_compile_time_resource_exhausted_is_not_oom(self, msg):
+        """A program that does not fit is not memory pressure: it must
+        propagate, not walk the spill ladder down to the host engine."""
+        from spark_rapids_tpu.memory.oom import is_oom_error, retry_on_oom
+        assert not is_oom_error(RuntimeError(msg))
+        calls = []
+
+        def refused():
+            calls.append(1)
+            raise RuntimeError(msg)
+
+        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+            retry_on_oom(refused)
+        assert len(calls) == 1
+
     def test_non_oom_propagates(self):
         from spark_rapids_tpu.memory.oom import retry_on_oom
 
@@ -199,7 +242,7 @@ class TestOomRetry:
         set_active_catalog(cat)
         try:
             def oom():
-                raise RuntimeError("RESOURCE_EXHAUSTED")
+                raise RuntimeError(_V5E_RUNTIME_OOM)
 
             with pytest.raises(RuntimeError):
                 retry_on_oom(oom)

@@ -94,3 +94,11 @@ class _DummyChild:
 
     def num_partitions(self, ctx):
         return 1
+
+
+def test_dryrun_multichip_raises_on_too_few_devices():
+    """Fewer devices than asked for is an error — never a quiet re-run on
+    virtual CPU devices that reports a mesh nobody has."""
+    import __graft_entry__ as g
+    with pytest.raises(RuntimeError, match="device"):
+        g.dryrun_multichip(len(jax.devices()) + 1)

@@ -319,3 +319,29 @@ class TestAggReviewRegressions:
                    "2020-13-01", None]})
         check_expr(E.Cast(Ref(0, dt.STRING), dt.DATE), b,
                    [18262, None, 18263, 18262, None, None])
+
+
+@pytest.mark.parametrize("dtype", ["float64", "int64", "int32"])
+@pytest.mark.parametrize("rows", [2048, 700])
+def test_grouped_aggregate_prefix_sums(dtype, rows):
+    """The grouped aggregate's prefix sums: floats go through two levels
+    of associative scans (a float64 cumsum costs the v5e compiler ~150 s),
+    integers through jnp.cumsum — and the two levels are bit-identical to
+    it for integers, which is what lets a measurement choose between
+    them (``scripts/chip_probe.py prefix``)."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops.aggregate import (_prefix_sums,
+                                                _two_level_prefix_sums)
+    rng = np.random.default_rng(rows)
+    if dtype == "float64":
+        host = rng.uniform(0, 1e5, (rows, 3))
+    else:
+        host = rng.integers(-1000, 1000, (rows, 3)).astype(dtype)
+    want = np.cumsum(host, axis=0, dtype=host.dtype)
+    for fn in (_prefix_sums, _two_level_prefix_sums):
+        got = np.asarray(fn(jnp.asarray(host)))
+        assert got.dtype == host.dtype and got.shape == host.shape
+        if dtype == "float64":
+            np.testing.assert_allclose(got, want, rtol=1e-12)
+        else:
+            assert np.array_equal(got, want)

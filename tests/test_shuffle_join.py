@@ -329,3 +329,78 @@ def test_nested_loop_with_filtered_small_build():
     want = sorted(j.collect_host())
     assert got == want
     assert len(got) == 6        # 3 x 2, not 3 x 4
+
+
+def test_build_side_is_one_program_per_shape():
+    """The join build side dispatches ONE jitted program per (keys, batch
+    shape): op by op it was ~240 one-op programs per shape, each a
+    compile of its own on the chip (PR 21: 342 of the 366 programs q3's
+    first run compiled there)."""
+    import jax
+    from spark_rapids_tpu.columnar.host import host_to_device
+    from spark_rapids_tpu.ops.join import build_side
+
+    def batch(seed):
+        rng = np.random.default_rng(seed)
+        return host_to_device(HostBatch.from_pydict(
+            [("k", dt.INT64), ("v", dt.FLOAT64)],
+            {"k": rng.integers(0, 50, 1000).tolist(),
+             "v": rng.random(1000).tolist()}), capacity=1536)
+
+    first, second = batch(1), batch(2)
+    compiled = []
+
+    def on_compile(event, duration, **kw):
+        if event.endswith("backend_compile_duration"):
+            compiled.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        built = build_side(first, [0])
+        n_first = len(compiled)
+        build_side(second, [0])             # same shape: nothing new
+        n_second = len(compiled) - n_first
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_compile)
+    assert n_first <= 1 and n_second == 0, (n_first, n_second)
+    fp = np.asarray(built.fp)
+    live = int(np.asarray(built.matchable).sum())
+    assert live == 1000 and (np.diff(fp[:live].astype(np.float64)) >= 0).all()
+
+
+def test_build_side_dispatches_through_the_kernel_choke_point():
+    """The build side is a join's largest single allocation: it goes
+    through ``kernel_cache.call`` like every other cached kernel — the
+    op's metrics see the cache and the compile, and an OOM at the
+    ``kernel`` site walks the ladder instead of killing the query. Under
+    a trace it inlines and leaves the cache alone."""
+    import jax
+    from spark_rapids_tpu import faults
+    from spark_rapids_tpu.columnar.host import host_to_device
+    from spark_rapids_tpu.ops import kernel_cache as kc
+    from spark_rapids_tpu.ops.base import Metrics
+    from spark_rapids_tpu.ops.join import build_side
+
+    batch = host_to_device(HostBatch.from_pydict(
+        [("k", dt.INT64)], {"k": list(range(40, 0, -1))}), capacity=96)
+    m = Metrics("join")
+    # null_safe: a cache key no other test of this process has built.
+    build_side(batch, [0], null_safe=True, metrics=m)
+    assert m.values["kernelCacheMisses"] == 1 and m.values["compileTime"] > 0
+    build_side(batch, [0], null_safe=True, metrics=m)
+    assert m.values["kernelCacheHits"] == 1
+
+    faults.reset_counters()
+    faults.configure("oom@kernel:1")
+    built = build_side(batch, [0], metrics=m)
+    assert faults.counters().get("retriesAttempted", 0) >= 1
+    assert sorted(np.asarray(built.batch.columns[0].data)[:40].tolist()) \
+        == list(range(1, 41))
+    assert (np.diff(np.asarray(built.fp)[:40].astype(np.float64)) >= 0).all()
+
+    before = kc.cache().stats()
+    traced = jax.jit(lambda b: build_side(b, [0]).fp)(batch)
+    after = kc.cache().stats()
+    assert (after["hits"], after["misses"]) == \
+        (before["hits"], before["misses"])
+    assert np.array_equal(np.asarray(traced), np.asarray(built.fp))

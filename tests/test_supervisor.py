@@ -79,6 +79,45 @@ def _submit_q3(data_dir, **over):
 # Policy units (pure, no processes)
 # ---------------------------------------------------------------------------
 
+class TestWorkerEnv:
+    """A chip belongs to one process: launchers tell every worker its
+    device instead of letting it inherit whatever JAX would find."""
+
+    def test_cpu_worker_is_pinned_to_the_cpu(self):
+        from spark_rapids_tpu.parallel.cluster.worker import worker_env
+        env = worker_env("cpu", base={"JAX_PLATFORMS": "tpu,cpu",
+                                      "SRT_FAULTS": "oom@kernel:1"})
+        assert env["JAX_PLATFORMS"] == "cpu"
+        assert "SRT_FAULTS" not in env      # never inherited into a pool
+        assert "TPU_VISIBLE_CHIPS" not in env
+
+    def test_tpu_worker_gets_one_chip(self):
+        from spark_rapids_tpu.parallel.cluster.worker import worker_env
+        env = worker_env("tpu:2", base={})
+        assert env["JAX_PLATFORMS"] == "tpu"
+        assert env["TPU_VISIBLE_CHIPS"] == "2"
+        assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+
+    @pytest.mark.parametrize("bad", ["gpu", "tpu", "tpu:x", ""])
+    def test_unknown_device_is_an_error(self, bad):
+        from spark_rapids_tpu.parallel.cluster.worker import worker_env
+        with pytest.raises(ValueError):
+            worker_env(bad, base={})
+
+    def test_supervisor_spawns_cpu_workers_by_default(self, monkeypatch):
+        seen = {}
+
+        def fake_popen(cmd, env=None, cwd=None):
+            seen["env"] = env
+            return FakeProc()
+
+        monkeypatch.setattr(subprocess, "Popen", fake_popen)
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+        sup = Supervisor("127.0.0.1:1", conf=C.TpuConf({}))
+        sup._spawn_proc("w0", {})
+        assert seen["env"]["JAX_PLATFORMS"] == "cpu"
+
+
 class TestBackoffSchedule:
     def test_deterministic_exponential_with_cap(self):
         sched = [restart_backoff_ms(n, 250, 10000) for n in range(1, 9)]

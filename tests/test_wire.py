@@ -353,8 +353,9 @@ class TestCodecV2:
 
 
 class TestStagingBuffer:
-    """Packed staging uploads: one aligned buffer, one transfer, and
-    grouped tiny batches share a transfer bit-identically."""
+    """Packed staging uploads: one aligned buffer, one device_put call
+    over its typed views, and grouped tiny batches share a call
+    bit-identically."""
 
     def test_offsets_aligned_and_layout_matches(self):
         hb = HostBatch.from_pydict(
@@ -380,6 +381,25 @@ class TestStagingBuffer:
             ra = device_to_host(a, ("a", "b")).to_pylist()
             rb = device_to_host(b, ("a", "b")).to_pylist()
             assert ra == rb
+
+    @pytest.mark.parametrize("members", [1, 3])
+    def test_counters_tell_calls_from_transfers(self, members):
+        # A device_put call moves one transfer per wire ARRAY: the
+        # counters must not pass a call off as a link transfer.
+        encs = [wire.pack_batch(HostBatch.from_pydict(
+            [("a", dt.INT64), ("b", dt.FLOAT64)],
+            {"a": [i, None, i + 2], "b": [i + 0.5, 0.25 * i, None]}))
+            for i in range(members)]
+        arrays = sum(len(wire._batch_layout(e.cap, e.specs)[0])
+                     for e in encs)
+        assert arrays > members          # several arrays per batch
+        c0 = wire.counters()
+        wire.upload_packed_group(encs)
+        c1 = wire.counters()
+        moved = {k: c1.get(k, 0) - c0.get(k, 0) for k in (
+            "uploadCalls", "uploadTransfers", "uploadedBatches")}
+        assert moved == {"uploadCalls": 1, "uploadTransfers": arrays,
+                         "uploadedBatches": members}
 
     def test_plan_upload_groups(self):
         # Tiny members accumulate to the threshold; big ones ship alone.
