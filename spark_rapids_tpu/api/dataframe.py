@@ -80,10 +80,13 @@ class DataFrameReader:
         return self
 
     def _scan(self, fmt: str, paths) -> "DataFrame":
+        from spark_rapids_tpu import monitoring
         from spark_rapids_tpu.io import infer_schema
         if isinstance(paths, str):
             paths = [paths]
-        schema = infer_schema(fmt, paths, self._options)
+        # Opens a footer per table, each time a query is built anew.
+        with monitoring.span("infer-schema", "planning"):
+            schema = infer_schema(fmt, paths, self._options)
         return DataFrame(self._session,
                          L.FileScan(fmt, list(paths), schema,
                                     dict(self._options)))

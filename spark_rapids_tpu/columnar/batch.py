@@ -306,7 +306,8 @@ MIN_SHRINK_BYTES = 4 << 20
 
 
 def coalesce_iter(batches, target_rows: int, shrink: bool = False,
-                  target_bytes: int = 512 * 1024 * 1024):
+                  target_bytes: int = 512 * 1024 * 1024,
+                  owner: Optional[str] = None):
     """Group a batch stream into ~``target_rows``-capacity batches with
     minimal host syncs (grouping keys off static capacities, the exchange
     serving idiom — GpuCoalesceBatches.scala:115 done the TPU way).
@@ -325,6 +326,10 @@ def coalesce_iter(batches, target_rows: int, shrink: bool = False,
     (many-string-column) rows must not ride the row target into
     multi-GB batches (the batchSizeBytes bound, GpuCoalesceBatches'
     byte goal).
+
+    ``owner`` names the operator whose input this is, for the trace
+    (the generator runs when that operator pulls, so only here can the
+    compaction be told from the child's own work).
     """
     group: List[DeviceBatch] = []
     group_cap = 0
@@ -335,7 +340,12 @@ def coalesce_iter(batches, target_rows: int, shrink: bool = False,
         if shrink:
             # Only batches worth compacting pay a sizes pull (below the
             # threshold the kernel-time saved can't repay a round trip).
-            g, _ = shrink_all(g, min_bytes=MIN_SHRINK_BYTES)
+            # The pull is a host sync, and the shrinks are dispatched
+            # behind it into a queue run dry: one span for the idiom.
+            from spark_rapids_tpu import monitoring
+            with monitoring.op_span(owner or "coalesce", "shrink-all",
+                                    level=monitoring.LEVEL_KERNEL):
+                g, _ = shrink_all(g, min_bytes=MIN_SHRINK_BYTES)
         if len(g) == 1:
             return g[0]
         cap = bucket_capacity(sum(b.capacity for b in g))

@@ -21,8 +21,8 @@ everything engine-shaped is lazy.
 
 from spark_rapids_tpu.monitoring import history, telemetry  # noqa: F401
 from spark_rapids_tpu.monitoring.recorder import (     # noqa: F401
-    LEVEL_KERNEL, LEVEL_OPERATOR, LEVEL_QUERY, category_breakdown,
-    configure, enabled, events, export_chrome, instant, level,
-    maybe_configure, now_ns, open_span_count, process_tag, query_ids,
-    record_span, reset, set_process_tag, snapshot, span, thread_names,
-    trace_enabled)
+    LEVEL_KERNEL, LEVEL_OPERATOR, LEVEL_QUERY, adopt, category_breakdown,
+    configure, current, enabled, events, export_chrome, instant, level,
+    maybe_configure, now_ns, op_span, open_span_count, process_tag, query_ids,
+    record_span, reset, self_ns, self_times, set_process_tag, snapshot,
+    span, thread_names, trace_enabled)

@@ -14,7 +14,7 @@ Per physical node:
   with the subtree observed wall and the signed error percentage.
 
 The query footer aggregates the audit entries (Recovery/Scheduler/...)
-and, when the flight recorder is on, the span-category time breakdown —
+and, when the flight recorder is on, the span categories' self times —
 so one artifact answers "where did query N's wall-clock go".
 """
 
@@ -104,13 +104,11 @@ def render(phys, ctx) -> str:
         qid = ctx.cache.get("trace_query")
         if qid is not None:
             from spark_rapids_tpu.monitoring import recorder
-            cats: Dict[str, float] = {}
-            syncs = 0
-            for e in recorder.events(qid):
-                if e[0] == "X":
-                    cats[e[2]] = cats.get(e[2], 0.0) + e[4] / 1e6
-                    if e[2] == "sync":
-                        syncs += 1
+            # Self time: a partition span encloses its operators' spans,
+            # which enclose their syncs; each ms is counted once.
+            cats = recorder.self_times(qid)
+            syncs = sum(1 for e in recorder.events(qid)
+                        if e[0] == "X" and e[2] == "sync")
             if cats:
                 body = ", ".join(f"{c}={ms:.1f}ms"
                                  for c, ms in sorted(cats.items()))

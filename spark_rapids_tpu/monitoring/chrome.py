@@ -13,11 +13,16 @@ importer load directly. Mapping:
   spool and recovery rework land on distinct tracks.
 - spans are ``"X"`` complete events (ts/dur in microseconds, as the
   format requires), instants are ``"i"`` thread-scoped events.
+- the format has no field for a span's cause, so each event's ``args``
+  carry ``sid`` / ``parent`` (the recorder's span ids) and a span's
+  ``self_us`` (its duration less its same-thread children).
 """
 
 from __future__ import annotations
 
 from typing import Dict, List
+
+from spark_rapids_tpu.monitoring.recorder import self_ns
 
 
 def to_chrome(events: List[tuple], thread_names: Dict[int, str],
@@ -30,8 +35,9 @@ def to_chrome(events: List[tuple], thread_names: Dict[int, str],
     trace: List[dict] = []
     seen_pids = set()
     seen_tids = set()
+    own = self_ns(events)
     for e in events:
-        ph, name, cat, ts, dur, tid, qid, args = e
+        ph, name, cat, ts, dur, tid, qid, args, sid, parent = e
         if qid not in seen_pids:
             seen_pids.add(qid)
             trace.append({"ph": "M", "name": "process_name", "pid": qid,
@@ -46,12 +52,12 @@ def to_chrome(events: List[tuple], thread_names: Dict[int, str],
                               tid, f"thread-{tid}")}})
         ev = {"ph": ph, "name": name, "cat": cat, "pid": qid, "tid": tid,
               "ts": ts / 1e3}
+        ev["args"] = dict(args or (), parent=parent)
         if ph == "X":
             ev["dur"] = (dur or 0) / 1e3
+            ev["args"].update(sid=sid, self_us=own[sid] / 1e3)
         else:
             ev["s"] = "t"
-        if args:
-            ev["args"] = dict(args)
         trace.append(ev)
     return {"traceEvents": trace, "displayTimeUnit": "ms"}
 

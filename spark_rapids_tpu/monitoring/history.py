@@ -146,7 +146,7 @@ def build_record(phys, ctx, *, query_id: int, status: str,
     instants: List[list] = []
     if recorder.enabled():
         for e in recorder.events(query_id):
-            ph, name, cat, ts, dur, tid, qid, args = e
+            ph, name, cat, ts, dur, tid, qid, args = e[:8]
             if ph == "X":
                 categories[cat] = categories.get(cat, 0.0) + dur / 1e6
             else:

@@ -7,12 +7,13 @@ queue, so query wall time ~= device compute + syncs * that floor. This
 wraps every sync
 funnel (``jax.device_get``, ``ArrayImpl.__array__`` / ``__int__`` /
 ``__float__`` / ``__bool__`` / ``__index__``) and records each blocking
-read as a ``sync`` span (LEVEL_KERNEL) whose args carry the innermost
-engine call sites — the "where do the round trips come from" view a
-device trace alone does not give. The spans interleave
-with the operator/upload/shuffle spans on the same timeline, so a
-Perfetto export shows each round trip *inside* the operator that paid
-for it.
+read as a ``sync`` span (LEVEL_KERNEL) — the "where do the round trips
+come from" view a device trace alone does not give. The span's parent
+chain names the operator (or ``download``, ``upload``...) that paid for
+the round trip; its args carry the innermost engine call sites too, for
+a sync outside any span. The spans interleave with the
+operator/upload/shuffle spans on the same timeline, so a Perfetto export
+shows each round trip *inside* the operator that paid for it.
 
 Install once per process (:func:`install`); the wrappers stay resident
 but record nothing while the recorder is disabled or below
@@ -21,7 +22,7 @@ LEVEL_KERNEL, so installation is safe outside profiling runs too.
 
 from __future__ import annotations
 
-import traceback
+import sys
 from typing import Dict, List, Tuple
 
 from spark_rapids_tpu.monitoring import recorder
@@ -30,15 +31,20 @@ _INSTALLED = False
 
 
 def _site() -> str:
-    """Innermost TWO spark_rapids_tpu frames (helper + its caller)."""
+    """Innermost TWO spark_rapids_tpu frames (helper + its caller). A
+    walk over the live frames: no source line is read (55 to 78 blocking
+    reads a TPC-H query, PR 24)."""
     frames = []
-    for f in reversed(traceback.extract_stack()):
-        if "spark_rapids_tpu" in f.filename and \
-                "/monitoring/" not in f.filename:
-            short = f.filename.split("spark_rapids_tpu/")[-1]
-            frames.append(f"{short}:{f.lineno} {f.name}")
+    f = sys._getframe(2)        # past _site and the wrapper
+    while f is not None:
+        filename = f.f_code.co_filename
+        if "spark_rapids_tpu" in filename and \
+                "/monitoring/" not in filename:
+            short = filename.split("spark_rapids_tpu/")[-1]
+            frames.append(f"{short}:{f.f_lineno} {f.f_code.co_name}")
             if len(frames) == 2:
                 break
+        f = f.f_back
     return " <- ".join(frames) if frames else "<outside engine>"
 
 
@@ -70,19 +76,41 @@ def install() -> None:
     _INSTALLED = True
 
 
+def _funnel(event: tuple) -> bool:
+    return event[2] == "sync" and "site" in (event[7] or {})
+
+
+def owner(event: tuple, by_sid: Dict[int, tuple]) -> str:
+    """Who paid for a sync span: the nearest span above it that is no
+    funnel span — ``<Op>:<metric>`` for an operator's ``timed()``
+    section, ``<Op>:<name>`` for a span that names its operator
+    (``HashJoinExec:shrink-all``), else the span's name (``download``,
+    ``upload``...); the call site where no such span is open. A
+    ``sizesPullTime`` section is its own owner."""
+    up = event
+    while up is not None and _funnel(up):
+        up = by_sid.get(up[9])
+    if up is None:
+        return event[7].get("site") or "<unknown>"
+    args = up[7] or {}
+    if "metric" in args:
+        return f"{up[1]}:{args['metric']}"
+    return f"{args['op']}:{up[1]}" if args.get("op") else up[1]
+
+
 def sync_stats(query_id=None) -> Dict[str, Tuple[int, float]]:
-    """Aggregate recorded sync spans: ``label @ site`` -> (count, secs)
-    — the exact shape scripts/syncprof.py reports."""
+    """Aggregate the recorded sync spans: ``label @ owner`` -> (count,
+    secs), the shape scripts/syncprof.py reports. A ``sizesPullTime``
+    section that holds funnel spans is their owner and no sync of its
+    own; below kernel level, where no funnel records, it is the sync."""
+    evs = [e for e in recorder.events(query_id) if e[0] == "X"]
+    by_sid = {e[8]: e for e in evs}
+    holds_syncs = {e[9] for e in evs if e[2] == "sync"}
     stats: Dict[str, List[float]] = {}
-    for e in recorder.events(query_id):
-        ph, name, cat, ts, dur, tid, qid, args = e
-        if ph != "X" or cat != "sync":
+    for e in evs:
+        if e[2] != "sync" or e[8] in holds_syncs:
             continue
-        a = args or {}
-        # timed(m, "sizesPullTime") spans are syncs too — their "site"
-        # is the metric name on the owning operator.
-        site = a.get("site") or a.get("metric") or "<unknown>"
-        s = stats.setdefault(f"{name} @ {site}", [0, 0.0])
+        s = stats.setdefault(f"{e[1]} @ {owner(e, by_sid)}", [0, 0.0])
         s[0] += 1
-        s[1] += dur / 1e9
+        s[1] += e[4] / 1e9
     return {k: (int(v[0]), v[1]) for k, v in stats.items()}

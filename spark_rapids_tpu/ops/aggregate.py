@@ -1143,7 +1143,7 @@ class HashAggregateExec(Exec):
 
     def execute_device(self, ctx, partition):
         import jax as _jax
-        from spark_rapids_tpu import config as _C
+        from spark_rapids_tpu import config as _C, monitoring
         m = ctx.metrics_for(self)
         self._has_nans = bool(ctx.conf.get(_C.HAS_NANS))
         update, merge, finalize, mixed, passthrough = self._jits()
@@ -1185,7 +1185,8 @@ class HashAggregateExec(Exec):
                 effective_batch_target(
                     int(ctx.conf.get(C.BATCH_SIZE_ROWS))),
                 shrink=True,
-                target_bytes=int(ctx.conf.get(C.BATCH_SIZE_BYTES)))
+                target_bytes=int(ctx.conf.get(C.BATCH_SIZE_BYTES)),
+                owner=self.name)
         for batch in child_iter:
             saw_input = True
             if update_stage:
@@ -1200,8 +1201,10 @@ class HashAggregateExec(Exec):
                         partial = retry_on_oom(
                             update, batch, jnp.asarray(offset, jnp.int64))
                 if can_skip and skip_key not in ctx.cache:
-                    groups, live = _jax.device_get(
-                        [partial.num_rows, batch.live_count()])
+                    # A host sync that waits for the first update.
+                    with monitoring.op_span(self.name, "agg-skip-probe"):
+                        groups, live = _jax.device_get(
+                            [partial.num_rows, batch.live_count()])
                     ctx.cache[skip_key] = \
                         int(groups) >= skip_ratio * max(int(live), 1)
                 offset += batch.capacity

@@ -26,15 +26,16 @@ from spark_rapids_tpu.columnar.batch import DeviceBatch
 from spark_rapids_tpu.columnar.host import (
     HostBatch, device_to_host, host_to_device)
 from spark_rapids_tpu.config import TpuConf
+from spark_rapids_tpu.monitoring import recorder as _recorder
 
 Schema = Tuple[Tuple[str, DataType], ...]
 
 
 class Metrics:
-    """Per-operator metric registry (NvtxWithMetrics analog — ``timed``
-    additionally opens a named ``jax.profiler.TraceAnnotation`` so a
-    profile of a query shows per-operator ranges, NvtxWithMetrics.scala:
-    21-44)."""
+    """Per-operator metric registry (NvtxWithMetrics analog — with the
+    flight recorder on, ``timed`` sections are also profiler
+    annotations, so a profile of a query shows per-operator ranges,
+    NvtxWithMetrics.scala:21-44)."""
 
     def __init__(self, owner: str = ""):
         self.owner = owner
@@ -441,6 +442,7 @@ class Exec:
         catalog = get_active_catalog()
         sink = faults.get_recovery_sink()
         token = faults.get_query_token()
+        parent_span = _recorder.current()
         for attempt in range(wd.max_attempts):
             cancel = threading.Event()
             box: Dict[str, object] = {}
@@ -448,12 +450,13 @@ class Exec:
             def work():
                 # Thread-locals don't inherit: the worker needs the
                 # query's spill catalog (OOM ladder), recovery sink,
-                # query token (cancellation/owner/fault tag), and its
-                # attempt's cancel event.
+                # query token (cancellation/owner/fault tag), its
+                # attempt's cancel event, and the span it works for.
                 set_active_catalog(catalog)
                 faults.set_recovery_sink(sink)
                 faults.set_query_token(token)
                 faults.set_cancel_event(cancel)
+                _recorder.adopt(parent_span)
                 try:
                     box["out"] = fn()
                 except BaseException as e:
@@ -566,7 +569,8 @@ class Exec:
                         # joins to broadcast, and the skipped probe
                         # exchanges are flagged so the stage pass does not
                         # shuffle them anyway (parallel/replan.py).
-                        RP.plan_adaptive(ctx, self)
+                        with monitoring.span("replan", "planning"):
+                            RP.plan_adaptive(ctx, self)
                         # Independent stages (join build/probe sides...)
                         # materialize their exchange outputs concurrently
                         # before the ordered partition loop; a no-op when
@@ -632,7 +636,7 @@ class Exec:
                             finally:
                                 pipe.close()
                         with monitoring.span(
-                                "download", "device-compute",
+                                "download", "download",
                                 args={"batches": len(batches)}):
                             host_batches = download_batches(batches, names)
                     finally:
@@ -641,8 +645,9 @@ class Exec:
                 # Row materialization is pure host CPU — outside the permit,
                 # like the reference releasing GpuSemaphore once the task
                 # leaves the device.
-                for hb in host_batches:
-                    rows.extend(hb.to_pylist())
+                with monitoring.span("to-rows", "download"):
+                    for hb in host_batches:
+                        rows.extend(hb.to_pylist())
             finally:
                 collect_span.__exit__(None, None, None)
                 # Live telemetry (the hot-collect instrumentation the
@@ -683,7 +688,8 @@ class Exec:
             # no-op when tracing is off or calibration is disabled.
             try:
                 from spark_rapids_tpu.plan import cost as COST
-                COST.observe_query(ctx)
+                with monitoring.span("calibrate", "query"):
+                    COST.observe_query(ctx)
             except Exception:   # calibration must never fail a query
                 pass
         else:
@@ -787,31 +793,38 @@ _TIMED_CATS = {"bufferTime": "host-prefetch", "shuffleTime": "shuffle",
                "sizesPullTime": "sync"}
 
 
-def timed(metrics: Metrics, name: str = "totalTime"):
-    """Context manager adding elapsed ns to a metric AND opening a
-    ``jax.profiler.TraceAnnotation`` named ``<Op>:<metric>`` — a captured
-    profile (jax.profiler.trace) shows every operator's dispatch ranges
-    (NvtxWithMetrics.scala:21-44 analog). The same interval records as a
-    flight-recorder span (monitoring/recorder.py), so every operator
-    that meters itself lands on the trace timeline for free."""
-    import jax.profiler as _prof
-    from spark_rapids_tpu.monitoring import recorder as _rec
+class _Timer:
+    __slots__ = ("_metrics", "_name", "_span", "_t0")
 
-    class _Timer:
-        def __enter__(self):
-            self._ann = _prof.TraceAnnotation(
-                f"{metrics.owner or 'op'}:{name}")
-            self._ann.__enter__()
-            self._span = _rec.span(
-                metrics.owner or "op", _TIMED_CATS.get(
-                    name, "device-compute"), _rec.LEVEL_OPERATOR,
-                args=None if name == "totalTime" else {"metric": name})
-            self._span.__enter__()
-            self.t0 = time.perf_counter_ns()
+    def __init__(self, metrics: Metrics, name: str):
+        self._metrics = metrics
+        self._name = name
 
-        def __exit__(self, *exc):
-            metrics.add(name, time.perf_counter_ns() - self.t0)
+    def __enter__(self):
+        span = None
+        if _recorder.enabled():     # no label or args built when off
+            owner, name = self._metrics.owner or "op", self._name
+            span = _recorder.span(
+                owner, _TIMED_CATS.get(name, "device-compute"),
+                _recorder.LEVEL_OPERATOR,
+                args=None if name == "totalTime" else {"metric": name},
+                label=f"{owner}:{name}")
+            span.__enter__()
+        self._span = span
+        self._t0 = time.perf_counter_ns()
+
+    def __exit__(self, *exc):
+        self._metrics.add(self._name, time.perf_counter_ns() - self._t0)
+        if self._span is not None:
             self._span.__exit__(None, None, None)
-            self._ann.__exit__(None, None, None)
-            return False
-    return _Timer()
+        return False
+
+
+def timed(metrics: Metrics, name: str = "totalTime"):
+    """Context manager adding elapsed ns to a metric. The same interval
+    is a flight-recorder span (monitoring/recorder.py) and so, with the
+    recorder on, a profiler annotation ``<Op>:<metric>``: a captured
+    profile (jax.profiler.trace) shows every operator's dispatch ranges
+    (NvtxWithMetrics.scala:21-44 analog). With the recorder off it
+    annotates nothing."""
+    return _Timer(metrics, name)

@@ -482,7 +482,11 @@ class LocalLimitExec(Exec):
                 taken = min(batch.rows_hint, remaining)
                 out.rows_hint = taken
             else:
-                taken = int(out.live_count())
+                # A host sync; without a span of the limit's own the
+                # trace charges it to the exchange that pulls this chain.
+                from spark_rapids_tpu import monitoring
+                with monitoring.op_span(self.name, "limit-count"):
+                    taken = int(out.live_count())
             remaining -= taken
             yield out
 

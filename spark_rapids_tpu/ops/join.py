@@ -517,7 +517,8 @@ class _JoinKernelMixin:
         probe_iter = coalesce_iter(
             probe_iter, int(ctx.conf.get(C.BATCH_SIZE_ROWS)),
             shrink=True,
-            target_bytes=int(ctx.conf.get(C.BATCH_SIZE_BYTES)))
+            target_bytes=int(ctx.conf.get(C.BATCH_SIZE_BYTES)),
+            owner=self.name)
         # Dispatch the FIRST probe batch's upstream work before blocking on
         # the build stats: the async stats copy then overlaps probe-side
         # scan/decode instead of serializing ahead of it.

@@ -296,6 +296,7 @@ class ShuffleExchangeExec(Exec):
             return self._materialize_device_traced(ctx, key)
 
     def _materialize_device_traced(self, ctx, key):
+        from spark_rapids_tpu import monitoring
         self._ensure_bounds(ctx, device=True)
         n = self.partitioning.num_partitions
         sess = self._open_session(ctx)
@@ -313,12 +314,20 @@ class ShuffleExchangeExec(Exec):
         def flush_window(window: List[DeviceBatch]):
             from spark_rapids_tpu import faults
             faults.fault_point("exchange.flush", owner=id(self))
+            # What the exchange does itself, apart from pulling its child.
+            with monitoring.span("exchange-flush", "shuffle",
+                                 args={"batches": len(window)}):
+                _flush_window(window)
+
+        def _flush_window(window: List[DeviceBatch]):
             if n == 1:
                 # Single destination: no pids, no sort, no slices — shrink
                 # each batch to its live bucket (using hints when known)
                 # and bucket it directly.
                 from spark_rapids_tpu.columnar.batch import shrink_all
-                pieces, counts1 = shrink_all(window)
+                with monitoring.op_span(self.name, "shrink-all",
+                                        level=monitoring.LEVEL_KERNEL):
+                    pieces, counts1 = shrink_all(window)
                 for piece, cnt in zip(pieces, counts1):
                     if cnt == 0:
                         continue
@@ -630,7 +639,9 @@ class BroadcastExchangeExec(Exec):
                                                       shrink_all)
         if any(b.device_size_bytes() >= MIN_SHRINK_BYTES
                for b in batches):
-            batches, _ = shrink_all(batches)
+            with monitoring.op_span(self.name, "shrink-all",
+                                    level=monitoring.LEVEL_KERNEL):
+                batches, _ = shrink_all(batches)
         total = sum(b.capacity for b in batches)
         single = batches[0] if len(batches) == 1 else \
             concat_batches(batches, bucket_capacity(total))

@@ -203,11 +203,13 @@ class PartitionPipeline:
         self._closed = False
 
     # -- producers -----------------------------------------------------------
-    def _prefetch_task(self, partition: int, cancel) -> None:
+    def _prefetch_task(self, partition: int, cancel,
+                       parent_span: int) -> None:
         from spark_rapids_tpu import faults, monitoring
         faults.set_recovery_sink(self._sink)
         faults.set_query_token(self._token)
         faults.set_cancel_event(cancel)
+        monitoring.adopt(parent_span)
         t0 = time.perf_counter()
         try:
             if not cancel.is_set():
@@ -215,6 +217,7 @@ class PartitionPipeline:
                                      args={"partition": partition}):
                     self._source.prefetch_host(self._ctx, partition)
         finally:
+            monitoring.adopt(0)
             faults.set_cancel_event(None)
             faults.set_query_token(None)
             faults.set_recovery_sink(None)
@@ -223,12 +226,17 @@ class PartitionPipeline:
             _record(self._ctx, "prefetchedPartitions", 1)
 
     def _ensure_submitted(self, upto: int) -> None:
+        from spark_rapids_tpu import monitoring
         upto = min(upto, self._nparts - 1)
+        # The prefetch's cause: the consumer's span open now (the
+        # partition that asks for it, ``prefetchPartitions`` ahead).
+        parent_span = monitoring.current()
         while self._submitted < upto:
             self._submitted += 1
             p = self._submitted
             cancel = threading.Event()
-            fut = self._pool.submit(self._prefetch_task, p, cancel)
+            fut = self._pool.submit(self._prefetch_task, p, cancel,
+                                    parent_span)
             self._slots[p] = _Slot(fut, cancel)
 
     # -- the ordered consumer ------------------------------------------------
@@ -261,7 +269,12 @@ class PartitionPipeline:
                     return
                 except concurrent.futures.TimeoutError:
                     if fut.done():
-                        raise   # the TASK raised TimeoutError, not the poll
+                        # Either the TASK raised TimeoutError, or it
+                        # ended just after the poll gave up: asking
+                        # again returns, or raises the task's own error,
+                        # never the poll's.
+                        fut.result()
+                        return
                     # Query cancel/deadline: stop waiting, cancel the
                     # prefetch, and unwind at this ordered point — the
                     # same place a prefetch fault would have surfaced.
@@ -358,13 +371,14 @@ def prematerialize_stages(ctx, root) -> None:
                                      None))}
     if len(runnable) < 2:
         return
+    from spark_rapids_tpu import monitoring
     wd = _watchdog_params(ctx.conf)
     catalog = get_active_catalog()
     sink = faults.get_recovery_sink()
     token = faults.get_query_token()
+    parent_span = monitoring.current()
 
     def run_stage(st):
-        from spark_rapids_tpu import monitoring
 
         def materialize():
             st.boundary.stage_prematerialize(ctx)
@@ -380,9 +394,11 @@ def prematerialize_stages(ctx, root) -> None:
         set_active_catalog(catalog)
         faults.set_recovery_sink(sink)
         faults.set_query_token(token)
+        monitoring.adopt(parent_span)
         try:
             run_stage(st)
         finally:
+            monitoring.adopt(0)
             faults.set_query_token(None)
             faults.set_recovery_sink(None)
 

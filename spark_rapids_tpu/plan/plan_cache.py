@@ -466,30 +466,29 @@ def plan_or_bind(conf, logical: LogicalPlan):
         # couple independently-armed queries. Bypass, don't poison.
         _record("planCacheBypasses")
         return Planner(conf).plan(logical)
-    t0 = time.perf_counter_ns()
-    try:
-        param, values, dtypes = parameterize(logical)
-        key = (plan_key(param), _conf_key(conf))
-        hash(key)
-    except (Uncacheable, TypeError):
-        _record("planCacheUncacheable")
-        return Planner(conf).plan(logical)
-    _CACHE.configure(int(conf.get(C.PLAN_CACHE_MAX_ENTRIES)))
-    entry = _CACHE.lookup(key)
-    hit = entry is not None
-    if not hit:
-        entry = _CACHE.insert(
-            key, PlanCacheEntry(Planner(conf).plan(param), dtypes))
-    dur = time.perf_counter_ns() - t0
-    _record("planBindNs", dur)
-    if monitoring.enabled():
-        # The acceptance probe: steady-state plan+bind must stay in the
-        # low single-digit ms (vs tens-to-hundreds for a full plan).
-        monitoring.record_span(
-            "plan-bind", "planning", monitoring.now_ns() - dur, dur,
-            args={"planCacheHit": hit, "bindSlots": len(values)},
-            level=monitoring.LEVEL_QUERY)
-        monitoring.instant(
-            "plan-cache-hit" if hit else "plan-cache-miss", "planning",
-            args={"bindSlots": len(values)})
+    # The acceptance probe: steady-state plan+bind must stay in the low
+    # single-digit ms (vs tens-to-hundreds for a full plan).
+    span_args: dict = {}
+    with monitoring.span("plan-bind", "planning", args=span_args,
+                         level=monitoring.LEVEL_QUERY):
+        t0 = time.perf_counter_ns()
+        try:
+            param, values, dtypes = parameterize(logical)
+            key = (plan_key(param), _conf_key(conf))
+            hash(key)
+        except (Uncacheable, TypeError):
+            _record("planCacheUncacheable")
+            span_args["uncacheable"] = True
+            return Planner(conf).plan(logical)
+        _CACHE.configure(int(conf.get(C.PLAN_CACHE_MAX_ENTRIES)))
+        entry = _CACHE.lookup(key)
+        hit = entry is not None
+        if not hit:
+            entry = _CACHE.insert(
+                key, PlanCacheEntry(Planner(conf).plan(param), dtypes))
+        _record("planBindNs", time.perf_counter_ns() - t0)
+        span_args.update(planCacheHit=hit, bindSlots=len(values))
+    monitoring.instant(
+        "plan-cache-hit" if hit else "plan-cache-miss", "planning",
+        args={"bindSlots": len(values)})
     return BoundPlan(entry.template, values, dtypes, hit)

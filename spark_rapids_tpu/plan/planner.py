@@ -765,53 +765,55 @@ class PhysicalPlan:
             err_text = f"{type(e).__name__}: {e}"
             raise
         finally:
-            if ticket is not None:
-                # Teardown accounting BEFORE the context close captures
-                # the leak report: cancelled vs deadline-killed.
-                if ticket.token.cancelled():
-                    sched = SC.metrics_entry(ctx)
-                    if ticket.token.reason == "deadline exceeded":
-                        sched.add("deadlineKills", 1)
-                        SC._record("deadlineKills")
-                        monitoring.instant(
-                            "query-deadline-killed", "recovery",
-                            qid=trace_qid)
-                    else:
-                        sched.add("cancelled", 1)
-                        SC._record("cancelled")
-                        monitoring.instant(
-                            "query-cancelled", "recovery",
-                            args={"reason": ticket.token.reason},
-                            qid=trace_qid)
-                faults.set_query_token(None)
-                mgr.finish(ticket)
-            if qrun is not None:
-                # Retire the dispatch state and the query's spool tree
-                # BEFORE the context close: sessions opened on it are
-                # keep_on_close, so the coordinator owns this cleanup.
-                qrun.finish()
-            # Live telemetry + persistent event log, BEFORE the context
-            # close (the record reads ctx.metrics and the trace ring).
-            if ticket is not None and ticket.token.cancelled():
-                status = ("deadline"
-                          if ticket.token.reason == "deadline exceeded"
-                          else "cancelled")
-            qos_class = ticket.qos_class if ticket is not None else None
-            q_tenant = ticket.tenant if ticket is not None else None
-            dur_ms = (_time.perf_counter() - t0_query) * 1e3
-            lbls = {"class": str(qos_class or "-"),
-                    "tenant": str(q_tenant or "-")}
-            monitoring.telemetry.inc("srt_queries", status=status, **lbls)
-            monitoring.telemetry.observe("srt_query_latency_ms", dur_ms,
-                                         **lbls)
-            monitoring.history.log_query(
-                self, ctx, query_id=trace_qid, status=status,
-                qos_class=qos_class, tenant=q_tenant,
-                duration_ms=dur_ms, error=err_text)
-            # Metrics survive the collect for DataFrame.metrics().
-            self.last_ctx = ctx
-            if owned:
-                ctx.close()
+            # The device idles through the teardown: a span of its own.
+            with monitoring.span("finish", "query", qid=trace_qid):
+                if ticket is not None:
+                    # Teardown accounting BEFORE the context close captures
+                    # the leak report: cancelled vs deadline-killed.
+                    if ticket.token.cancelled():
+                        sched = SC.metrics_entry(ctx)
+                        if ticket.token.reason == "deadline exceeded":
+                            sched.add("deadlineKills", 1)
+                            SC._record("deadlineKills")
+                            monitoring.instant(
+                                "query-deadline-killed", "recovery",
+                                qid=trace_qid)
+                        else:
+                            sched.add("cancelled", 1)
+                            SC._record("cancelled")
+                            monitoring.instant(
+                                "query-cancelled", "recovery",
+                                args={"reason": ticket.token.reason},
+                                qid=trace_qid)
+                    faults.set_query_token(None)
+                    mgr.finish(ticket)
+                if qrun is not None:
+                    # Retire the dispatch state and the query's spool tree
+                    # BEFORE the context close: sessions opened on it are
+                    # keep_on_close, so the coordinator owns this cleanup.
+                    qrun.finish()
+                # Live telemetry + persistent event log, BEFORE the context
+                # close (the record reads ctx.metrics and the trace ring).
+                if ticket is not None and ticket.token.cancelled():
+                    status = ("deadline"
+                              if ticket.token.reason == "deadline exceeded"
+                              else "cancelled")
+                qos_class = ticket.qos_class if ticket is not None else None
+                q_tenant = ticket.tenant if ticket is not None else None
+                dur_ms = (_time.perf_counter() - t0_query) * 1e3
+                lbls = {"class": str(qos_class or "-"),
+                        "tenant": str(q_tenant or "-")}
+                monitoring.telemetry.inc("srt_queries", status=status, **lbls)
+                monitoring.telemetry.observe("srt_query_latency_ms", dur_ms,
+                                             **lbls)
+                monitoring.history.log_query(
+                    self, ctx, query_id=trace_qid, status=status,
+                    qos_class=qos_class, tenant=q_tenant,
+                    duration_ms=dur_ms, error=err_text)
+                # Metrics survive the collect for DataFrame.metrics().
+                self.last_ctx = ctx
+                if owned:
+                    ctx.close()
 
     def host_fallback_nodes(self) -> List[str]:
         out = []

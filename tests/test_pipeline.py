@@ -232,3 +232,59 @@ def test_no_lingering_prefetch_threads(data_dir):
             return
         time.sleep(0.05)
     assert not alive, f"pipeline threads leaked: {alive}"
+
+
+# ---------------------------------------------------------------------------
+# The ordered consumer's poll (PERF.md, PR 24: one collect in ~1,850 raised
+# a spurious TimeoutError on the parquet cell)
+# ---------------------------------------------------------------------------
+
+@requires_pipeline
+def test_poll_timeout_racing_a_finished_prefetch(data_dir, monkeypatch):
+    """The interleaving forced: every poll of a prefetch future gives up
+    only once the future is done. The consumer has to take the task's
+    result then, not hand the poll's own TimeoutError up as the task's."""
+    import concurrent.futures
+    import glob
+    from spark_rapids_tpu.plan.logical import col
+    paths = sorted(glob.glob(f"{data_dir}/lineitem/*.parquet"))
+
+    def rows():
+        s = _session(**{"spark.rapids.sql.format.scanCache.maxBytes": 0})
+        return s.read.parquet(*paths).filter(col("l_quantity") < 10) \
+            .select("l_orderkey", "l_linenumber").collect()
+
+    want = rows()
+    real = concurrent.futures.Future.result
+    held_back = []
+
+    def late_timeout(self, timeout=None):
+        if timeout is None:
+            return real(self)
+        concurrent.futures.wait([self])
+        held_back.append(self)
+        raise concurrent.futures.TimeoutError()
+
+    monkeypatch.setattr(concurrent.futures.Future, "result", late_timeout)
+    got = rows()
+    assert held_back, "no prefetch was polled: the pipeline did not run"
+    assert got == want and want
+
+
+@requires_pipeline
+def test_prefetch_task_raising_timeout_error_surfaces_it():
+    """A TimeoutError that the TASK raised is the task's, and goes up."""
+    from spark_rapids_tpu.config import TpuConf
+    from spark_rapids_tpu.ops.base import ExecContext
+
+    class Source:
+        def prefetch_host(self, ctx, partition):
+            raise TimeoutError(f"partition {partition} timed out")
+
+    ctx = ExecContext(TpuConf({}))
+    pipe = PL.PartitionPipeline(ctx, Source(), 2, PL.params_of(ctx.conf))
+    try:
+        with pytest.raises(TimeoutError, match="partition 0 timed out"):
+            pipe.consume(0, lambda: "never")
+    finally:
+        pipe.close()

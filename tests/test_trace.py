@@ -16,7 +16,11 @@ Contract under test:
   scheduler-queue / host-prefetch / device-compute / upload / shuffle
   categories on per-query, per-thread tracks;
 - ``explain_analyze`` renders observed rows/bytes/wall next to the cost
-  model's estimates with a per-node error.
+  model's estimates with a per-node error;
+- (PR 25) every span has an id and a parent: a collect's events form one
+  tree, work handed to a pool thread keeps its cause, ``self_times``
+  counts each ms once, and every enabled span is also a profiler
+  annotation on the device trace's clock (none with the recorder off).
 """
 
 import json
@@ -263,6 +267,8 @@ def _identity_check(qname, mod, ddir):
     rows_on = on.collect()
     assert rows_on == rows_off
     assert monitoring.events() != []
+    # The recorder observes the plan and never shapes it.
+    assert on.explain() == off.explain()
     off2 = mod.QUERIES[qname](_session(trace=False, scan_cache=False),
                               ddir)
     assert off2.collect() == rows_off
@@ -325,7 +331,7 @@ def test_process_tag_prefixes_exported_tracks():
     # per-process trace exports render "worker <wid> query N" tracks;
     # the untagged driver keeps the plain "query N" names.
     from spark_rapids_tpu.monitoring.chrome import to_chrome
-    evs = [("X", "stage", "cluster", 1_000, 2_000, 1, 3, None)]
+    evs = [("X", "stage", "cluster", 1_000, 2_000, 1, 3, None, 1, 0)]
     try:
         monitoring.set_process_tag("worker w7")
         doc = to_chrome(evs, {1: "t"}, monitoring.process_tag())
@@ -393,3 +399,369 @@ def test_explain_analyze_full_suite(qname, pack, data_dir, suites_dir):
     out = df.explain_analyze()
     assert "wall=" in out and "rows=" in out
     assert "est " in out and "err=" in out
+
+
+# ---------------------------------------------------------------------------
+# Span ids and parents (PR 25)
+# ---------------------------------------------------------------------------
+
+def _kernel_session(**kw):
+    from spark_rapids_tpu.monitoring import syncs
+    syncs.install()     # resident wrappers; silent below kernel level
+    s = _session(**kw)
+    s.set("spark.rapids.sql.trace.level", "kernel")
+    return s
+
+
+def _chain(e, by_sid):
+    """The spans above ``e``, innermost first."""
+    out, seen = [], set()
+    while e[9]:
+        assert e[9] not in seen, "cycle in the parent chain"
+        seen.add(e[9])
+        e = by_sid[e[9]]
+        out.append(e)
+    return out
+
+
+@pytest.mark.parametrize("qname", ["q1", "q3"])
+def test_parent_chain_is_a_tree_rooted_at_collect(qname, data_dir):
+    tpch.QUERIES[qname](_kernel_session(), data_dir).collect()
+    monitoring.reset()
+    df = tpch.QUERIES[qname](_kernel_session(), data_dir)
+    df.collect()
+    qid, evs = _query_events(df)
+    by_sid = {e[8]: e for e in _spans(evs)}
+    assert len(by_sid) == len(_spans(evs)), "span ids repeat"
+    collect, = [e for e in _spans(evs) if e[1] == "collect"]
+    syncs = [e for e in _spans(evs) if e[2] == "sync"]
+    assert syncs, "no sync recorded at kernel level"
+    for e in evs:
+        if e is collect or e[1] in ("admission-queue", "calibrate",
+                                    "finish"):
+            assert e[9] == 0    # the admission before the collect, the
+            continue            # calibration and the teardown after it
+        chain = _chain(e, by_sid)
+        assert chain and chain[-1] is collect, f"{e[1]!r} hangs loose"
+        if e[0] == "X":                 # a child lies inside its parent
+            up = chain[0]
+            if up[5] == e[5]:
+                assert up[3] <= e[3] and e[3] + e[4] <= up[3] + up[4]
+    # Every blocking read is paid for by an operator's own span (a
+    # timed() section, or the span around the operator's pull) or by the
+    # result download: none is left to a container.
+    from spark_rapids_tpu.monitoring.syncs import owner
+    owners = {owner(e, by_sid) for e in syncs}
+    assert "download" in owners
+    assert owners <= {"download", "exchange-flush",
+                      "ShuffleExchangeExec:shrink-all",
+                      "HashAggregateExec:shrink-all",
+                      "HashAggregateExec:agg-skip-probe",
+                      "HashAggregateExec:sizesPullTime",
+                      "LocalLimitExec:limit-count"}, owners
+
+
+def test_prefetch_keeps_its_cause_across_threads(data_dir):
+    """A prefetch runs on a pool thread; its parent is the consumer's
+    `partition` span that asked for it (the first partition asks for
+    ``prefetchPartitions`` ahead, so it is also the one that consumes
+    partition 0's)."""
+    import glob
+    from spark_rapids_tpu.plan.logical import col
+    paths = sorted(glob.glob(f"{data_dir}/lineitem/*.parquet"))
+    df = _kernel_session(scan_cache=False).read.parquet(*paths) \
+        .filter(col("l_quantity") < 10).select("l_orderkey")
+    df.collect()
+    qid, evs = _query_events(df)
+    by_sid = {e[8]: e for e in _spans(evs)}
+    collect, = [e for e in _spans(evs) if e[1] == "collect"]
+    prefetches = {e[7]["partition"]: e for e in _spans(evs)
+                  if e[1] == "prefetch"}
+    assert len(prefetches) == len(paths)
+    for p, e in prefetches.items():
+        up = by_sid[e[9]]
+        assert e[5] != collect[5], "prefetch ran on the collect thread"
+        assert up[1] == "partition" and up[5] == collect[5]
+        assert up[7]["partition"] <= p
+        assert _chain(e, by_sid)[-1] is collect
+    assert by_sid[prefetches[0][9]][7]["partition"] == 0
+    # what the worker does for the prefetch hangs under it
+    packs = [e for e in _spans(evs) if e[1] == "wire-pack"]
+    assert packs and all(by_sid[e[9]][1] == "prefetch" for e in packs)
+    # the pool threads carry nothing over to their next task
+    assert monitoring.current() == 0
+
+
+@pytest.mark.parametrize("qname", ["q1", "q3"])
+def test_self_times_count_each_ms_once(qname, data_dir):
+    df = tpch.QUERIES[qname](_kernel_session(), data_dir)
+    df.collect()
+    qid, evs = _query_events(df)
+    own = monitoring.self_ns(evs)
+    collect, = [e for e in _spans(evs) if e[1] == "collect"]
+    by_tid = {}
+    for e in _spans(evs):
+        assert 0 <= own[e[8]] <= e[4]
+        by_tid[e[5]] = by_tid.get(e[5], 0) + own[e[8]]
+    # one thread's self times add up to no more than the time it was
+    # inside the query (durations summed would pass it several times):
+    # the collect, and on its thread the spans before and after it
+    roots = sum(e[4] for e in _spans(evs)
+                if e[9] == 0 and e[5] == collect[5])
+    for tid, ns in by_tid.items():
+        inside = roots if tid == collect[5] else collect[4]
+        assert ns <= inside * 1.001, (tid, ns, inside)
+    assert sum(e[4] for e in _spans(evs) if e[5] == collect[5]) \
+        > collect[4]
+    cats = monitoring.self_times(qid)
+    assert sum(cats.values()) == pytest.approx(
+        sum(own.values()) / 1e6, rel=1e-9)
+    assert cats["query"] < collect[4] / 1e6
+
+
+@pytest.mark.parametrize("qname", ["q1", "q3"])
+def test_span_metric_categories_never_nest(qname, data_dir):
+    """`planning`, `download` and `compile` spans never enclose one
+    another on a thread: a category's durations summed are a time (all
+    that the benchmark's per-category totals can carry)."""
+    s = _kernel_session()
+    tpch.QUERIES[qname](s, data_dir).collect()
+    monitoring.reset()
+    tpch.QUERIES[qname](s, data_dir).collect()
+    spans = _spans(monitoring.events())     # ring 0 (planning) included
+    seen = set()
+    for cat in ("planning", "download", "compile"):
+        by_tid = {}
+        for e in spans:
+            if e[2] == cat:
+                by_tid.setdefault(e[5], []).append((e[3], e[3] + e[4]))
+                seen.add(e[1])
+        for ivs in by_tid.values():
+            ivs.sort()
+            for (_, end), (start, _) in zip(ivs, ivs[1:]):
+                assert end <= start, f"{cat} spans overlap"
+    assert {"infer-schema", "plan-bind", "replan", "download",
+            "to-rows"} <= seen
+
+
+def test_sync_stats_name_the_owner(data_dir):
+    from spark_rapids_tpu.monitoring.syncs import sync_stats
+    df = tpch.QUERIES["q1"](_kernel_session(), data_dir)
+    df.collect()
+    qid, evs = _query_events(df)
+    stats = sync_stats(qid)
+    assert any(k.endswith("@ download") for k in stats), stats
+    assert not any("<unknown>" in k or ".py:" in k for k in stats), stats
+    # a funnel inside a funnel (device_get -> __array__) counts once
+    leaves = [e for e in _spans(evs) if e[2] == "sync"
+              and e[8] not in {c[9] for c in _spans(evs)
+                               if c[2] == "sync"}]
+    assert sum(n for n, _ in stats.values()) == len(leaves)
+
+
+# ---------------------------------------------------------------------------
+# One span, two sinks: the profiler trace (PR 25)
+# ---------------------------------------------------------------------------
+
+def _host_annotations(trace_dir):
+    """line name -> [(name, start, end)] of the host plane."""
+    import glob
+    import jax.profiler
+    path, = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(path)
+    out = {}
+    for plane in data.planes:
+        if plane.name == "/host:CPU":
+            for i, ln in enumerate(plane.lines):
+                out[f"{ln.name}#{i}"] = [
+                    (e.name, e.start_ns, e.start_ns + e.duration_ns)
+                    for e in ln.events]
+    return out
+
+
+def _profiled_collect(make_df, trace_dir, name="test:query"):
+    import jax.profiler
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation(name):
+            rows = make_df().collect()
+    finally:
+        jax.profiler.stop_trace()
+    return rows
+
+
+def test_enabled_spans_annotate_the_profile(data_dir, tmp_path):
+    s = _session()
+    tpch.QUERIES["q1"](s, data_dir).collect()       # warm, configured
+    got = _profiled_collect(lambda: tpch.QUERIES["q1"](s, data_dir),
+                            tmp_path)
+    assert got
+    lines = _host_annotations(tmp_path)
+    line, = [evs for evs in lines.values()
+             if any(n == "test:query" for n, _, _ in evs)]
+    names = {n for n, _, _ in line}
+    assert {"query:collect", "download:download", "download:to-rows",
+            "planning:plan-bind", "planning:infer-schema"} <= names
+    ops = [n for n in names if n.endswith(":totalTime")]
+    assert ops and all(":" in n and "Exec" in n for n in ops), names
+    # nested on the one line, on the profiler's clock
+    (_, q0, q1), = [e for e in line if e[0] == "test:query"]
+    (_, c0, c1), = [e for e in line if e[0] == "query:collect"]
+    assert q0 <= c0 and c1 <= q1
+    for n, s0, s1 in line:
+        if n == "download:download" or n.endswith(":totalTime"):
+            assert c0 <= s0 and s1 <= c1, n
+        if n.startswith("planning:plan-bind"):
+            assert q0 <= s0 and s1 <= c0    # before the funnel
+
+
+def test_idle_owner_script_names_the_program(data_dir, tmp_path):
+    """scripts/idle_owner.py over a kept trace: the same idle gaps under
+    the three rules; the owner rule names spans of the program only."""
+    import importlib.util
+    import os
+    import jax.profiler
+    spec = importlib.util.spec_from_file_location(
+        "idle_owner", os.path.join(os.path.dirname(__file__), "..",
+                                   "scripts", "idle_owner.py"))
+    idle_owner = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(idle_owner)
+    s = _kernel_session()
+    tpch.QUERIES["q3"](s, data_dir).collect()
+    _profiled_collect(lambda: tpch.QUERIES["q3"](s, data_dir), tmp_path,
+                      name="bench:query")
+    path = idle_owner.tr.find_trace(str(tmp_path))
+    anns, idle, queries = idle_owner.idle_gaps(
+        jax.profiler.ProfileData.from_file(path))
+    assert queries == 1 and idle
+    program = [a for a in anns if idle_owner.PROGRAM.match(a[0])]
+    names = {a[0] for a in program}
+    assert {"bench:query", "query:collect", "sync:device_get",
+            "download:download"} <= names
+    assert not any("(" in n.split("[")[0] or "::" in n for n in names)
+    owners = [a for a in program if not a[0].startswith("sync:")]
+    total = sum(e - s for s, e in idle)
+    for annotations in (anns, program, owners):
+        table = idle_owner.by_label(annotations, idle)
+        assert sum(ns for _, ns in table) == pytest.approx(total)
+    assert all(idle_owner.PROGRAM.match(label) and
+               not label.startswith("sync:")
+               for label, _ in idle_owner.by_label(owners, idle))
+    # a gap split over its extent: the same idle, and the stretches of
+    # the flattened thread neither overlap nor leave the query
+    split = idle_owner.by_extent(owners, idle)
+    assert sum(ns for _, ns in split) == pytest.approx(total)
+    segs = idle_owner.segments(owners)
+    assert all(a[1] <= b[0] for a, b in zip(segs, segs[1:]))
+    (_, q0, q1), = [a for a in owners if a[0] == "bench:query"]
+    assert segs[0][0] == q0 and segs[-1][1] == q1
+    assert "query:finish" in {label for _, _, label in segs}
+    assert idle_owner.main([str(tmp_path)]) == 0
+
+
+def test_disabled_recorder_annotates_nothing(data_dir, tmp_path):
+    import gc
+    from spark_rapids_tpu.monitoring import recorder
+    s = _session(trace=False)
+    tpch.QUERIES["q1"](s, data_dir).collect()
+    assert not monitoring.enabled()
+    assert recorder._ANNOTATION is None
+    assert recorder._on_gc not in gc.callbacks
+    from jax._src import monitoring as jm
+    assert recorder._on_jax_duration not in \
+        jm.get_event_duration_listeners()
+    assert monitoring.span("a", "b") is monitoring.span("c", "d")
+    _profiled_collect(lambda: tpch.QUERIES["q1"](s, data_dir), tmp_path)
+    line, = [evs for evs in _host_annotations(tmp_path).values()
+             if any(n == "test:query" for n, _, _ in evs)]
+    ours = [n for n, _, _ in line
+            if n.split(":")[0] in ("query", "download", "planning",
+                                   "device-compute", "sync", "queued")
+            or n.endswith(":totalTime")]
+    assert ours == []
+
+
+def test_recorder_module_imports_only_the_standard_library():
+    import ast
+    import sys
+    from spark_rapids_tpu.monitoring import recorder
+    tree = ast.parse(open(recorder.__file__).read())
+    for node in tree.body:
+        if isinstance(node, ast.Import):
+            mods = [a.name for a in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            mods = [node.module]
+        else:
+            continue
+        for m in mods:
+            assert m.split(".")[0] in sys.stdlib_module_names, m
+
+
+def test_gc_inside_the_recorder_lock_cannot_deadlock():
+    """A collection can start inside ``_ring()``, with ``_LOCK`` held and
+    no ring yet for the thread's query: the callback takes no lock, and
+    the span reaches its ring with the next ordinary record."""
+    import gc
+    import threading
+    from spark_rapids_tpu.monitoring import recorder
+    monitoring.configure(True, monitoring.LEVEL_KERNEL)
+    gc.disable()        # no collection of the interpreter's own in between
+    try:
+        monitoring.reset()                      # no ring for query 0
+
+        def collection():
+            recorder._on_gc("start", {"generation": 0})
+            recorder._on_gc("stop", {"generation": 0, "collected": 7,
+                                     "uncollectable": 0})
+        with recorder._LOCK:
+            assert not recorder._RINGS
+            t = threading.Thread(target=collection, name="gc-here")
+            t.start()
+            t.join(10)
+            assert not t.is_alive(), "the gc callback waits for _LOCK"
+            recorder._on_gc("start", {"generation": 2})   # same thread
+            recorder._on_gc("stop", {"generation": 2, "collected": 1,
+                                     "uncollectable": 0})
+            assert not recorder._RINGS          # nothing touched a ring
+        with monitoring.span("after", "device-compute"):
+            pass
+        evs = monitoring.events()
+        gcs = [e for e in _spans(evs) if e[2] == "runtime"]
+        assert [e[7] for e in gcs] == [
+            {"generation": 0, "collected": 7},
+            {"generation": 2, "collected": 1}]
+        assert gcs[0][5] == t.ident and gcs[1][5] == threading.get_ident()
+        assert monitoring.thread_names()[t.ident] == "gc-here"
+        assert len({e[8] for e in _spans(evs)}) == 3
+    finally:
+        gc.enable()
+        monitoring.configure(False)
+        monitoring.reset()
+
+
+def test_compile_and_gc_record_as_spans():
+    import gc
+    import jax
+    import jax.numpy as jnp
+    monitoring.configure(True, monitoring.LEVEL_QUERY)
+    with monitoring.span("step", "device-compute",
+                         level=monitoring.LEVEL_QUERY) as step:
+        # never compiled before, and too small for the persistent cache
+        jax.jit(lambda x: x * 3.25 + 41.5)(jnp.arange(7)).block_until_ready()
+        gc.collect()
+    monitoring.configure(False)
+    spans = _spans(monitoring.events())
+    compiled = [e for e in spans if e[2] == "compile"]
+    assert compiled and all(e[1] == "backend-compile"
+                            and e[9] == step.sid for e in compiled)
+    assert all(e[4] > 0 and e[7]["fun_name"] for e in compiled)
+    full = [e for e in spans if e[2] == "runtime" and e[1] == "gc"]
+    # gc.collect()'s, and any full collection the interpreter ran itself
+    assert full and all(e[9] == step.sid for e in full)
+    assert all(e[7]["generation"] == 2 and e[7]["collected"] >= 0
+               for e in full)
+    _assert_well_formed(monitoring.events())
+    # disabled again: the listeners are gone with it
+    assert monitoring.recorder._on_gc not in gc.callbacks
