@@ -1,8 +1,8 @@
 """chip_probe.py — the small on-chip measurements the defaults and notes quote.
 
-    python scripts/chip_probe.py [sync] [link] [upload] [prefix]
+    python scripts/chip_probe.py [sync] [link] [upload] [prefix] [slots]
 
-With no section named it runs all four. One process, one chip, one JSON
+With no section named it runs all five. One process, one chip, one JSON
 object per line, every reading on the host's clock around a
 ``block_until_ready`` (on an attached chip that waits for completion):
 
@@ -20,7 +20,14 @@ object per line, every reading on the host's clock around a
 - ``prefix`` the grouped aggregate's prefix sums at 786,432 x 4, float64,
              int64 and int32, each through ``aggregate._prefix_sums`` and
              through the formulation it forks away from (first call with
-             compile, then the median of 20).
+             compile, then the median of 20);
+- ``slots``  the grouped aggregate's update on a q1-shaped batch of
+             786,432 rows (two string keys, q1's eight aggregates) holding
+             4 to 4,096 groups: the sorted path alone, the update as
+             shipped (probe, ``cond``, then slots or sort), and the update
+             with the slot limit raised to 1,024 — the table behind
+             ``aggregate._SLOT_MAX_GROUPS``; the two paths' sums are
+             compared on the way.
 
 Like ``chip_smoke.py`` it refuses any backend but a TPU unless
 ``--cpu-rehearsal`` is given, which runs the control flow at a tiny size
@@ -38,7 +45,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-SECTIONS = ("sync", "link", "upload", "prefix")
+SECTIONS = ("sync", "link", "upload", "prefix", "slots")
 LABEL = {}
 
 
@@ -221,8 +228,104 @@ def probe_prefix(jax, small: bool) -> None:
                  max_err_vs_numpy=err)
 
 
+def q1_like_aggregate():
+    """q1's grouped aggregate over (flag, status, qty, price, disc, tax):
+    the operator (``hasNans`` false, as the benchmark's cells run it) and
+    a maker of batches of ``rows`` rows in ``groups`` groups."""
+    import numpy as np
+    from spark_rapids_tpu import exprs as E
+    from spark_rapids_tpu.columnar import dtypes as dt
+    from spark_rapids_tpu.columnar.host import HostBatch, host_to_device
+    from spark_rapids_tpu.exprs.base import BoundReference as Ref, lit
+    from spark_rapids_tpu.ops import (AggSpec, Average, CountStar,
+                                      HashAggregateExec,
+                                      InMemorySourceExec, Sum)
+    schema = (("flag", dt.STRING), ("status", dt.STRING),
+              ("qty", dt.FLOAT64), ("price", dt.FLOAT64),
+              ("disc", dt.FLOAT64), ("tax", dt.FLOAT64))
+    qty, price, disc, tax = (Ref(i, dt.FLOAT64) for i in range(2, 6))
+    disc_price = E.Multiply(price, E.Subtract(lit(1.0), disc))
+    charge = E.Multiply(disc_price, E.Add(lit(1.0), tax))
+    agg = HashAggregateExec(
+        InMemorySourceExec(schema, [[]]),
+        [("flag", Ref(0, dt.STRING)), ("status", Ref(1, dt.STRING))],
+        [AggSpec("sum_qty", Sum(qty)), AggSpec("sum_base", Sum(price)),
+         AggSpec("sum_disc_price", Sum(disc_price)),
+         AggSpec("sum_charge", Sum(charge)),
+         AggSpec("avg_qty", Average(qty)),
+         AggSpec("avg_price", Average(price)),
+         AggSpec("avg_disc", Average(disc)),
+         AggSpec("count_order", CountStar(None))], mode="partial")
+    agg._has_nans = False
+
+    def batch(rows: int, groups: int, seed: int = 0):
+        rng = np.random.default_rng(seed)
+        g = rng.integers(0, groups, rows)
+        return host_to_device(HostBatch.from_pydict(schema, {
+            "flag": [f"F{i:04d}" for i in g // 2],
+            "status": ["O" if i % 2 else "F" for i in g],
+            "qty": rng.integers(1, 51, rows).astype(np.float64),
+            "price": np.round(rng.uniform(900, 105_000, rows), 2),
+            "disc": rng.integers(0, 11, rows) / 100.0,
+            "tax": rng.integers(0, 9, rows) / 100.0}))
+    return agg, batch
+
+
+def probe_slots(jax, small: bool) -> None:
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.ops import aggregate
+    rows = 3 << (8 if small else 18)
+    n = 3 if small else 20
+    shipped = aggregate._slot_limit(rows)
+    agg, make = q1_like_aggregate()
+    off = jnp.asarray(0, jnp.int64)
+    ms = lambda secs: quartiles([s * 1e3 for s in secs])
+
+    def update(limit):
+        # The limit is read while tracing: one jit per value of it.
+        def fn(b):
+            rule, aggregate._slot_limit = aggregate._slot_limit, \
+                lambda capacity: min(limit, capacity)
+            try:
+                return agg._update_batch(b, off)
+            finally:
+                aggregate._slot_limit = rule
+        return jax.jit(fn)
+
+    paths = {"sorted_alone": jax.jit(lambda b: agg._sorted_update(
+                 *agg._project_inputs(b), off)),
+             "update_as_shipped": update(shipped),
+             "update_limit_1024": update(1024)}
+    for groups in ((4, 64) if small else
+                   (4, 16, 32, 64, 128, 256, 512, 1024, 4096)):
+        b = make(rows, groups)
+        facts, outs = {}, {}
+        for name, f in paths.items():
+            t0 = time.perf_counter()
+            outs[name] = jax.block_until_ready(f(b))
+            first = time.perf_counter() - t0
+            facts[name] = {"first_call_s": round(first, 3),
+                           "steady_ms": ms(timed(lambda: f(b), n))}
+        want = outs["sorted_alone"]
+        found = int(want.num_rows)
+        gap = 0.0
+        for name, got in outs.items():
+            assert int(got.num_rows) == found, (name, got.num_rows)
+            for cw, cg in zip(want.columns, got.columns):
+                w, g = np.asarray(cw.data)[:found], np.asarray(cg.data)[:found]
+                assert (np.asarray(cw.validity) ==
+                        np.asarray(cg.validity)).all(), name
+                if cw.dtype.is_floating:
+                    gap = max(gap, float(np.max(np.abs(g - w) / np.abs(w))))
+                else:
+                    assert (w == g).all(), name   # keys in order, counts
+        emit("slots", rows=rows, groups=found, slot_limit_shipped=shipped,
+             max_rel_gap_vs_sorted=gap, **facts)
+
+
 PROBES = {"sync": probe_sync, "link": probe_link, "upload": probe_upload,
-          "prefix": probe_prefix}
+          "prefix": probe_prefix, "slots": probe_slots}
 
 
 def main(argv=None) -> int:

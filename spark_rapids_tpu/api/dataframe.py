@@ -605,7 +605,7 @@ class DataFrame:
         # tuples here.
         from spark_rapids_tpu.ops.base import audit_metric_groups
         exempt = audit_metric_groups()
-        return {k: {name: v for name, v in m.values.items()
+        return {k: {name: v for name, v in m.settle().values.items()
                     if keep is None or name in keep
                     or m.owner in exempt}
                 for k, m in ctx.metrics.items()}

@@ -35,7 +35,7 @@ def _node_metrics(ctx, op) -> dict:
     if ctx is None:
         return {}
     m = ctx.metrics.get(f"{op.name}@{id(op):x}")
-    return dict(m.values) if m is not None else {}
+    return dict(m.settle().values) if m is not None else {}
 
 
 def _wall_ns(vals: dict) -> float:
@@ -73,6 +73,11 @@ def render(phys, ctx) -> str:
         batches = vals.get("numOutputBatches")
         if batches:
             parts.append(f"batches={int(batches)}")
+        # How a grouped aggregate's update batches found their groups.
+        for short, name in (("slot", "aggSlotBatches"),
+                            ("sorted", "aggSortedBatches")):
+            if name in vals:
+                parts.append(f"{short}={int(vals[name])}")
         est = ests.get(getattr(op, "_logical_id", -1))
         if est is not None:
             obs_ms = _subtree_wall_ns(ctx, op) / 1e6

@@ -91,10 +91,12 @@ class AggFunction:
     # Sum-decomposable aggregates (Sum/Count/Average) expose their work as
     # masked value streams; HashAggregateExec stacks every stream of the
     # whole spec list into per-dtype 2D arrays and computes ALL group sums
-    # with ONE cumsum + boundary-diff per dtype (f64 scatter-adds cost
-    # ~147ms/1M on this chip; a (1M, k) cumsum costs ~48ms TOTAL —
-    # scripts/microbench.py). None = not sum-decomposable (min/max/first/
-    # last keep the per-fn segment path).
+    # with ONE prefix sum + boundary-diff per dtype on the sorted path
+    # (q1's fifteen streams over 786,432 rows: 104-113 ms a batch with
+    # the sort, on one v5e), or reduces each stream group by group where
+    # the batch holds few groups (4.3 ms at four groups; my chip run, PR
+    # 26, _SLOT_MAX_GROUPS has the table). None = not sum-decomposable
+    # (min/max/first/last keep the per-fn segment path, sorted).
     # ``has_nans`` mirrors spark.rapids.sql.hasNans: when the user asserts
     # float data is finite, the out-of-band NaN/inf occurrence streams (3
     # extra i32 cumsum columns per f64 sum) are skipped entirely.
@@ -710,6 +712,51 @@ def _two_level_prefix_sums(M: jnp.ndarray) -> jnp.ndarray:
     return (inner + before[:, None, :]).reshape(n, k)
 
 
+# The most distinct key fingerprints a batch may show and still be grouped
+# without a sort (HashAggregateExec._slot_update), fixed where slots still
+# win by 2x with room to spare. q1's eight aggregates over 786,432 rows on
+# one v5e, ms a batch, median of 20 (my chip run, PR 26:
+# ``scripts/chip_probe.py slots``):
+#
+#   groups in the batch      4     16     32     64    128    256    512   1024
+#   sorted path alone    113.3  119.1  106.8  113.9  104.2  103.7  103.5  103.3
+#   probe, cond, slots     4.3    5.4    6.9    9.8   15.7   27.3   50.8   97.3
+#
+# The slot update costs ~3.7 ms + 0.09 ms a group FOUND (a loop over the
+# groups, not over the limit), so 512 groups are the edge (2.04x). What
+# a larger limit costs is paid by the batches beyond it: the probe gives
+# up after ``limit + 1`` rounds of ~10 us each (4,096 groups behind a
+# limit of 128: 99.3 ms against 103.2 sorted alone, the fingerprints are
+# shared; behind 1,024: 108.6), 2.5 % of the sort that follows at 256.
+_SLOT_MAX_GROUPS = 256
+# ... and the rows a batch must hold for each group it is asked about. A
+# round of the probe is bound by its launches up to ~1 M rows, so a small
+# batch pays as much for it as a large one, for a sort that costs ~0.13 us
+# a row: q3's four update batches a query are of 4,096 rows and thousands
+# of groups, and 129 rounds each cost it 4.7 ms a query (`device_busy_ms`
+# 490.9 against 486.2) before this rule, 0.2 ms after (486.3; my chip
+# runs, PR 26). One round per 4,096 rows keeps the probe under 2 % of the
+# sort it may precede; a batch under 4,096 rows is not asked at all.
+_SLOT_ROWS_PER_GROUP = 4096
+
+
+def _slot_limit(capacity: int) -> int:
+    """The most groups a batch of ``capacity`` rows may show and be
+    grouped without a sort; 0: the batch is too small to ask."""
+    return min(_SLOT_MAX_GROUPS, capacity // _SLOT_ROWS_PER_GROUP)
+
+
+def _count_updates(m, partials) -> None:
+    """Operator metrics ``aggSlotBatches`` / ``aggSortedBatches`` from
+    update partials' ``(capacity, groups)``: ``_update_batch`` groups
+    without a sort exactly where it finds at most ``_slot_limit``
+    groups."""
+    for capacity, groups in partials:
+        limit = _slot_limit(capacity)
+        m.add("aggSlotBatches" if limit and int(groups) <= limit
+              else "aggSortedBatches", 1)
+
+
 class HashAggregateExec(Exec):
     """Groupby aggregate. ``mode``:
     - 'partial': emits [keys..., buffers...] for a downstream exchange
@@ -775,6 +822,15 @@ class HashAggregateExec(Exec):
         return project_batch(cols, batch), ords
 
     @staticmethod
+    def _input_col(work: DeviceBatch, ord_, mask) -> SortedCol:
+        """An aggregate's input column of the working batch, in place,
+        valid only under ``mask``; ``ord_`` None is count(*)'s."""
+        if ord_ is None:
+            return SortedCol(jnp.zeros((work.capacity,), jnp.int64), mask)
+        c = work.columns[ord_]
+        return SortedCol(c.data, c.validity & mask, c.lengths)
+
+    @staticmethod
     def _sorted_col(col: DeviceColumn, perm, slive) -> SortedCol:
         data = jnp.take(col.data, perm, axis=0)
         validity = jnp.take(col.validity, perm, axis=0) & slive
@@ -797,12 +853,12 @@ class HashAggregateExec(Exec):
         return DeviceColumn(bt, data, valid)
 
     # -- sorted-path machinery ----------------------------------------------
-    def _group_sorted(self, work: DeviceBatch):
+    def _group_sorted(self, work: DeviceBatch, fingerprints=None):
         """Group + ONE packed gather of the whole batch to group-sorted
         order (rowmove.py): per-column takes cost ~40-60ms each at 1M rows
         on this chip; the packed 2D form moves every column at once."""
         from spark_rapids_tpu.columnar.rowmove import gather_rows
-        g = kernels.group_ids(work, range(self._nkeys))
+        g = kernels.group_ids(work, range(self._nkeys), fingerprints)
         live = work.live_count()
         sorted_b = gather_rows(work, g.perm, live)
         slive = jnp.arange(work.capacity, dtype=jnp.int32) < live
@@ -863,21 +919,21 @@ class HashAggregateExec(Exec):
                 out.append(plan[1])
         return out
 
-    def _assemble(self, work: DeviceBatch, g, all_bufs) -> DeviceBatch:
+    def _assemble(self, work: DeviceBatch, leader, num_groups,
+                  all_bufs) -> DeviceBatch:
         """Key columns at group leaders (one small packed gather) + buffer
-        columns -> the output buffer batch."""
+        columns -> the output buffer batch, of ``leader``'s length."""
         from spark_rapids_tpu.columnar.rowmove import gather_rows
-        cap = work.capacity
-        gmask = jnp.arange(cap, dtype=jnp.int32) < g.num_groups
+        gmask = jnp.arange(leader.shape[0], dtype=jnp.int32) < num_groups
         out_cols: List[DeviceColumn] = []
         if self._nkeys:
             keys = gather_rows(work.select(range(self._nkeys)),
-                               g.group_leader, g.num_groups)
+                               leader, num_groups)
             out_cols.extend(keys.columns)
         for spec, bufs in zip(self.aggs, all_bufs):
             for buf, bt in zip(bufs, spec.fn.buffer_types):
                 out_cols.append(self._buf_column(buf, bt, gmask))
-        return DeviceBatch(tuple(out_cols), g.num_groups)
+        return DeviceBatch(tuple(out_cols), num_groups)
 
     def _sorted_view(self, sorted_b: DeviceBatch, ord_: int) -> SortedCol:
         c = sorted_b.columns[ord_]
@@ -887,12 +943,38 @@ class HashAggregateExec(Exec):
                       offset: jnp.ndarray) -> DeviceBatch:
         """One input batch -> partial buffer batch. ``offset`` is the global
         arrival index of this batch's row 0 (orders First/Last across the
-        stream)."""
+        stream).
+
+        How rows find their groups is chosen on the device from what the
+        batch shows: at most ``_slot_limit(capacity)`` distinct key
+        fingerprints -> :meth:`_slot_update` (no sort); more ->
+        :meth:`_sorted_update`. Both give the same groups in the same
+        order with the same leaders; a batch of at most that many groups
+        is one whose output holds at most that many rows, which is how
+        the host learns the choice where it reads a count anyway
+        (``_count_updates``)."""
         work, ords = self._project_inputs(batch)
         if self._global_ok:
             return self._global_stage(work, ords, offset, update=True)
+        limit = _slot_limit(work.capacity) if self._slot_ok else 0
+        if not limit:
+            return self._sorted_update(work, ords, offset)
+        fp = kernels.key_fingerprint(work.columns[:self._nkeys],
+                                     work.capacity)
+        live = work.row_mask()
+        pa, pb, found = kernels.smallest_fingerprints(*fp, live, limit)
+        return jax.lax.cond(
+            found > limit,
+            lambda: self._sorted_update(work, ords, offset, fp),
+            lambda: self._slot_update(work, ords, fp, live, pa[:limit],
+                                      pb[:limit], found))
+
+    def _sorted_update(self, work: DeviceBatch, ords, offset,
+                       fingerprints=None) -> DeviceBatch:
+        """Group by a stable sort of the key fingerprints, then segment
+        sums over the sorted batch: any number of groups, any spec."""
         cap = work.capacity
-        g, sorted_b, slive = self._group_sorted(work)
+        g, sorted_b, slive = self._group_sorted(work, fingerprints)
         row_index = offset.astype(jnp.int64) + g.perm.astype(jnp.int64)
         inputs = []
         for spec, ord_ in zip(self.aggs, ords):
@@ -904,7 +986,67 @@ class HashAggregateExec(Exec):
                 inputs.append(("update", self._sorted_view(sorted_b, ord_)))
         bufs = self._run_specs(inputs, g.group_of_sorted, slive, cap,
                                row_index, self._has_nans)
-        return self._assemble(work, g, bufs)
+        return self._assemble(work, g.group_leader, g.num_groups, bufs)
+
+    @property
+    def _slot_ok(self) -> bool:
+        """Grouped, and every spec is sum-decomposable: what
+        :meth:`_slot_update` can answer (First, Last, Min and Max keep
+        the sorted path alone, with no ``cond`` in the program)."""
+        return self._nkeys > 0 and all(
+            type(s.fn).sum_terms_update is not AggFunction.sum_terms_update
+            for s in self.aggs)
+
+    def _slot_update(self, work: DeviceBatch, ords, fingerprints, live,
+                     pa, pb, num_groups) -> DeviceBatch:
+        """The update for a batch of few groups, whose fingerprint pairs
+        ``(pa[i], pb[i])``, ``i < num_groups``, are known in ascending
+        order (``kernels.smallest_fingerprints``): one pass of masked
+        reductions over the batch per group found. Group i's leader is
+        its first row and its sums are, per value stream of
+        ``sum_terms_update``, the sum over the rows whose pair is the
+        i-th, each in the stream's own dtype.
+
+        The rule that makes it cheap: nothing here sorts, gathers,
+        scatters or prefix-scans an array of the batch's capacity
+        (``tests/test_agg_slots.py`` reads the jaxpr). Elementwise work
+        and reductions only; the one gather takes the keys at the
+        ``len(pa)`` leaders. The result is padded to the batch's capacity,
+        as the sorted path's is."""
+        cap = work.capacity
+        slots = pa.shape[0]
+        ha, hb = fingerprints
+        rows = jnp.arange(cap, dtype=jnp.int32)
+
+        def sums_where(mask):
+            """Per spec, the sum of each value stream over ``mask``: a
+            stream is zero where its column is not valid, so the mask
+            goes in as validity."""
+            out = []
+            for spec, ord_ in zip(self.aggs, ords):
+                terms = spec.fn.sum_terms_update(
+                    self._input_col(work, ord_, mask), self._has_nans)
+                out.append([jnp.sum(values, dtype=values.dtype)
+                            for _cls, values in terms])
+            return out
+
+        def one_group(i, acc):
+            mask = live & (ha == pa[i]) & (hb == pb[i])
+            first = jnp.min(jnp.where(mask, rows, cap))
+            return jax.tree.map(
+                lambda a, v: jax.lax.dynamic_update_index_in_dim(a, v, i, 0),
+                acc, (first, sums_where(mask)))
+
+        empty = jax.tree.map(
+            lambda v: jnp.zeros((slots,), v.dtype),
+            jax.eval_shape(lambda: (jnp.int32(0), sums_where(live))))
+        leader, sums = jax.lax.fori_loop(0, num_groups, one_group, empty)
+        bufs = [spec.fn.bufs_from_sums(s, slots, self._has_nans)
+                for spec, s in zip(self.aggs, sums)]
+        return jax.tree.map(
+            lambda x: x if x.ndim == 0 else jnp.pad(
+                x, [(0, cap - slots)] + [(0, 0)] * (x.ndim - 1)),
+            self._assemble(work, leader, num_groups, bufs))
 
     def _merge_batch(self, batch: DeviceBatch) -> DeviceBatch:
         """Merge a buffer batch (re-group by keys, merge buffers)."""
@@ -922,7 +1064,7 @@ class HashAggregateExec(Exec):
             ci += nbuf
         bufs = self._run_specs(inputs, g.group_of_sorted, slive, cap, None,
                                self._has_nans)
-        return self._assemble(batch, g, bufs)
+        return self._assemble(batch, g.group_leader, g.num_groups, bufs)
 
     def _mixed_batch(self, batch: DeviceBatch) -> DeviceBatch:
         """Distinct combo stage: input [keys..., x, nd buffers...] with
@@ -947,14 +1089,15 @@ class HashAggregateExec(Exec):
                 ci += nbuf
         bufs = self._run_specs(inputs, g.group_of_sorted, slive, cap,
                                row_index)
-        return self._assemble(batch, g, bufs)
+        return self._assemble(batch, g.group_leader, g.num_groups, bufs)
 
     # -- zero-key fast path ---------------------------------------------------
     @property
     def _global_ok(self) -> bool:
         """Zero grouping keys and every fn supports whole-batch masked
-        reductions (no sort, no segment scatters — a 1M-row f64 masked sum
-        costs ~46ms vs ~700ms through the sorted path on this chip)."""
+        reductions (no sort, no segment scatters: q1's update as masked
+        reductions over four groups takes 4.3 ms a batch of 786,432 rows
+        against 113.3 ms through the sorted path; my chip run, PR 26)."""
         if self._nkeys != 0 or self.mode == "mixed_final":
             return False
         for spec in self.aggs:
@@ -972,13 +1115,9 @@ class HashAggregateExec(Exec):
             row_index = offset.astype(jnp.int64) + \
                 jnp.arange(cap, dtype=jnp.int64)
             for spec, ord_ in zip(self.aggs, ords):
-                if ord_ is None:
-                    col = SortedCol(jnp.zeros((cap,), jnp.int64), live)
-                else:
-                    c = work.columns[ord_]
-                    col = SortedCol(c.data, c.validity & live, c.lengths)
-                all_bufs.append(spec.fn.update_global(col, row_index,
-                                                      live=live))
+                all_bufs.append(spec.fn.update_global(
+                    self._input_col(work, ord_, live), row_index,
+                    live=live))
         else:
             ci = self._nkeys
             for spec in self.aggs:
@@ -1042,12 +1181,8 @@ class HashAggregateExec(Exec):
             jnp.arange(cap, dtype=jnp.int64)
         out_cols = list(work.columns[:self._nkeys])
         for spec, ord_ in zip(self.aggs, ords):
-            if ord_ is None:
-                col = SortedCol(jnp.zeros((cap,), jnp.int64), live)
-            else:
-                c = work.columns[ord_]
-                col = SortedCol(c.data, c.validity & live, c.lengths)
-            bufs = spec.fn.update_row(col, row_index)
+            bufs = spec.fn.update_row(self._input_col(work, ord_, live),
+                                      row_index)
             for buf, bt in zip(bufs, spec.fn.buffer_types):
                 out_cols.append(self._buf_column(buf, bt, live))
         return DeviceBatch(tuple(out_cols), work.num_rows, sel=work.sel)
@@ -1070,6 +1205,7 @@ class HashAggregateExec(Exec):
         clone so a cache entry never pins the plan subtree."""
         from spark_rapids_tpu.ops import kernel_cache as kc
         key = ("agg-fns", type(self).__name__, self.mode, self._has_nans,
+               _SLOT_MAX_GROUPS, _SLOT_ROWS_PER_GROUP,
                kc.fingerprint(tuple(self.group_names)),
                kc.fingerprint(tuple(self.group_exprs)),
                kc.fingerprint(tuple(self.aggs)))
@@ -1092,8 +1228,11 @@ class HashAggregateExec(Exec):
     _CONSOLIDATE_CHUNK = 12
 
     def _consolidate(self, ctx, m, pending: List[DeviceBatch],
-                     final_stage: bool = False) -> DeviceBatch:
+                     final_stage: bool = False,
+                     fresh: int = 0) -> DeviceBatch:
         """Chunked tree of shrink + concat + merge over the pending list.
+        The last ``fresh`` of them are update partials not yet counted
+        as slot or sorted batches: the first sizes pull says which.
 
         Each level does ONE batched sizes pull for its hint-less batches
         (every host sync stalls the dispatch queue; exchange
@@ -1114,7 +1253,11 @@ class HashAggregateExec(Exec):
         batches = pending
         while True:
             with timed(m, "sizesPullTime"):
-                batches, _ = shrink_all(batches)
+                batches, counts = shrink_all(batches)
+            if level == 0 and fresh:
+                # An update's output has its input's capacity.
+                _count_updates(m, zip((b.capacity for b in pending[-fresh:]),
+                                      counts[-fresh:]))
             if len(batches) == 1:
                 single = batches[0]
                 if level == 0 and first_stage is not None:
@@ -1154,6 +1297,15 @@ class HashAggregateExec(Exec):
         saw_input = False
         offset = 0
         update_stage = self.mode in ("partial", "complete")
+        # Update partials whose choice of grouping (aggSlotBatches /
+        # aggSortedBatches) no read has told yet. Complete mode counts
+        # them at the sizes pull of the next consolidation. Partial mode
+        # has no read of its own after the skip probe, and adds none: it
+        # leaves their group counts, device scalars, to whoever reads the
+        # metrics.
+        counting = update_stage and self._slot_ok
+        uncounted = 0
+        unread: list = []
         # Adaptive partial-skip (skipAggPassReductionRatio): measure the
         # FIRST partial batch's reduction; if grouping barely reduced it,
         # later batches project rows straight into the buffer layout and
@@ -1207,6 +1359,13 @@ class HashAggregateExec(Exec):
                             [partial.num_rows, batch.live_count()])
                     ctx.cache[skip_key] = \
                         int(groups) >= skip_ratio * max(int(live), 1)
+                    if counting:
+                        _count_updates(m, [(partial.capacity, groups)])
+                elif counting and not skipping:
+                    if self.mode == "complete":
+                        uncounted += 1
+                    else:
+                        unread.append((partial.capacity, partial.num_rows))
                 offset += batch.capacity
                 if self.mode == "partial":
                     # Partial stage feeds an exchange, which batches its
@@ -1228,9 +1387,18 @@ class HashAggregateExec(Exec):
             if pending_cap > consolidate_at and len(pending) > 1 \
                     and self.mode != "mixed_final":
                 with timed(m):
-                    merged = self._consolidate(ctx, m, pending)
+                    merged = self._consolidate(ctx, m, pending,
+                                               fresh=uncounted)
+                uncounted = 0
                 pending = [merged]
                 pending_cap = merged.capacity
+        if unread:
+            name = self.name
+
+            def read_flags(metrics):
+                with monitoring.op_span(name, "slot-flags"):
+                    _count_updates(metrics, _jax.device_get(unread))
+            m.defer(read_flags)
         if self.mode == "partial":
             return
         if not saw_input:
@@ -1239,7 +1407,8 @@ class HashAggregateExec(Exec):
                 yield self._empty_result()
             return
         with timed(m):
-            acc = self._consolidate(ctx, m, pending, final_stage=True)
+            acc = self._consolidate(ctx, m, pending, final_stage=True,
+                                    fresh=uncounted)
         record_batch(m, acc)
         yield acc
 

@@ -44,10 +44,28 @@ class Metrics:
         # threads under the pipelined executor — lock it so two
         # concurrent collects can never lose counter increments.
         self._lock = threading.Lock()
+        self._deferred: list = []
 
     def add(self, name: str, amount: float):
         with self._lock:
             self.values[name] = self.values.get(name, 0) + amount
+
+    def defer(self, count) -> None:
+        """A count that only a device read can give: ``count(self)``
+        makes the read and its ``add`` calls when the metrics are next
+        read (:meth:`settle`), after the query and not inside it. It is
+        handed the registry so that it need not hold it: no cycle."""
+        with self._lock:
+            self._deferred.append(count)
+
+    def settle(self) -> "Metrics":
+        """Run what :meth:`defer` holds; ``DataFrame.metrics()`` and
+        ``explain_analyze`` read through here."""
+        with self._lock:
+            todo, self._deferred = self._deferred, []
+        for count in todo:
+            count(self)
+        return self
 
     def __repr__(self):  # pragma: no cover - cosmetic
         return f"Metrics({self.values})"

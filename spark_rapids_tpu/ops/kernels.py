@@ -215,11 +215,53 @@ class Grouping:
     #                            group's first sorted row (by group id)
 
 
-def group_ids(batch: DeviceBatch, key_ordinals: Sequence[int]) -> Grouping:
-    """Assign dense group ids over the key columns (cuDF groupBy analog)."""
+def smallest_fingerprints(ha: jnp.ndarray, hb: jnp.ndarray,
+                          live: jnp.ndarray, limit: int):
+    """The distinct ``(ha, hb)`` pairs among live rows in ascending order,
+    as far as the first ``limit + 1``: ``(pa, pb, found)`` with ``pa`` and
+    ``pb`` of ``limit + 1`` entries and ``found`` <= ``limit + 1`` of them
+    set. ``found == limit + 1`` says the batch holds MORE than ``limit``
+    groups. Each round is two masked min-reductions ("the smallest pair
+    above the last one found") and the loop ends with the batch's last
+    pair: a batch of four groups pays five rounds, one of thousands
+    ``limit + 1``. No sort, nothing moved."""
+    top = jnp.uint32(0xFFFFFFFF)
+
+    def wanted(state):
+        found, more = state[0], state[1]
+        return more & (found <= limit)
+
+    def next_pair(state):
+        found, _, la, lb, pa, pb = state
+        above = (found == 0) | (ha > la) | ((ha == la) & (hb > lb))
+        cand = live & above
+        a = jnp.min(jnp.where(cand, ha, top))
+        b = jnp.min(jnp.where(cand & (ha == a), hb, top))
+        more = jnp.any(cand)
+        # A round that finds nothing writes past the pairs found: unread.
+        pa = jax.lax.dynamic_update_index_in_dim(pa, a, found, 0)
+        pb = jax.lax.dynamic_update_index_in_dim(pb, b, found, 0)
+        return found + more.astype(jnp.int32), more, a, b, pa, pb
+
+    none = jnp.zeros((limit + 1,), jnp.uint32)
+    found, _, _, _, pa, pb = jax.lax.while_loop(
+        wanted, next_pair,
+        (jnp.int32(0), jnp.bool_(True), jnp.uint32(0), jnp.uint32(0),
+         none, none))
+    return pa, pb, found
+
+
+def group_ids(batch: DeviceBatch, key_ordinals: Sequence[int],
+              fingerprints: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None
+              ) -> Grouping:
+    """Assign dense group ids over the key columns (cuDF groupBy analog).
+    ``fingerprints``: the keys' ``key_fingerprint``, where the caller has
+    it already."""
     cap = batch.capacity
-    cols = [batch.columns[i] for i in key_ordinals]
-    ha, hb = key_fingerprint(cols, cap)
+    if fingerprints is None:
+        cols = [batch.columns[i] for i in key_ordinals]
+        fingerprints = key_fingerprint(cols, cap)
+    ha, hb = fingerprints
     live = batch.row_mask()
     # Sort rows by (live desc, ha, hb): padding last.
     passes = [jnp.where(live, jnp.uint32(0), jnp.uint32(0xFFFFFFFF)), ha, hb]
@@ -236,7 +278,7 @@ def group_ids(batch: DeviceBatch, key_ordinals: Sequence[int]) -> Grouping:
     gid = jnp.cumsum(new_seg.astype(jnp.int32)) - 1
     # Padding rows go to the last slot (their writes are masked downstream).
     gid = jnp.where(slive, gid, jnp.int32(max(cap - 1, 0)))
-    num_groups = jnp.sum(new_seg.astype(jnp.int32))
+    num_groups = jnp.sum(new_seg, dtype=jnp.int32)
     # Leader: original row index of each group's first sorted row.
     leader = jnp.zeros((cap,), jnp.int32).at[
         jnp.where(new_seg, gid, cap)].set(perm, mode="drop")

@@ -71,14 +71,14 @@ def _jit_map_pressure_relief():
     (kernels recompile transparently — slow, but alive)."""
     yield
     import gc
-    if _map_count() > 52000:
+    if _map_count() > 44000:
         from spark_rapids_tpu.ops import kernel_cache as kc
         cache = kc.cache()
         bound = cache.max_entries
         cache.configure(max(bound // 2, 64))
         cache.configure(bound)
         gc.collect()
-        if _map_count() > 61000:
+        if _map_count() > 52000:
             import jax
             jax.clear_caches()
             gc.collect()

@@ -185,6 +185,27 @@ def test_filter_and_global_aggregate(cap, one_chip):
     _compile(step, one_chip, _lineitem_like(cap))
 
 
+def test_grouped_aggregate_update(one_chip):
+    """q1's grouped update at a reader batch's 786,432 rows: the probe
+    for the batch's distinct keys, the ``cond``, and both branches (the
+    slot loops and the sort) in one program. One capacity: the sort in
+    it takes the chip's compiler half a minute."""
+    import os
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                    "scripts"))
+    from chip_probe import q1_like_aggregate
+    agg, make = q1_like_aggregate()
+    cap = 3 << 18
+    batch = jax.tree.map(
+        lambda x: np.zeros((cap,) + x.shape[1:], x.dtype) if x.ndim
+        else np.asarray(cap, x.dtype), make(8, 4))
+    out = _compile(lambda b: agg._update_batch(b, jnp.asarray(0, jnp.int64)),
+                   one_chip, batch)
+    text = out.as_text()
+    assert "conditional(" in text and "while(" in text
+
+
 def test_radix_permutation(one_chip):
     """The LSD radix argsort every sort and grouping shares
     (ops/kernels.py _radix_perm), one key word: two stable u32 argsort
