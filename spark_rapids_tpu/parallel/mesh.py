@@ -40,6 +40,11 @@ DATA_AXIS = "data"
 
 def make_mesh(n_devices: Optional[int] = None,
               axis: str = DATA_AXIS) -> Mesh:
+    """A one-axis mesh over ``jax.devices()``, which lists the process's
+    devices in id order: all of them, or the first ``n_devices``. The
+    four chips of one 2x2 v5e host give a ``data`` axis of 4 (ids 0-3);
+    the host's 2x2 ICI layout is not an axis of its own, the one
+    collective here (``all_to_all``) runs over all four."""
     devs = jax.devices()
     if n_devices is not None:
         devs = devs[:n_devices]

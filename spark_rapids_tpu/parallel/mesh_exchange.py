@@ -1,7 +1,7 @@
 """Planner-integrated collective shuffle: ShuffleExchangeExec lowered onto
 a jax.sharding.Mesh (VERDICT r1 item 4).
 
-When ``spark.rapids.sql.mesh.enabled`` is on, the planner emits
+With ``spark.rapids.sql.shuffle.transport=mesh`` the planner emits
 ``MeshExchangeExec`` for hash shuffles instead of the single-process
 materialized exchange: child partitions become one uniform-shape shard per
 mesh device, ONE jitted ``shard_map`` program runs the split +
@@ -11,19 +11,35 @@ GpuShuffleExchangeExec.scala:69,145), and each output partition serves its
 device's post-exchange shard to the normal per-partition operator stream
 above. Operators (aggregate final stage, shuffled join) compose unchanged.
 
-Single real chip degenerates to n=1; the 8-virtual-CPU-device mesh in
-tests/conftest.py exercises the real collective path.
+Today every post-exchange shard is moved to ``jax.devices()[0]``
+(``_addressable_parts``) and every operator above an exchange runs there:
+the other chips take part in the collectives alone (``meshLandedBytes``
+counts what lands where; ROADMAP B1 is the change that leaves a partition
+where its shard lies).
+
+A single chip degenerates to n=1; the tests run the real collective path
+on the virtual CPU devices they ask for (``tests/conftest.py``).
+
+What one exchange costs is visible as spans of the category
+``mesh-exchange`` (``shard``, ``pids``, ``counts``, ``collective``,
+``land``, ``unfold``; docs/observability.md) and as the counters
+``meshLiveBytes`` / ``meshWireBytes`` / ``meshLandedBytes.dev<i>``, on the
+operator's ``Metrics`` and process-wide (:func:`counters`).
 """
 
 from __future__ import annotations
 
-from typing import List, Optional
+import collections
+import functools
+import threading
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from spark_rapids_tpu import monitoring
 from spark_rapids_tpu.columnar.batch import (
     DeviceBatch, DeviceColumn, bucket_capacity, string_repad)
 from spark_rapids_tpu.ops.base import Exec, ExecContext, Schema, timed
@@ -52,6 +68,119 @@ def mesh_size() -> int:
 # ~1 ms on an attached chip and has not been re-measured (ROADMAP A3).
 # Module-level so tests can lower it.
 TWO_PHASE_MIN_SHARD_ROWS = 1 << 18
+
+
+# -- counters -----------------------------------------------------------------
+# Process-wide totals beside each operator's ``Metrics``
+# (benchmark/metrics/mesh_exchange_padding_pct.py reads the ratio of two).
+# ``meshLiveBytes`` and ``meshWireBytes`` count an exchange together, once
+# its live rows are known on the host: at once where the two-phase path
+# pulled the counts matrix anyway, else when a counter is next read. Until
+# then they are the landed shards' ``num_rows``, device scalars in
+# ``_UNREAD``: no exchange makes a blocking read for a counter's sake. An
+# exchange that falls off the end of ``_UNREAD`` unread is in neither
+# total, so their ratio stays a ratio.
+_COUNTER_LOCK = threading.Lock()
+_COUNTERS: Dict[str, int] = {}
+_UNREAD: collections.deque = collections.deque(maxlen=4096)
+
+
+def _add_counters(counts: Dict[str, int]) -> None:
+    with _COUNTER_LOCK:
+        for k, v in counts.items():
+            _COUNTERS[k] = _COUNTERS.get(k, 0) + v
+
+
+def counters() -> Dict[str, int]:
+    """``meshExchanges``, ``meshLiveBytes``, ``meshWireBytes`` and
+    ``meshLandedBytes.dev<id>`` of this process. Reads what the exchanges
+    since the last call left on the device."""
+    while _UNREAD:
+        try:
+            read = _UNREAD.popleft()
+        except IndexError:          # another reader took it
+            break
+        _add_counters(read())
+    with _COUNTER_LOCK:
+        return dict(_COUNTERS)
+
+
+def reset_counters() -> None:
+    _UNREAD.clear()
+    with _COUNTER_LOCK:
+        _COUNTERS.clear()
+
+
+def _row_width(shard: DeviceBatch) -> int:
+    """Bytes one row takes in the collective's arrays: every column's
+    data, validity and (strings) lengths, as decoded on the device."""
+    return sum(x.dtype.itemsize * int(np.prod(x.shape[1:], dtype=np.int64))
+               for x in tree_flatten(shard.columns)[0])
+
+
+def _tally(rows, landed_on: List[int], row_width: int,
+           wire_bytes: int) -> Dict[str, int]:
+    """One exchange's counters from the rows of each landed shard and
+    the device it lies on."""
+    out = {"meshLiveBytes": 0, "meshWireBytes": wire_bytes}
+    for r, d in zip(rows, landed_on):
+        out["meshLiveBytes"] += int(r) * row_width
+        key = f"meshLandedBytes.dev{d}"
+        out[key] = out.get(key, 0) + int(r) * row_width
+    return out
+
+
+class _UnreadExchange:
+    """An exchange whose live rows are still device scalars (no counts
+    matrix was pulled): read once, under ``<Op>:landed-rows``, when the
+    operator's metrics or the process's counters are first read, and the
+    scalars are let go."""
+
+    __slots__ = ("_rows", "_tally", "_op", "_counts")
+
+    def __init__(self, rows, tally, op: str):
+        self._rows, self._tally, self._op = rows, tally, op
+        self._counts: Optional[Dict[str, int]] = None
+
+    def __call__(self) -> Dict[str, int]:
+        if self._counts is None:
+            with monitoring.op_span(self._op, "landed-rows"):
+                self._counts = self._tally(jax.device_get(self._rows))
+            self._rows = None
+        return self._counts
+
+    def settle(self, metrics) -> None:
+        for k, v in self().items():
+            metrics.add(k, v)
+
+
+def _count_exchange(m, landed_rows, landed_on: List[int], row_width: int,
+                    wire_bytes: int) -> None:
+    """One exchange into the operator's and the process's counters.
+    ``landed_rows[i]`` are the rows of the shard that landed on device
+    ``landed_on[i]``: ints where the counts matrix was pulled, else
+    device scalars, read when a counter is next read and not before."""
+    tally = functools.partial(_tally, landed_on=landed_on,
+                              row_width=row_width, wire_bytes=wire_bytes)
+    _add_counters({"meshExchanges": 1})
+    if all(isinstance(r, int) for r in landed_rows):
+        counts = tally(landed_rows)
+        for k, v in counts.items():
+            m.add(k, v)
+        _add_counters(counts)
+        return
+    unread = _UnreadExchange(landed_rows, tally, m.owner or "MeshExchangeExec")
+    m.defer(unread.settle)
+    _UNREAD.append(unread)
+
+
+def _phase(name: str):
+    """A span of the category ``mesh-exchange`` (profiler annotation
+    ``mesh-exchange:<name>``). The phases are never nested in one another
+    and never enclose the pull of the child, so the category's sum is a
+    time. It is a host clock over asynchronous dispatch: a phase that
+    blocks (``counts``) also holds the wait for the child's device work."""
+    return monitoring.span(name, "mesh-exchange")
 
 
 def _uniform_shards(batches_per_dev: List[List[DeviceBatch]],
@@ -279,8 +408,9 @@ class MeshExchangeExec(Exec):
             try:
                 from spark_rapids_tpu import faults
                 faults.fault_point("mesh.exchange", owner=id(self))
-                shards = _uniform_shards(per_dev, self.schema)
-                stacked = M.shard_batches(mesh, shards)
+                with _phase("shard"):
+                    shards = _uniform_shards(per_dev, self.schema)
+                    stacked = M.shard_batches(mesh, shards)
                 # Two-phase sizes-then-data (SURVEY §7 hard part 6):
                 # exchange per-destination COUNTS first (a (n,n) int32
                 # collective + one host pull), size the data collective's
@@ -291,24 +421,30 @@ class MeshExchangeExec(Exec):
                 # only cost.
                 from spark_rapids_tpu.ops import kernel_cache as kc
                 mkey = self._mesh_key(mesh)
-                pids_fn = kc.lookup("mesh-pids", mkey,
-                                    lambda: self._pids_step(mesh), m)
-                pids = pids_fn(stacked)
-                piece_cap = None
+                with _phase("pids"):
+                    pids_fn = kc.lookup("mesh-pids", mkey,
+                                        lambda: self._pids_step(mesh), m)
+                    pids = pids_fn(stacked)
+                piece_cap = landed_rows = None
                 if n > 1 and shards[0].capacity >= \
                         TWO_PHASE_MIN_SHARD_ROWS:
-                    counts_fn = kc.lookup(
-                        "mesh-counts", mkey + (fold,),
-                        lambda: self._counts_step(mesh, n, fold), m)
-                    counts = np.asarray(counts_fn(stacked, pids))
+                    with _phase("counts"):
+                        counts_fn = kc.lookup(
+                            "mesh-counts", mkey + (fold,),
+                            lambda: self._counts_step(mesh, n, fold), m)
+                        # row d: what device d receives from each peer
+                        counts = np.asarray(counts_fn(stacked, pids))
+                    landed_rows = counts.sum(axis=1).tolist()
                     piece_cap = bucket_capacity(max(int(counts.max()), 1))
                     if piece_cap >= shards[0].capacity:
                         piece_cap = None  # padding wouldn't shrink
-                step = kc.lookup(
-                    "mesh-exchange", mkey + (fold, piece_cap),
-                    lambda: self._build_step(mesh, n, fold,
-                                             piece_capacity=piece_cap), m)
-                out = step(stacked, pids)
+                with _phase("collective"):
+                    step = kc.lookup(
+                        "mesh-exchange", mkey + (fold, piece_cap),
+                        lambda: self._build_step(mesh, n, fold,
+                                                 piece_capacity=piece_cap),
+                        m)
+                    out = step(stacked, pids)
                 # Where the collective left its output: one shard per
                 # mesh device — before _addressable_parts moves them all
                 # to device 0 for the single-process operator stream
@@ -318,7 +454,16 @@ class MeshExchangeExec(Exec):
                 m.add("meshShardDevices", len(
                     {s.device for s in
                      tree_flatten(out)[0][0].addressable_shards}))
-                parts = _addressable_parts(out, n)
+                with _phase("land"):
+                    parts = _addressable_parts(out, n)
+                if landed_rows is None:     # no counts pulled: deferred
+                    landed_rows = [p.num_rows for p in parts]
+                row_width = _row_width(shards[0])
+                _count_exchange(
+                    m, landed_rows,
+                    [next(iter(p.num_rows.devices())).id for p in parts],
+                    row_width,
+                    n * n * (piece_cap or shards[0].capacity) * row_width)
             except Exception as err:
                 if not bool(ctx.conf.get(C.MESH_DEGRADE_ENABLED)):
                     raise
@@ -340,17 +485,18 @@ class MeshExchangeExec(Exec):
             # logical partitions (the pids recompute is one murmur pass
             # over the received rows — received shards are dense, so
             # this is row-proportional, not capacity-proportional).
-            for d in range(n):
-                lo = d * fold
-                cnt = min(np_parts - lo, fold)
-                if cnt <= 0:
-                    continue
-                shard = parts[d]
-                shard_pids = self.partitioning.partition_ids(shard)
-                live = shard.row_mask()
-                for j in range(cnt):
-                    keep = (shard_pids == lo + j) & live
-                    sess.write_shard(lo + j, shard.compact(keep))
+            with _phase("unfold"):
+                for d in range(n):
+                    lo = d * fold
+                    cnt = min(np_parts - lo, fold)
+                    if cnt <= 0:
+                        continue
+                    shard = parts[d]
+                    shard_pids = self.partitioning.partition_ids(shard)
+                    live = shard.row_mask()
+                    for j in range(cnt):
+                        keep = (shard_pids == lo + j) & live
+                        sess.write_shard(lo + j, shard.compact(keep))
         sess.commit()
         ctx.cache[key] = sess
         return sess
