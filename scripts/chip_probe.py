@@ -1,8 +1,8 @@
 """chip_probe.py — the small on-chip measurements the defaults and notes quote.
 
-    python scripts/chip_probe.py [sync] [link] [upload] [prefix] [slots]
+    python scripts/chip_probe.py [sync] [link] [upload] [prefix] [slots] [rowmove]
 
-With no section named it runs all five. One process, one chip, one JSON
+With no section named it runs all six. One process, one chip, one JSON
 object per line, every reading on the host's clock around a
 ``block_until_ready`` (on an attached chip that waits for completion):
 
@@ -27,7 +27,21 @@ object per line, every reading on the host's clock around a
              shipped (probe, ``cond``, then slots or sort), and the update
              with the slot limit raised to 1,024 — the table behind
              ``aggregate._SLOT_MAX_GROUPS``; the two paths' sums are
-             compared on the way.
+             compared on the way;
+- ``rowmove`` the packed row movers of ``columnar/rowmove.py`` on a batch
+             shaped like TPC-H Q5's big mesh shards (three int32, two
+             int16, four int64, two float64 columns: 59 bytes + validity
+             a row) at N = 16,384, 262,144 and 1,572,864 rows, ~98 % and
+             ~25 % live: the compaction as a slab SCATTER (the form the
+             movers had until PR 30, kept here alone), the same with
+             ``unique_indices``, and as shipped (index scatter + slab
+             GATHER); each kind of column alone at the largest N; what
+             ONE gather costs by dtype and width (why the slabs are uint32
+             words, 16 a slab); then the mesh's split into four pieces
+             (four compactions + stack against ``split_batch``'s one pass)
+             and the concat of four members, at the shard and at a
+             two-phase piece capacity — the table behind the module's
+             docstring.
 
 Like ``chip_smoke.py`` it refuses any backend but a TPU unless
 ``--cpu-rehearsal`` is given, which runs the control flow at a tiny size
@@ -45,7 +59,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-SECTIONS = ("sync", "link", "upload", "prefix", "slots")
+SECTIONS = ("sync", "link", "upload", "prefix", "slots", "rowmove")
 LABEL = {}
 
 
@@ -58,6 +72,11 @@ def quartiles(xs):
     return {"n": len(xs), "min": xs[0], "p25": xs[len(xs) // 4],
             "median": statistics.median(xs), "p75": xs[(3 * len(xs)) // 4],
             "max": xs[-1]}
+
+
+def ms(secs):
+    """Quartiles of timings in milliseconds."""
+    return quartiles([s * 1e3 for s in secs])
 
 
 def timed(fn, n: int):
@@ -165,7 +184,6 @@ def probe_upload(jax, small: bool) -> None:
     }
     for fn in shapes.values():
         jax.block_until_ready(fn())
-    ms = lambda secs: quartiles([s * 1e3 for s in secs])
     emit("upload", rows=rows, wire_arrays=len(arrays),
          array_bytes=sorted(a.nbytes for a in arrays),
          encoded_bytes=nbytes, staging_bytes=int(enc.nbytes),
@@ -280,7 +298,6 @@ def probe_slots(jax, small: bool) -> None:
     shipped = aggregate._slot_limit(rows)
     agg, make = q1_like_aggregate()
     off = jnp.asarray(0, jnp.int64)
-    ms = lambda secs: quartiles([s * 1e3 for s in secs])
 
     def update(limit):
         # The limit is read while tracing: one jit per value of it.
@@ -324,8 +341,168 @@ def probe_slots(jax, small: bool) -> None:
              max_rel_gap_vs_sorted=gap, **facts)
 
 
+def q5_shard_like(rows: int, live_share: float, classes=("w8", "i64", "f64"),
+                  seed: int = 0):
+    """A device batch of ``rows`` capacity with the columns of q5's big
+    mesh shards (until PR 30 the slabs ``u8[N,27]`` = three int32 and two
+    int16 columns and the eleven validity bytes, ``i64[N,4]``,
+    ``f64[N,2]``), all rows in the prefix and ``live_share`` of them
+    selected; ``classes`` keeps one kind of column alone."""
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.columnar import dtypes as dt
+    from spark_rapids_tpu.columnar.batch import DeviceBatch, DeviceColumn
+    rng = np.random.default_rng(seed)
+    kinds = {"i64": [dt.INT64] * 4, "f64": [dt.FLOAT64] * 2,
+             "w8": [dt.INT32] * 3 + [dt.INT16] * 2}
+    cols = []
+    for cls in classes:
+        for t in kinds[cls]:
+            if t is dt.FLOAT64:
+                data = rng.uniform(0, 1e5, rows)
+            else:
+                data = rng.integers(0, 30000, rows).astype(t.np_dtype)
+            cols.append(DeviceColumn(t, jnp.asarray(data),
+                                     jnp.asarray(rng.random(rows) < 0.97)))
+    sel = jnp.asarray(rng.random(rows) < live_share)
+    return DeviceBatch(tuple(cols), jnp.asarray(rows, jnp.int32), sel=sel)
+
+
+def scatter_compact(batch, keep=None, unique: bool = False):
+    """``compact_batch`` as it was until PR 30: the slabs themselves are
+    scattered to the live rows' ranks. ``unique`` promises XLA distinct
+    indices, for which the dead rows get distinct out-of-range targets."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar.rowmove import pack_batch, unpack_batch
+    cap = batch.capacity
+    live = batch.row_mask() if keep is None else (keep & batch.row_mask())
+    rank = jnp.cumsum(live.astype(jnp.int32)) - 1
+    dead = cap + jnp.arange(cap, dtype=jnp.int32) if unique else cap
+    positions = jnp.where(live, rank, dead)
+    out = {k: jnp.zeros_like(slab).at[positions].set(
+               slab, mode="drop", unique_indices=unique)
+           for k, slab in pack_batch(batch).items()}
+    return unpack_batch(out, batch, jnp.sum(live.astype(jnp.int32)))
+
+
+def gather_by_shape(jax, small: bool, top: int, n: int) -> None:
+    """What ONE slab gather costs by dtype, width and the layout the
+    compiler gives it (read from the compiled HLO), at ``top`` rows and at
+    half of that, for an index list as a 98 % live compaction makes it."""
+    import re
+    import jax.numpy as jnp
+    import numpy as np
+    take = jax.jit(lambda slab, idx: jnp.take(slab, idx, axis=0,
+                                              mode="clip"))
+    for rows in (top // 2, top):
+        live = np.random.default_rng(2).random(rows) < 0.98
+        idx = jnp.asarray(np.resize(np.flatnonzero(live), rows), jnp.int32)
+        for dtn, widths in (("uint8", (27, 31, 59, 64, 128)),
+                            ("uint32", (4, 7, 8, 15, 16, 32, 128)),
+                            ("int64", (4,)), ("float64", (0, 2, 5, 8, 16))):
+            for w in widths[:2] if small else widths:
+                slab = jnp.ones((rows, w) if w else (rows,), dtn)
+                hlo = take.lower(slab, idx).compile().as_text()
+                layouts = sorted({m for line in hlo.splitlines()
+                                  if "gather" in line
+                                  for m in re.findall(
+                                      r"= (\S+\[%d[,\]]\S*) \w+\(" % rows, line)})
+                jax.block_until_ready(take(slab, idx))
+                emit("rowmove", mover="gather_by_shape", rows=rows,
+                     dtype=dtn, width=w, layouts=layouts,
+                     steady_ms=ms(timed(lambda: take(slab, idx), n)))
+                del slab
+
+
+def probe_rowmove(jax, small: bool) -> None:
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.columnar.batch import (DeviceBatch,
+                                                 bucket_capacity,
+                                                 concat_batches)
+    from spark_rapids_tpu.columnar.rowmove import (compact_batch,
+                                                   pack_batch, unpack_batch)
+    from spark_rapids_tpu.parallel.partitioning import split_batch
+    from spark_rapids_tpu.shims import tree_map
+    n = 3 if small else 10
+    sizes = (256, 1024) if small else (16384, 262144, 1572864)
+
+    def race(what, forms, *args, **facts):
+        """First call (with compile) and steady times of each form on the
+        same arguments; every form's answer must equal the first's."""
+        outs, read = {}, {}
+        for name, fn in forms.items():
+            f = jax.jit(fn)
+            t0 = time.perf_counter()
+            outs[name] = jax.block_until_ready(f(*args))
+            first = time.perf_counter() - t0
+            read[name] = {"first_call_s": round(first, 3),
+                          "steady_ms": ms(timed(lambda: f(*args), n))}
+        want = jax.tree_util.tree_leaves(next(iter(outs.values())))
+        for name, got in outs.items():      # num_rows is a leaf too
+            for x, y in zip(want, jax.tree_util.tree_leaves(got)):
+                assert (np.asarray(x) == np.asarray(y)).all(), (what, name)
+        emit("rowmove", mover=what, **facts, **read)
+
+    compacts = {"scatter": scatter_compact,
+                "scatter_unique": lambda b: scatter_compact(b, unique=True),
+                "gather_as_shipped": compact_batch}
+    for rows in sizes:
+        for share in (0.98, 0.25):
+            race("compact", compacts, q5_shard_like(rows, share),
+                 rows=rows, live_share=share, columns="w8+i64+f64")
+    for cls in ("w8", "i64", "f64"):
+        race("compact", compacts, q5_shard_like(sizes[-1], 0.98, (cls,)),
+             rows=sizes[-1], live_share=0.98, columns=cls)
+
+    gather_by_shape(jax, small, sizes[-1], n)
+
+    # The mesh's send side (four destinations) and its receive side.
+    rows, parts = sizes[-1], 4
+    shard = compact_batch(q5_shard_like(rows, 0.98))
+    pids = jnp.asarray(np.random.default_rng(1).integers(0, parts, rows),
+                       jnp.int32)
+    for pc in (None, bucket_capacity(int(rows * 0.98 / parts * 1.02))):
+        def four_passes(b, pids, pc=pc):
+            pieces = [scatter_compact(b, pids == p) for p in range(parts)]
+            if pc is not None:
+                pieces = [DeviceBatch(
+                    tree_map(lambda x: x[:pc], p.columns),
+                    jnp.minimum(p.num_rows, pc)) for p in pieces]
+            return tree_map(lambda *xs: jnp.stack(xs), *pieces)
+        race("split", {"four_scatter_passes": four_passes,
+                       "one_pass_as_shipped":
+                           lambda b, pids, pc=pc: split_batch(b, pids, parts,
+                                                              pc)},
+             shard, pids, rows=rows, pieces=parts, piece_capacity=pc or rows)
+        members = [tree_map(lambda x, i=i: x[i],
+                            split_batch(shard, pids, parts, pc))
+                   for i in range(parts)]
+        cap = bucket_capacity(sum(m.capacity for m in members))
+
+        def scatter_concat(bs, cap=cap):
+            out, off = {}, jnp.asarray(0, jnp.int32)
+            for b in bs:
+                live = b.row_mask()
+                pos = jnp.where(live, jnp.cumsum(live.astype(jnp.int32))
+                                - 1 + off, cap)
+                for k, slab in pack_batch(b).items():
+                    acc = out.get(k)
+                    if acc is None:
+                        acc = jnp.zeros((cap,) + slab.shape[1:], slab.dtype)
+                    out[k] = acc.at[pos].set(slab, mode="drop")
+                off = off + jnp.sum(live.astype(jnp.int32))
+            return unpack_batch(out, bs[0], off)
+        race("concat", {"scatter_per_member": scatter_concat,
+                        "gather_as_shipped":
+                            lambda bs, cap=cap: concat_batches(bs, cap)},
+             members, members=parts, member_capacity=members[0].capacity,
+             capacity=cap)
+
+
 PROBES = {"sync": probe_sync, "link": probe_link, "upload": probe_upload,
-          "prefix": probe_prefix, "slots": probe_slots}
+          "prefix": probe_prefix, "slots": probe_slots,
+          "rowmove": probe_rowmove}
 
 
 def main(argv=None) -> int:

@@ -108,22 +108,6 @@ class DeviceColumn:
             return DeviceColumn(self.dtype, data, validity, lengths)
         return DeviceColumn(self.dtype, data, validity)
 
-    def scatter(self, positions: jax.Array, capacity: int) -> "DeviceColumn":
-        """Write row i to ``positions[i]``; positions >= capacity are dropped."""
-        if self.dtype.is_string:
-            shape = (capacity, self.string_width)
-        else:
-            shape = (capacity,)
-        data = jnp.zeros(shape, self.data.dtype).at[positions].set(
-            self.data, mode="drop")
-        validity = jnp.zeros((capacity,), jnp.bool_).at[positions].set(
-            self.validity, mode="drop")
-        if self.dtype.is_string:
-            lengths = jnp.zeros((capacity,), jnp.int32).at[positions].set(
-                self.lengths, mode="drop")
-            return DeviceColumn(self.dtype, data, validity, lengths)
-        return DeviceColumn(self.dtype, data, validity)
-
     def with_validity(self, validity: jax.Array) -> "DeviceColumn":
         data = _zero_dead(self.data, validity)
         if self.dtype.is_string:
@@ -222,8 +206,8 @@ class DeviceBatch:
 
     def compact(self, keep: jax.Array) -> "DeviceBatch":
         """Materialize rows where ``keep`` (ANDed with row_mask) as a packed
-        prefix — the cuDF ``Table.filter`` analog, via one packed scatter
-        per slab (columnar/rowmove.py)."""
+        prefix — the cuDF ``Table.filter`` analog, via one index scatter
+        and one packed gather per slab (columnar/rowmove.py)."""
         from spark_rapids_tpu.columnar.rowmove import compact_batch
         return compact_batch(self, keep)
 
@@ -260,8 +244,9 @@ def concat_batches(batches: Sequence[DeviceBatch], capacity: int) -> DeviceBatch
     ``capacity`` rows.
 
     The cuDF ``Table.concatenate`` analog used by GpuCoalesceBatches
-    (GpuCoalesceBatches.scala:643), via one packed scatter per member
-    (columnar/rowmove.py) — selection vectors compact away here.
+    (GpuCoalesceBatches.scala:643), via one index scatter and one packed
+    gather per slab (columnar/rowmove.py) — selection vectors compact away
+    here.
     Capacities are static, so overflow is checked at trace time.
     """
     assert batches, "concat of zero batches"
@@ -375,16 +360,16 @@ def shrink_to_capacity(batch: DeviceBatch, capacity: int) -> DeviceBatch:
     """Re-bucket a batch whose live rows fit a smaller capacity (after a
     groupby/filter the packed prefix is all that matters). Jitted;
     requires ``live_count <= capacity``. Selection vectors compact away
-    (cost scales with the small OUTPUT capacity — rowmove.compact_to)."""
+    (cost scales with the small OUTPUT capacity — rowmove.compact_batch)."""
     if capacity >= batch.capacity and batch.sel is None:
         return batch
     hint = batch.rows_hint
 
     def _build():
         def _shrink(b: DeviceBatch) -> DeviceBatch:
-            from spark_rapids_tpu.columnar.rowmove import compact_to
+            from spark_rapids_tpu.columnar.rowmove import compact_batch
             if b.sel is not None:
-                return compact_to(b, capacity, b.live_count())
+                return compact_batch(b, capacity=capacity)
             idx = jnp.arange(capacity, dtype=jnp.int32)
             return b.gather(idx, b.num_rows)
         return jax.jit(_shrink)

@@ -75,8 +75,12 @@ class BuiltSide:
     max_run: Optional[jnp.ndarray] = None     # kept for mesh path compat
     stats: Optional[jnp.ndarray] = None       # int64 stats vector
     table: Optional[jnp.ndarray] = None       # dense key -> row, or None
-    table_base: Optional[Tuple[int, ...]] = None   # kmin per key (host)
-    table_spans: Optional[Tuple[int, ...]] = None  # span per key (host)
+    # kmin and span per key: (k,) int64 DEVICE vectors, pytree leaves — as
+    # host constants they made every probe program data-dependent, so a
+    # process over new data compiled each anew (9 programs of 4-8 s in the
+    # mesh q5 cell: my chip run, PR 30).
+    table_base: Optional[jnp.ndarray] = None
+    table_spans: Optional[jnp.ndarray] = None
     host_stats: Optional[List[int]] = None    # stats pulled once (aux)
 
     def stats_host(self) -> Optional[List[int]]:
@@ -91,16 +95,17 @@ class BuiltSide:
 
 def _builtside_flatten(bs: "BuiltSide"):
     children = (bs.batch, bs.fp, bs.matchable, bs.row_live, bs.num_rows,
-                bs.max_run, bs.stats, bs.table)
+                bs.max_run, bs.stats, bs.table, bs.table_base,
+                bs.table_spans)
     aux = (tuple(bs.key_ordinals) if bs.key_ordinals is not None else None,
-           bs.null_safe, bs.table_base, bs.table_spans)
+           bs.null_safe)
     return children, aux
 
 
 def _builtside_unflatten(aux, children):
-    ko, ns, tb, tsp = aux
-    batch, fp, matchable, row_live, num_rows, max_run, stats, table = \
-        children
+    ko, ns = aux
+    (batch, fp, matchable, row_live, num_rows, max_run, stats, table,
+     tb, tsp) = children
     return BuiltSide(batch, fp, matchable, row_live, num_rows,
                      list(ko) if ko is not None else None, ns, max_run,
                      stats, table, tb, tsp)
@@ -248,11 +253,10 @@ def _maybe_build_dense(built: BuiltSide, batch: DeviceBatch,
     fn = kc.lookup("join-dense-build", (size,), _builder)
     # The table indexes the fingerprint-SORTED batch (built.batch) — the
     # same rows every other join path gathers from.
-    built.table = fn(built.batch, built.matchable,
-                     jnp.asarray(mins, jnp.int64),
-                     jnp.asarray(spans, jnp.int64), tuple(key_ordinals))
-    built.table_base = tuple(mins)
-    built.table_spans = tuple(spans)
+    built.table_base = jnp.asarray(mins, jnp.int64)
+    built.table_spans = jnp.asarray(spans, jnp.int64)
+    built.table = fn(built.batch, built.matchable, built.table_base,
+                     built.table_spans, tuple(key_ordinals))
 
 
 def _pair_keys_equal(built: BuiltSide, b_idx: jnp.ndarray,

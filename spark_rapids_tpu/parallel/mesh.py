@@ -31,8 +31,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from spark_rapids_tpu.shims import (shard_map, tree_flatten,
                                     tree_map, tree_unflatten)
 
-from spark_rapids_tpu.columnar.batch import (
-    DeviceBatch, DeviceColumn, bucket_capacity, concat_batches)
+from spark_rapids_tpu.columnar.batch import DeviceBatch, bucket_capacity
+from spark_rapids_tpu.columnar.rowmove import concat_stacked
 from spark_rapids_tpu.parallel.partitioning import Partitioning, split_batch
 
 DATA_AXIS = "data"
@@ -68,9 +68,10 @@ def all_to_all_exchange(batch: DeviceBatch, pids: jnp.ndarray,
                         ) -> DeviceBatch:
     """ICI hash-shuffle step for one device's shard (call under shard_map).
 
-    Splits the local batch into per-destination pieces, exchanges piece
-    ownership with ``all_to_all`` (one fused ICI collective, not a peer
-    pull protocol), and concatenates the received pieces.
+    Splits the local batch into per-destination pieces (one index scatter
+    and one gather per slab: a row moves once), exchanges piece ownership
+    with ``all_to_all`` (one fused ICI collective, not a peer pull
+    protocol), and concatenates the received pieces.
 
     ``piece_capacity`` is the static per-destination piece size. Default
     (None) is the worst case — every piece at the full shard capacity, an
@@ -78,29 +79,14 @@ def all_to_all_exchange(batch: DeviceBatch, pids: jnp.ndarray,
     (SURVEY §7 sizes-then-data) exchanges COUNTS first and passes the
     observed max, so the collective moves ~the real data volume.
     """
-    pieces = split_batch(batch, pids, n_devices)
-    if piece_capacity is not None:
-        # split_batch pieces are already packed prefixes; truncating to
-        # the exchanged max is a static slice, not another scatter pass.
-        def trunc(p: DeviceBatch) -> DeviceBatch:
-            cols = tuple(
-                DeviceColumn(c.dtype, c.data[:piece_capacity],
-                             c.validity[:piece_capacity],
-                             c.lengths[:piece_capacity]
-                             if c.dtype.is_string else None)
-                for c in p.columns)
-            return DeviceBatch(
-                cols, jnp.minimum(p.num_rows, piece_capacity))
-        pieces = [trunc(p) for p in pieces]
-    # Stack piece leaves -> leading axis = destination device.
-    stacked = tree_map(lambda *xs: jnp.stack(xs), *pieces)
+    # One pass, straight into the collective's layout: leaves
+    # (n_devices, piece_capacity, ...), leading axis = destination device.
+    stacked = split_batch(batch, pids, n_devices, piece_capacity)
     received = jax.lax.all_to_all(stacked, axis, split_axis=0,
                                   concat_axis=0, tiled=False)
     # received leaf shape == stacked leaf shape; index i = piece from peer i.
-    parts = [tree_map(lambda x, i=i: x[i], received)
-             for i in range(n_devices)]
-    total_cap = sum(p.capacity for p in parts)
-    return concat_batches(parts, bucket_capacity(total_cap))
+    return concat_stacked(received, bucket_capacity(
+        n_devices * (piece_capacity or batch.capacity)))
 
 
 def exchange_counts(batch: DeviceBatch, pids: jnp.ndarray,
@@ -125,10 +111,8 @@ def all_gather_batch(batch: DeviceBatch, n_devices: int,
     the one-time all-gather replacing collect+torrent-broadcast+re-upload).
     """
     gathered = jax.lax.all_gather(batch, axis, axis=0, tiled=False)
-    parts = [tree_map(lambda x, i=i: x[i], gathered)
-             for i in range(n_devices)]
-    total_cap = sum(p.capacity for p in parts)
-    return concat_batches(parts, bucket_capacity(total_cap))
+    return concat_stacked(gathered,
+                          bucket_capacity(n_devices * batch.capacity))
 
 
 # ---------------------------------------------------------------------------

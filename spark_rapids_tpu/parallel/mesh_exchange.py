@@ -226,8 +226,8 @@ def _uniform_shards(batches_per_dev: List[List[DeviceBatch]],
             cols.append(c)
         s = DeviceBatch(tuple(cols), s.num_rows)
         if s.capacity != cap:
-            idx = jnp.arange(cap, dtype=jnp.int32)
-            s = s.gather(idx, s.num_rows)
+            # jitted: eagerly a packed gather is ~40 one-op programs
+            s = _pad_shard(s, cap)
         out.append(s)
     return out
 
@@ -547,3 +547,14 @@ class MeshExchangeExec(Exec):
                         buckets[p].append(piece)
             ctx.cache[key] = buckets
         yield from iter(ctx.cache[key][partition])
+
+
+def _pad_shard(shard: DeviceBatch, capacity: int) -> DeviceBatch:
+    """A dense shard at a larger ``capacity`` (``_uniform_shards``): ONE
+    program per capacity. Run op by op, the pack, gather and unpack of
+    columnar/rowmove.py are some forty one-op programs, each compiled anew
+    in every process and dispatched one by one in every query."""
+    from spark_rapids_tpu.ops import kernel_cache as kc
+    return kc.lookup("mesh-pad", (capacity,), lambda: jax.jit(
+        lambda b: b.gather(jnp.arange(capacity, dtype=jnp.int32),
+                           b.num_rows)))(shard)

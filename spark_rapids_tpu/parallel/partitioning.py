@@ -3,10 +3,10 @@ GpuRangePartitioning.scala + GpuRangePartitioner.scala,
 GpuRoundRobinPartitioning.scala, GpuSinglePartitioning.scala,
 GpuPartitioning.scala:44-124).
 
-Each strategy maps rows to partition ids on device; ``split_batch`` is the
-``Table.contiguousSplit`` analog — it packs each destination's rows into its
-own fixed-capacity batch (compact-by-mask per destination, so every piece
-keeps a static shape for XLA).
+Each strategy maps rows to partition ids on device; ``split_batch``
+(columnar/rowmove.py, re-exported here) is the ``Table.contiguousSplit``
+analog — it packs each destination's rows into its own fixed-capacity piece,
+all pieces in one pass, so every piece keeps a static shape for XLA.
 
 Hash partitioning uses the bit-exact Spark murmur3 (exprs/hash.py) with
 ``pmod(hash, n)`` — TPU shuffle partitions line up with CPU Spark's, the
@@ -25,6 +25,7 @@ import numpy as np
 from spark_rapids_tpu.columnar import dtypes as dt
 from spark_rapids_tpu.columnar.batch import DeviceBatch, DeviceColumn
 from spark_rapids_tpu.columnar.host import HostBatch, HostColumn
+from spark_rapids_tpu.columnar.rowmove import split_batch  # noqa: F401
 from spark_rapids_tpu.exprs.base import Expression, as_device_column, \
     as_host_column
 from spark_rapids_tpu.exprs.hash import Murmur3Hash
@@ -210,16 +211,6 @@ def _host_as_device_like(hc: HostColumn):
 # ---------------------------------------------------------------------------
 # Splitting (Table.contiguousSplit analog)
 # ---------------------------------------------------------------------------
-
-def split_batch(batch: DeviceBatch, pids: jnp.ndarray,
-                num_partitions: int) -> List[DeviceBatch]:
-    """Pack each destination's rows into its own batch (stable order)."""
-    out = []
-    for p in range(num_partitions):
-        keep = (pids == p) & batch.row_mask()
-        out.append(batch.compact(keep))
-    return out
-
 
 def split_host_batch(hb: HostBatch, pids: np.ndarray,
                      num_partitions: int) -> List[HostBatch]:

@@ -30,14 +30,18 @@ CAPS = (1 << 20, 3 << 19)
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -241,3 +245,66 @@ def test_join_probe(cap, one_chip):
                            _lineitem_like(1 << 17))
     _compile(lambda bs, p: join.probe_ranges(bs, p, [3]), one_chip,
              built, probe)
+
+
+# -- packed row movers (columnar/rowmove.py) ------------------------------------
+
+MESH_SHARD = 3 << 19        # TPC-H SF1 q5's big mesh shards: 1,572,864 rows
+
+
+def _mesh_shard_like(cap, sel=False):
+    """A batch with every kind of slab column: q5's shard (int32, int16,
+    int64, float64) plus a bool and a 16-byte string."""
+    from spark_rapids_tpu.columnar.batch import DeviceBatch, DeviceColumn
+    ones = np.ones((cap,), np.bool_)
+    kinds = ([dt.INT32] * 3 + [dt.INT16] * 2 + [dt.INT64] * 4
+             + [dt.FLOAT64] * 2 + [dt.BOOL])
+    cols = [DeviceColumn(t, np.zeros((cap,), t.np_dtype), ones)
+            for t in kinds]
+    cols.append(DeviceColumn(dt.STRING, np.zeros((cap, 16), np.uint8), ones,
+                             np.zeros((cap,), np.int32)))
+    return DeviceBatch(tuple(cols), np.asarray(cap, np.int32),
+                       sel=ones if sel else None)
+
+
+def test_compact_and_concat_at_a_mesh_shard(one_chip):
+    """The selection-vector discharge of one shard and the concat of four
+    received pieces, at the sizes the mesh cell dispatches them."""
+    from spark_rapids_tpu.columnar.batch import concat_batches
+    from spark_rapids_tpu.columnar.rowmove import compact_batch
+    hlo = _compile(compact_batch, one_chip,
+                   _mesh_shard_like(MESH_SHARD, sel=True)).as_text()
+    assert "scatter" in hlo and "gather" in hlo
+    pieces = [_mesh_shard_like(MESH_SHARD // 4) for _ in range(4)]
+    _compile(lambda bs: concat_batches(bs, MESH_SHARD), one_chip, pieces)
+
+
+def test_mesh_collective_step_for_four_chips(topo):
+    """``all_to_all_exchange`` under ``shard_map`` over the 2x2 host, at
+    a big shard with a two-phase piece capacity: one program across four
+    chips, a collective in it, and no slab scatter (the only scatters
+    are the send and the receive side's 1-D int32 index lists)."""
+    import re
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from spark_rapids_tpu.parallel import mesh as M
+    from spark_rapids_tpu.shims import shard_map, tree_map
+    n = len(topo.devices)
+    assert n == 4
+    mesh = Mesh(np.array(topo.devices), (M.DATA_AXIS,))
+    rows = NamedSharding(mesh, P(M.DATA_AXIS))
+    shard = _mesh_shard_like(MESH_SHARD)
+    stacked = jax.tree.map(lambda x: np.broadcast_to(x, (n,) + x.shape),
+                           shard)
+    pids = np.zeros((n, MESH_SHARD), np.int32)
+
+    def local(st, pids):
+        out = M.all_to_all_exchange(tree_map(lambda x: x[0], st), pids[0],
+                                    n, piece_capacity=9 << 16)
+        return tree_map(lambda x: x[None], out)
+
+    spec = P(M.DATA_AXIS)
+    hlo = _compile(shard_map(local, mesh, in_specs=(spec, spec),
+                             out_specs=spec), rows, stacked, pids).as_text()
+    assert "all-to-all" in hlo
+    scattered = re.findall(r"= (\w+)\[[\d,]*\]\S* scatter\(", hlo)
+    assert scattered and set(scattered) == {"s32"}, scattered

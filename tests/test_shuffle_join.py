@@ -157,6 +157,32 @@ class TestJoins:
             left, right, [Ref(1, dt.INT32)], [Ref(0, dt.INT32)], "inner")
         compare_engines(plan, self._expected_inner(), sort_result=True)
 
+    @pytest.mark.parametrize("join_type", ["inner", "left", "semi"])
+    def test_dense_probe_program_is_not_traced_per_key_range(self,
+                                                             join_type):
+        """The direct-address probe takes the table's key minimum and span
+        as device vectors: build sides over other keys (another seed,
+        another partition) run the SAME program. As host constants in the
+        pytree they made every new build side a new trace, and a new
+        compile in every process (PR 30)."""
+        traces = []
+        for base in (10, 5000, 70000):
+            left = source(ORDERS_SCHEMA, {
+                "o_key": [1, 2, 3, 4],
+                "o_cust": [base, base + 2, None, base + 9],
+                "o_total": [1.0, 2.0, 3.0, 4.0]})
+            right = source([("c_key", dt.INT32), ("c_acct", dt.INT64)], {
+                "c_key": [base + 2, base, base + 5],
+                "c_acct": [7, 8, 9]})
+            plan = BroadcastHashJoinExec(
+                left, right, [Ref(1, dt.INT32)], [Ref(0, dt.INT32)],
+                join_type)
+            dev = compare_engines(plan, sort_result=True)
+            assert len(dev) == {"inner": 2, "left": 4, "semi": 2}[join_type]
+            traces.append(plan._dense_jit_fn().fn._cache_size())
+        # (other tests' joins of this shape may have traced it before)
+        assert traces[0] >= 1 and traces[1:] == traces[:1] * 2
+
     def test_inner_shuffled(self):
         # Co-partition both sides by key first.
         left, right = join_sources()
