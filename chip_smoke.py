@@ -137,8 +137,8 @@ def phase_native_build(workdir: str) -> None:
 
 
 def session(**conf):
-    """A default session plus the two assertions about the DATA that
-    bench.py makes (TPC data is finite; float sums may reassociate).
+    """A default session plus two assertions about the DATA (TPC data
+    is finite; float sums may reassociate).
     Only the mesh phase passes ``conf``, and prints what it passed."""
     from spark_rapids_tpu.api.dataframe import TpuSession
     s = TpuSession()
@@ -262,9 +262,7 @@ def phase_queries(clock, data_dir: str, oracle: dict,
 def phase_report(devs, clock) -> None:
     from spark_rapids_tpu.columnar import wire
     from spark_rapids_tpu.ops import kernel_cache as kc
-    from spark_rapids_tpu.ops import native
     from spark_rapids_tpu.plan import cost
-    emit("native_kernels", **native.counters())
     emit("compile_cache", **kc.persistent_stats(),
          kernel_cache=kc.cache().stats(),
          backend_compile_s_total=round(clock.seconds, 3),
@@ -321,10 +319,10 @@ def _mesh_exchanges(df):
 
 
 # The mesh phase's two sides: transport -> (conf, why cost placement says
-# it stood down). Broadcast off on both, as bench.py's cluster probe did
-# for q3: the joins then really shuffle, and the transport under test
-# really carries them. The mesh side sets nothing else — cost placement
-# stands down by itself on a non-inprocess transport. It is turned off by
+# it stood down). Broadcast off on both: the joins then really shuffle,
+# and the transport under test really carries them. The mesh side sets
+# nothing else — cost placement stands down by itself on a
+# non-inprocess transport. It is turned off by
 # hand on the in-process side and only there: left on it (rightly) sends
 # q5's nation/region subtree to the host engine, and the comparison is
 # between two device exchanges, not between two placements.

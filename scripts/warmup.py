@@ -1,6 +1,6 @@
 """Compile-warmup pack: pre-populate the plan cache + persistent kernel
 cache from a recorded shape manifest, so a FRESH process serves its
-first query without the 7-26s cold-compile cliff (VERDICT weak #10).
+first query without the 7-26s cold-compile cliff.
 
 A shape manifest is a JSON list of entries::
 

@@ -2,14 +2,14 @@
 
 A brand-new framework with the capabilities of the RAPIDS Accelerator for Apache
 Spark (reference: /root/reference, v0.3.0-SNAPSHOT), re-designed TPU-first on
-JAX/XLA/Pallas rather than ported from the CUDA/cuDF design:
+JAX/XLA rather than ported from the CUDA/cuDF design:
 
 - Columnar batches are pytrees of fixed-capacity HBM device arrays with a
   runtime row count, so everything compiles under ``jax.jit`` with static
   shapes (ref: GpuColumnVector.java's cuDF-backed batches, re-imagined for
   XLA's compilation model).
 - Physical operators (scan, project, filter, hash aggregate, join, sort,
-  window, ...) evaluate whole batches with jax.numpy / Pallas kernels
+  window, ...) evaluate whole batches with jax.numpy kernels
   (ref: sql-plugin GpuExec nodes backed by libcudf JNI calls).
 - The plan-rewrite layer keeps the reference's crown-jewel architecture:
   wrap -> tag -> convert with per-operator kill-switch configs, fallback

@@ -392,7 +392,7 @@ class DataFrame:
         re-raise immediately. This is the call a sustained serving
         client should make — a herd of them converges onto the
         scheduler's observed service rate instead of hammering a full
-        queue (bench.py's sustained probe does exactly this)."""
+        queue."""
         from spark_rapids_tpu.parallel import scheduler as SC
         return SC.collect_with_retry(
             lambda: self.collect(timeout_ms=timeout_ms,

@@ -2,7 +2,7 @@
 analog — BenchUtils.scala's sorted/epsilon compare, ISSUE 5 satellite).
 
 One comparator for every harness that checks engine output against an
-oracle (bench.py, tests/test_suites.py, tests/test_tpch*.py, the
+oracle (chip_smoke.py, tests/test_suites.py, tests/test_tpch*.py, the
 scheduler's bit-identity tests): dtype-aware epsilon on floats, date
 normalization, None-aware exact compare on everything else, and an
 optional type-aware row sort for queries whose ORDER BY is computed from

@@ -209,7 +209,7 @@ class HostColumn:
         to native python scalars at C speed, then nulls patch in via the
         (usually tiny) invalid index set — the per-row python loop with
         its per-element dtype branches used to dominate ``collect``'s
-        pure-CPU tail (scripts/bench_rows.py measures the difference).
+        pure-CPU tail.
         Strings slice one contiguous ``tobytes()`` buffer per column
         instead of materializing the lazy per-row object array."""
         val = np.asarray(self.validity, dtype=np.bool_)

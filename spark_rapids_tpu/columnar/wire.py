@@ -166,7 +166,8 @@ def maybe_configure(conf) -> None:
     _CODEC_OVERRIDE = mode
 
 
-# Process-global transport counters (bench.py's ``wire`` JSON block):
+# Process-global transport counters (``counters()``; chip_smoke.py and
+# the telemetry registry read them):
 # rawBytes = decoded device footprint the plain codec would have shipped,
 # encodedBytes = wire arrays actually produced, stagingBytes = packed
 # staging buffers built, uploadTransfers = arrays handed to device_put
@@ -632,32 +633,20 @@ def _decode_fn(cap: int, specs: tuple):
                                          lengths))
                 continue
             if spec[0] == "rle":
-                _, logical_name, _val_name, run_cap, vmode = spec
+                _, logical_name, _val_name, _run_cap, vmode = spec
                 logical = dt.type_named(logical_name)
                 run_vals = next(it)
                 run_ends = next(it)
-                from spark_rapids_tpu.ops import native
-                if native.kernel_enabled("rleDecode") and \
-                        run_cap <= native.rle_max_runs():
-                    # Native Pallas interval-membership select over the
-                    # run table (ops/native.py): bit planes only, so the
-                    # expansion is exact — then the same pure cast.
-                    data = native.rle_decode(run_vals, run_ends, cap,
-                                             num_rows)
-                    if data.dtype != logical.np_dtype:
-                        data = data.astype(logical.np_dtype)
-                else:
-                    rows = jnp.arange(cap, dtype=jnp.int32)
-                    ridx = jnp.searchsorted(run_ends, rows,
-                                            side="right").astype(jnp.int32)
-                    data = jnp.take(run_vals, ridx, axis=0, mode="clip")
-                    if data.dtype != logical.np_dtype:
-                        data = data.astype(logical.np_dtype)  # pure cast
-                    # Zero padding rows (a full run table has no zero
-                    # slot).
-                    rows_ = jnp.arange(cap, dtype=jnp.int32)
-                    data = jnp.where(rows_ < num_rows, data,
-                                     jnp.zeros_like(data))
+                rows = jnp.arange(cap, dtype=jnp.int32)
+                ridx = jnp.searchsorted(run_ends, rows,
+                                        side="right").astype(jnp.int32)
+                data = jnp.take(run_vals, ridx, axis=0, mode="clip")
+                if data.dtype != logical.np_dtype:
+                    data = data.astype(logical.np_dtype)  # pure cast
+                # Zero padding rows (a full run table has no zero slot).
+                rows_ = jnp.arange(cap, dtype=jnp.int32)
+                data = jnp.where(rows_ < num_rows, data,
+                                 jnp.zeros_like(data))
                 cols.append(DeviceColumn(logical, data, valid_of(vmode)))
                 continue
             if spec[0] in ("delta", "for"):
@@ -850,11 +839,7 @@ def _staged_views(enc: EncodedBatch) -> List[np.ndarray]:
 
 
 def _decode_jit(cap: int, specs: tuple):
-    # The native fingerprint keys the cache like the kernel cache does:
-    # toggling a native gate must never serve a decode traced under the
-    # other setting (the RLE branch dispatches differently).
-    from spark_rapids_tpu.ops import native
-    key = ("decode", cap, specs, native.fingerprint())
+    key = ("decode", cap, specs)
     fn = _DECODE_JIT_CACHE.get(key)
     if fn is None:
         with _DECODE_JIT_LOCK:

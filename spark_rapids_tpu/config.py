@@ -450,8 +450,8 @@ TRACE_ENABLED = conf("spark.rapids.sql.trace.enabled").doc(
     "rung, stage recompute, join demotion, watchdog kill, "
     "cancellation, cross-query eviction) into a bounded per-query "
     "ring buffer. Consumed by DataFrame.trace_export (Chrome/Perfetto "
-    "JSON), DataFrame.explain_analyze, monitoring.snapshot() and "
-    "bench.py's trace block. Off = a no-op recorder with near-zero "
+    "JSON), DataFrame.explain_analyze, monitoring.snapshot() and the "
+    "benchmark's traced run. Off = a no-op recorder with near-zero "
     "per-call overhead (the NVTX-always-on analog, "
     "NvtxWithMetrics.scala:21-44). The SRT_TRACE env (0/1) overrides "
     "the default for a whole process.").boolean(False)
@@ -488,10 +488,10 @@ METRICS_ENABLED = conf("spark.rapids.sql.metrics.enabled").doc(
     "scrapeable while queries run, bridged from every existing counter "
     "funnel (scheduler/QoS, plan+kernel caches, recovery ladder, "
     "transport, pipeline, spill watermark). Consumed by "
-    "telemetry.snapshot()/render_text(), the OpenMetrics exporter "
-    "(metrics.port) and bench.py's telemetry block. Off = a no-op "
+    "telemetry.snapshot()/render_text() and the OpenMetrics exporter "
+    "(metrics.port). Off = a no-op "
     "registry whose per-call cost is one global load (the same "
-    "discipline as trace.enabled; scripts/microbench.py bounds it). "
+    "discipline as trace.enabled). "
     "The SRT_METRICS env (0/1) overrides the default for a whole "
     "process.").boolean(False)
 
@@ -1245,70 +1245,6 @@ BROADCAST_CACHE_FETCH_TIMEOUT_MS = conf(
     "short — the cache is an optimization, and the local build is "
     "always correct.").integer(50)
 
-NATIVE_ENABLED = conf("spark.rapids.sql.native.enabled").doc(
-    "Native Pallas kernel layer (ops/native.py): re-implement the "
-    "profiled top device-time sinks — the LSD radix sort's per-digit "
-    "passes, the hash-join probe's double binary search, wire v2's RLE "
-    "decode, and the sorted-segment groupby reductions — as TPU-native "
-    "Pallas (Mosaic) kernels instead of jax.numpy compositions, the "
-    "analog of the reference routing every kernel through libcudf "
-    "(PAPER.md L0). Every native kernel is bit-identical to its "
-    "jax.numpy twin (the parity suite pins this) and individually "
-    "gateable via the spark.rapids.sql.native.<kernel>.enabled keys; "
-    "false restores the jax.numpy code paths byte-for-byte. Kernels "
-    "engage only on a real TPU backend; the Pallas interpreter is "
-    "reachable only through the ops/native.py forced() test hook. As of "
-    "PR 21 Mosaic refuses all four kernels for v5e as written, so every "
-    "per-kernel gate defaults OFF (ROADMAP A5); setting one true on a "
-    "TPU raises the lowering error instead of falling back. The "
-    "SRT_NATIVE env (0/1) overrides the master default for a whole "
-    "process.").boolean(True)
-
-NATIVE_RADIX_SORT = conf("spark.rapids.sql.native.radixSort.enabled").doc(
-    "Per-kernel gate: native LSD radix rank for the stable u32 sort "
-    "passes every multi-pass sort shares (ops/kernels.py _radix_perm) — "
-    "an 8-bit counting-sort rank (block histogram + scanned bases + "
-    "stable within-block prefix) replacing XLA's O(n log^2 n) bitonic "
-    "argsort per pass. Stable by construction, so the permutation is "
-    "bit-identical. Default off: it does "
-    "not compile for v5e yet (ROADMAP A5).").boolean(False)
-
-NATIVE_JOIN_PROBE = conf("spark.rapids.sql.native.joinProbe.enabled").doc(
-    "Per-kernel gate: native hash-join probe (ops/join.py "
-    "probe_ranges) — one fused branchless lower/upper binary search "
-    "over the sorted build fingerprints (uint64 as two u32 planes, "
-    "lexicographic compare) instead of two jnp.searchsorted "
-    "dispatches. Default off: it does "
-    "not compile for v5e yet (ROADMAP A5).").boolean(False)
-
-NATIVE_RLE_DECODE = conf("spark.rapids.sql.native.rleDecode.enabled").doc(
-    "Per-kernel gate: native wire-v2 RLE decode (columnar/wire.py) — "
-    "one interval-membership select over the run table instead of the "
-    "searchsorted+gather chain, engaged when the run table fits "
-    "native.rleDecode.maxRuns. Values move as bit patterns (int "
-    "planes), so the decode stays bit-exact including -0.0/NaN float "
-    "payloads. Default off: it does "
-    "not compile for v5e yet (ROADMAP A5).").boolean(False)
-
-NATIVE_RLE_MAX_RUNS = conf("spark.rapids.sql.native.rleDecode.maxRuns").doc(
-    "Run-table bound for the native RLE decode: a column whose run "
-    "capacity exceeds this falls back to the jax.numpy "
-    "searchsorted+gather decode (the interval select is O(rows x "
-    "runs)).").integer(4096)
-
-NATIVE_SEGMENT_REDUCE = conf(
-    "spark.rapids.sql.native.segmentReduce.enabled").doc(
-    "Per-kernel gate: native sorted-segment reduction (ops/kernels.py "
-    "segment_reduce) — a single-sweep segmented scan (Hillis-Steele "
-    "within blocks, a sequential-grid carry across them) replacing the "
-    "scatter-based jax.ops.segment_* for group-sorted ids. Engages for "
-    "integer/count sums (exact two's-complement, carried as u32 "
-    "planes) and min/max in the total-order bit domain (so -0.0 < 0.0 "
-    "and identities match the twin exactly); float SUMS stay on the "
-    "jax.numpy twin — reduction order changes float rounding, and "
-    "bit-identity is the contract. Default off: it does "
-    "not compile for v5e yet (ROADMAP A5).").boolean(False)
-
 COST_CALIBRATION = conf("spark.rapids.sql.cost.calibration.enabled").doc(
     "Cost-model self-calibration (plan/cost.py): feed flight-recorder "
     "span timings (sync-category span means -> deviceSyncFloorMs, "
@@ -1468,7 +1404,7 @@ def generate_docs() -> str:
         "concurrently, bounded by `pipeline.maxConcurrentStages`.",
         "`SRT_PIPELINE=0` (or the conf) restores the serial dispatch",
         "exactly. Overlap is observable via the `Pipeline@query` metrics",
-        "entry and bench.py's `pipeline` JSON block (`hostPrefetchMs`,",
+        "entry and `parallel/pipeline.py counters()` (`hostPrefetchMs`,",
         "`consumerWaitMs`, `pipelineStalls`, `concurrentStages`,",
         "`overlapRatio`). See docs/performance.md for the overlap model",
         "and the interaction with the watchdog/recovery demotion ladder.",
@@ -1495,7 +1431,7 @@ def generate_docs() -> str:
         "consecutive encoded batches below",
         "`spark.rapids.sql.wire.minUploadBytes` share a call. The",
         "pack half runs on pipeline prefetch threads, so the ordered",
-        "consumer only dispatches. bench.py's `wire` JSON block reports",
+        "consumer only dispatches. `columnar/wire.py counters()` reports",
         "raw vs encoded bytes, per-codec column counts, call and",
         "transfer counts and the staging hit rate. See",
         "docs/performance.md.",
@@ -1549,7 +1485,7 @@ def generate_docs() -> str:
         "faultsInjected, corruptionsDetected, stageRecomputes,",
         "partitionRetries, watchdogKills, meshDegrades,",
         "meshCollectiveSkipped, crossQueryEvictions) surface",
-        "through `DataFrame.metrics()` and bench.py's JSON report.",
+        "through `DataFrame.metrics()` and `faults.counters()`.",
         "",
         "## Shuffle transport SPI",
         "",
@@ -1576,7 +1512,7 @@ def generate_docs() -> str:
         "refetches once (`remoteShardRefetches`). The",
         "`SRT_SHUFFLE_TRANSPORT` env overrides the default for a whole",
         "process (the CI matrix hook), and `Transport@query` metrics +",
-        "bench.py's `transport` JSON block carry",
+        "`parallel/transport counters()` carry",
         "`transportBytesWritten/Fetched` and the recovery counters.",
         "",
         "## Multi-query admission, isolation & cancellation",
@@ -1672,7 +1608,7 @@ def generate_docs() -> str:
         "merges partitions while BOTH `aqe.coalescePartitions.targetRows`",
         "and `aqe.coalescePartitions.targetBytes` hold. Decisions and",
         "estimate-vs-actual error surface in the `Cost@query` metrics",
-        "entry and bench.py's `cost` JSON block. See docs/performance.md.",
+        "entry and `plan/cost.py counters()`. See docs/performance.md.",
         "",
         "## Parameterized plan cache",
         "",
@@ -1698,7 +1634,7 @@ def generate_docs() -> str:
         "handle, and `scripts/warmup.py` replays a shape manifest so a",
         "fresh process serves its first query without the cold-compile",
         "cliff. Counters (planCacheHits/Misses/bindOnlyExecutions) land",
-        "in bench.py's `plan_cache` block and per-tenant on the",
+        "in `plan/plan_cache.py counters()` and per-tenant on the",
         "`Scheduler@query` metrics entry. See docs/performance.md.",
         "",
         "## Query flight recorder",
@@ -1717,7 +1653,7 @@ def generate_docs() -> str:
         "thread), `DataFrame.explain_analyze()` renders the plan tree",
         "with observed rows/bytes/wall next to the cost model's",
         "estimates, `monitoring.snapshot()` aggregates the span-category",
-        "breakdown bench.py publishes as its `trace` JSON block.",
+        "breakdown.",
         "Disabled, the recorder is a shared no-op costing nanoseconds",
         "per call site — results and metrics are byte-identical either",
         "way. See docs/observability.md.",
@@ -1749,45 +1685,6 @@ def generate_docs() -> str:
         "exposition-only: disabled (the default) the hot paths reduce",
         "to a single global load, and results are byte-identical either",
         "way. See docs/observability.md.",
-        "",
-        "## Native Pallas kernels",
-        "",
-        "With `spark.rapids.sql.native.enabled` (default true) the hot",
-        "device loops the flight recorder profiles as the top",
-        "device-time sinks run as TPU-native Pallas (Mosaic) kernels",
-        "instead of jax.numpy compositions — the analog of the",
-        "reference routing every kernel through libcudf:",
-        "",
-        "- `native.radixSort.enabled` — stable u32 radix rank for every",
-        "  LSD sort pass (`ops/kernels.py _radix_perm`): block",
-        "  histograms + scanned digit bases + a stable within-block",
-        "  prefix, 4 counting passes per word instead of an XLA",
-        "  bitonic argsort.",
-        "- `native.joinProbe.enabled` — the hash-join probe's double",
-        "  binary search (`ops/join.py probe_ranges`) fused into one",
-        "  branchless lower/upper search over two u32 planes.",
-        "- `native.rleDecode.enabled` — wire v2's RLE decode as an",
-        "  interval-membership select over the run table (bounded by",
-        "  `native.rleDecode.maxRuns`), bit patterns only.",
-        "- `native.segmentReduce.enabled` — sorted-segment groupby",
-        "  reductions as a single-sweep segmented scan (integer/count",
-        "  sums exactly in u32 carry planes; min/max in the total-order",
-        "  bit domain; float sums stay on the twin because reduction",
-        "  order changes float rounding).",
-        "",
-        "Every native kernel keeps its jax.numpy twin as a per-op",
-        "kill-switch fallback and is BIT-IDENTICAL to it (the",
-        "tests/test_native.py parity suite pins the whole dtype ladder",
-        "including -0.0/NaN); `native.enabled=false` (or `SRT_NATIVE=0`)",
-        "restores the jax.numpy code paths byte-for-byte. Kernels engage",
-        "only on a real TPU backend; the Pallas interpreter is reachable",
-        "only through the `ops/native.py` `forced()` test hook. All four",
-        "per-kernel gates default OFF: the v5e compiler (Mosaic) refuses",
-        "each kernel as written (ROADMAP A5 quotes it), none has run on",
-        "a chip, and a gate turns default-on only together with its",
-        "compile case in tests/test_chip_compile.py. bench.py's `native`",
-        "JSON block and chip_smoke.py report the live set and trace",
-        "counts. See docs/performance.md.",
         "",
         "## Dynamic per-rule kill switches",
         "",

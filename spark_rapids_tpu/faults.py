@@ -80,7 +80,7 @@ almost nothing. Every injection/recovery event bumps
 the process-global counters (``faultsInjected``, ``retriesAttempted``,
 ``spillEscalations``, ``hostFallbacks``, ``corruptionsDetected``) and,
 when a query is running, the per-query ``Recovery`` Metrics sink —
-surfaced through ``DataFrame.metrics()`` and ``bench.py``'s JSON.
+surfaced through ``DataFrame.metrics()`` and :func:`counters`.
 
 Deliberately imports nothing beyond stdlib: oom/stores/wire/ops all
 import this module from deep dispatch code.
@@ -527,7 +527,7 @@ def get_cancel_event():
 
 
 def record(name: str, amount: float = 1) -> None:
-    """Bump a recovery counter: process-global (bench.py JSON) and the
+    """Bump a recovery counter: process-global (:func:`counters`) and the
     active query's Recovery metrics (DataFrame.metrics())."""
     with _LOCK:
         _COUNTERS[name] = _COUNTERS.get(name, 0) + amount

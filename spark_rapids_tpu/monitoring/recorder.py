@@ -31,8 +31,7 @@ registered.
 Always cheap enough to leave on: the DISABLED path of :func:`span` /
 :func:`instant` is one module-global load + a truthiness test returning
 a shared no-op (no allocation, no lock, no clock read) — the tier-1
-suite runs bit-identical with tracing off, and scripts/microbench.py
-bounds the disabled-call cost. Enabled, every ring is a
+suite runs bit-identical with tracing off. Enabled, every ring is a
 ``collections.deque(maxlen=trace.maxEvents)``, so a runaway query can
 never hold more than a bounded window of its own history (the flight
 recorder discipline: you keep the tail, not the flight).
@@ -49,8 +48,7 @@ and per worker thread), ``DataFrame.explain_analyze`` joins the span
 stream with per-operator metrics and the cost model's estimates
 (analyze.py), :func:`self_times` gives each category's self time (a
 span's duration less what its children on the same thread cover), and
-:func:`snapshot` aggregates the span-category time breakdown bench.py
-publishes as its ``trace`` JSON block.
+:func:`snapshot` aggregates the span-category time breakdown.
 
 Deliberately imports nothing beyond stdlib at module level: faults.py
 (itself stdlib-only) emits instants from injection sites; faults (for
@@ -536,9 +534,8 @@ def snapshot() -> dict:
     (``ms``: the spans' durations summed, nested ones counted again;
     ``selfMs``: self time), instant counts by name, per-query event
     totals, ``listeners``: what an enabled recorder hears besides its
-    span sites (``gc``, ``compile``) — the ``trace`` block bench.py
-    publishes, and the at-a-glance answer to "where did the wall-clock
-    go" without exporting a full timeline."""
+    span sites (``gc``, ``compile``) — the at-a-glance answer to "where
+    did the wall-clock go" without exporting a full timeline."""
     cats: Dict[str, Dict[str, float]] = {}
     instants: Dict[str, int] = {}
     queries: Dict[str, Dict[str, float]] = {}

@@ -1,5 +1,4 @@
-"""Host-sync attribution on the span stream (scripts/syncprof.py's
-engine, promoted into the monitoring subsystem).
+"""Host-sync attribution on the span stream.
 
 A device->host read makes the driver wait for the device (0.9 ms for a
 dispatch-and-read on the attached v5e, PR 21) and drains the dispatch
@@ -100,7 +99,7 @@ def owner(event: tuple, by_sid: Dict[int, tuple]) -> str:
 
 def sync_stats(query_id=None) -> Dict[str, Tuple[int, float]]:
     """Aggregate the recorded sync spans: ``label @ owner`` -> (count,
-    secs), the shape scripts/syncprof.py reports. A ``sizesPullTime``
+    secs). A ``sizesPullTime``
     section that holds funnel spans is their owner and no sync of its
     own; below kernel level, where no funnel records, it is the sync."""
     evs = [e for e in recorder.events(query_id) if e[0] == "X"]

@@ -16,18 +16,17 @@ instrumentation on the query lifecycle.
 Same always-cheap discipline as the recorder: the DISABLED path of
 :func:`inc` / :func:`observe` / :func:`set_gauge` is one module-global
 load and a return — the tier-1 suite runs byte-identical with metrics
-off, and scripts/microbench.py's ``telemetry_overhead`` probe bounds
-the disabled-call cost next to the trace no-op.
+off.
 
 Config (process-global, last collect's conf wins — the wire-codec
 regime): ``spark.rapids.sql.metrics.enabled`` (``SRT_METRICS`` env
 override), ``spark.rapids.sql.metrics.port`` (the OpenMetrics exporter
 in exporter.py; 0 = registry only, no socket).
 
-Consumers: :func:`snapshot` (structured dict — bench.py's ``telemetry``
-block), :func:`render_text` (OpenMetrics/Prometheus text exposition —
-the exporter's ``/metrics`` body, zero-dependency so tests never need
-the socket), and the cluster runtime: workers flatten their registry
+Consumers: :func:`snapshot` (structured dict), :func:`render_text`
+(OpenMetrics/Prometheus text exposition — the exporter's ``/metrics``
+body, zero-dependency so tests never need the socket), and the cluster
+runtime: workers flatten their registry
 into :func:`export_cluster_blob` piggybacked on CBEAT heartbeats, the
 driver's coordinator feeds :func:`fleet_update`, and every fleet series
 re-renders with a ``worker=<wid>`` label.
@@ -372,11 +371,6 @@ def sync_funnels() -> None:
     except Exception:
         pass
     try:
-        from spark_rapids_tpu.ops import native as _n
-        sources.append(("native", _n.counters()))
-    except Exception:
-        pass
-    try:
         from spark_rapids_tpu.plan import cost as _c
         sources.append(("cost", _c.counters()))
     except Exception:
@@ -404,9 +398,9 @@ def sync_funnels() -> None:
 # -- consumers ----------------------------------------------------------------
 
 def snapshot() -> dict:
-    """Structured registry view (bench.py's ``telemetry`` block and the
-    zero-socket test path). Funnels are synced first so the view
-    reconciles with the subsystem counters at the instant of the call."""
+    """Structured registry view (the zero-socket path). Funnels are
+    synced first so the view reconciles with the subsystem counters at
+    the instant of the call."""
     sync_funnels()
     out: Dict[str, dict] = {}
     with _LOCK:

@@ -542,18 +542,15 @@ class Exec:
             from spark_rapids_tpu.columnar.host import download_batches
             from spark_rapids_tpu.memory import stores
             from spark_rapids_tpu.memory.stores import get_tpu_semaphore
-            # Adopt this query's wire codec selection (process-global,
-            # spark.rapids.sql.wire.codec) before any upload happens —
-            # its flight-recorder configuration, before any span
-            # site runs (spark.rapids.sql.trace.*) — and its native
-            # Pallas kernel gates, before any kernel traces
-            # (spark.rapids.sql.native.*).
+            # Adopt this query's process-globals (last writer wins):
+            # its wire codec (spark.rapids.sql.wire.codec) before any
+            # upload happens, its flight-recorder and telemetry
+            # configuration before any span site runs
+            # (spark.rapids.sql.trace.*), and its preemption policy.
             from spark_rapids_tpu.monitoring import telemetry
-            from spark_rapids_tpu.ops import native
             wire.maybe_configure(ctx.conf)
             monitoring.maybe_configure(ctx.conf)
             telemetry.maybe_configure(ctx.conf)
-            native.maybe_configure(ctx.conf)
             stores.preemption_configure(ctx.conf)
             # Task admission (GpuSemaphore.scala:74-87): at most
             # concurrentTpuTasks collects issue device work at once, so
@@ -668,8 +665,7 @@ class Exec:
                         rows.extend(hb.to_pylist())
             finally:
                 collect_span.__exit__(None, None, None)
-                # Live telemetry (the hot-collect instrumentation the
-                # microbench overhead probe models): one counter inc +
+                # Live telemetry: one counter inc +
                 # one histogram observe per collect, plus the spill
                 # ladder's tier occupancy and device high watermark —
                 # read off the catalog only if this query built one.

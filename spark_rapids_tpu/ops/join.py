@@ -302,24 +302,14 @@ def _pair_keys_equal(built: BuiltSide, b_idx: jnp.ndarray,
 
 def probe_ranges(built: BuiltSide, probe: DeviceBatch,
                  key_ordinals: Sequence[int], null_safe: bool = False):
-    """Per-probe-row match range [lo, hi) in the sorted build side.
-
-    With ``spark.rapids.sql.native.joinProbe.enabled`` live, the double
-    binary search runs as ONE native Pallas kernel (ops/native.py:
-    branchless lower+upper bound over two u32 planes) instead of two
-    jnp.searchsorted dispatches — insertion points are uniquely defined,
-    so the result is bit-identical."""
-    from spark_rapids_tpu.ops import native
+    """Per-probe-row match range [lo, hi) in the sorted build side."""
     fp = _fingerprint64(probe, key_ordinals)
     plive = probe.row_mask()
     if not null_safe:
         for i in key_ordinals:
             plive = plive & probe.columns[i].validity
-    if native.kernel_enabled("joinProbe"):
-        lo, hi = native.searchsorted_u64_pair(built.fp, fp)
-    else:
-        lo = jnp.searchsorted(built.fp, fp, side="left").astype(jnp.int32)
-        hi = jnp.searchsorted(built.fp, fp, side="right").astype(jnp.int32)
+    lo = jnp.searchsorted(built.fp, fp, side="left").astype(jnp.int32)
+    hi = jnp.searchsorted(built.fp, fp, side="right").astype(jnp.int32)
     counts = jnp.where(plive, hi - lo, 0)
     return lo, counts, plive
 

@@ -25,12 +25,8 @@ Design notes:
   (LRU); hits/misses are counted globally and surfaced per-op through
   ``Metrics`` as ``kernelCacheHits`` / ``kernelCacheMisses``.
 
-This module deliberately imports nothing from the ops/exprs/columnar
-layers (they all import it), only stdlib + numpy — with one lazy
-exception: ``lookup`` folds the native-kernel fingerprint
-(ops/native.py, which itself imports only config + jax) into every key
-so toggling a ``spark.rapids.sql.native.*`` gate can never serve a
-program traced under the other setting.
+This module imports nothing from the ops/exprs/columnar layers (they all
+import it), only stdlib + numpy.
 """
 
 from __future__ import annotations
@@ -293,16 +289,8 @@ def lookup(kind: str, key_parts: Tuple, builder: Callable[[], Callable],
            metrics=None) -> CompiledKernel:
     """Fetch-or-build the kernel for ``(kind, *key_parts)``, wrapping the
     built callable in :class:`CompiledKernel`. When ``metrics`` is given,
-    counts ``kernelCacheHits``/``kernelCacheMisses`` on it.
-
-    The native-kernel fingerprint (ops/native.py) is folded into every
-    key: a kernel traced while a native Pallas gate was live embeds
-    different lowering than its jax.numpy twin, so toggling
-    ``spark.rapids.sql.native.*`` must miss rather than serve the stale
-    program."""
-    from spark_rapids_tpu.ops import native
-    entry, hit = _CACHE.get((kind,) + tuple(key_parts)
-                            + (native.fingerprint(),),
+    counts ``kernelCacheHits``/``kernelCacheMisses`` on it."""
+    entry, hit = _CACHE.get((kind,) + tuple(key_parts),
                             lambda: CompiledKernel(builder()))
     if metrics is not None:
         metrics.add("kernelCacheHits" if hit else "kernelCacheMisses", 1)

@@ -123,25 +123,12 @@ def _radix_perm(passes: List[jnp.ndarray], capacity: int,
     returned permutation orders rows by the lexicographic pass tuple.
     ``unstable_first`` relaxes tie order on the least-significant pass
     only (spark.rapids.sql.stableSort.enabled off) — every later pass
-    must stay stable for multi-key correctness.
-
-    With ``spark.rapids.sql.native.radixSort.enabled`` live, each stable
-    uint32 pass runs as the native Pallas counting-sort rank
-    (ops/native.py) instead of XLA's bitonic argsort — bit-identical,
-    because a stable sort permutation is unique. Float-domain passes
-    (the TPU f64 key path) and the relaxed unstable first pass keep the
-    jnp twin."""
-    from spark_rapids_tpu.ops import native
-    use_native = native.kernel_enabled("radixSort")
+    must stay stable for multi-key correctness."""
     perm = jnp.arange(capacity, dtype=jnp.int32)
     first = True
     for words in reversed(passes):
         keyed = jnp.take(words, perm, axis=0)
-        stable = not (unstable_first and first)
-        if use_native and stable and keyed.dtype == jnp.uint32:
-            order = native.stable_argsort_u32(keyed)
-        else:
-            order = jnp.argsort(keyed, stable=stable)
+        order = jnp.argsort(keyed, stable=not (unstable_first and first))
         perm = jnp.take(perm, order, axis=0)
         first = False
     return perm
@@ -285,48 +272,19 @@ def group_ids(batch: DeviceBatch, key_ordinals: Sequence[int],
     return Grouping(perm, gid, num_groups, leader)
 
 
-def _seg_sum(values: jnp.ndarray, gid: jnp.ndarray,
-             capacity: int) -> jnp.ndarray:
-    """segment_sum with the native sorted-scan twin behind the
-    ``native.segmentReduce`` gate. The native path handles exactly the
-    order-free dtypes (two's-complement ints); floats always reduce
-    through jax.ops — reduction order changes rounding and bit identity
-    is the contract."""
-    from spark_rapids_tpu.ops import native
-    if native.kernel_enabled("segmentReduce"):
-        out = native.segment_sum_sorted(values, gid, capacity)
-        if out is not None:
-            return out
-    return jax.ops.segment_sum(values, gid, num_segments=capacity)
-
-
-def _seg_minmax(values: jnp.ndarray, gid: jnp.ndarray, capacity: int,
-                kind: str) -> jnp.ndarray:
-    """segment_min/max with the native total-order-bit-domain twin
-    behind the ``native.segmentReduce`` gate."""
-    from spark_rapids_tpu.ops import native
-    if native.kernel_enabled("segmentReduce"):
-        out = native.segment_minmax_sorted(values, gid, capacity, kind)
-        if out is not None:
-            return out
-    red = jax.ops.segment_min if kind == "min" else jax.ops.segment_max
-    return red(values, gid, num_segments=capacity)
-
-
 def segment_reduce(values: jnp.ndarray, validity: jnp.ndarray,
                    gid: jnp.ndarray, capacity: int, kind: str,
                    count_also: bool = False):
     """Segmented aggregate with Spark null discipline.
 
     values/validity are already permuted to sorted order; gid is
-    group_of_sorted (NONDECREASING — the native segmented-scan twin
-    relies on it). Returns (agg (capacity,), non_null_count (capacity,)).
+    group_of_sorted. Returns (agg (capacity,), non_null_count (capacity,)).
     ``kind``: sum | min | max.
     """
     if kind == "sum":
         masked = jnp.where(validity, values,
                            jnp.zeros_like(values))
-        agg = _seg_sum(masked, gid, capacity)
+        agg = jax.ops.segment_sum(masked, gid, num_segments=capacity)
     elif kind in ("min", "max"):
         if jnp.issubdtype(values.dtype, jnp.floating):
             # Spark orders NaN greatest. Reduce in the float domain with
@@ -339,24 +297,28 @@ def segment_reduce(values: jnp.ndarray, validity: jnp.ndarray,
             if kind == "min":
                 masked = jnp.where(real, values,
                                    jnp.asarray(jnp.inf, values.dtype))
-                m = _seg_minmax(masked, gid, capacity, "min")
-                has_real = _seg_sum(real.astype(jnp.int32), gid,
-                                    capacity) > 0
+                m = jax.ops.segment_min(masked, gid, num_segments=capacity)
+                has_real = jax.ops.segment_sum(
+                    real.astype(jnp.int32), gid, num_segments=capacity) > 0
                 agg = jnp.where(has_real, m, nanv)
             else:
                 masked = jnp.where(real, values,
                                    jnp.asarray(-jnp.inf, values.dtype))
-                m = _seg_minmax(masked, gid, capacity, "max")
-                has_nan = _seg_sum((validity & isnan).astype(jnp.int32),
-                                   gid, capacity) > 0
+                m = jax.ops.segment_max(masked, gid, num_segments=capacity)
+                has_nan = jax.ops.segment_sum(
+                    (validity & isnan).astype(jnp.int32), gid,
+                    num_segments=capacity) > 0
                 agg = jnp.where(has_nan, nanv, m)
         else:
             masked = jnp.where(validity, values,
                                _identity_for(values.dtype, kind))
-            agg = _seg_minmax(masked, gid, capacity, kind)
+            red = jax.ops.segment_min if kind == "min" \
+                else jax.ops.segment_max
+            agg = red(masked, gid, num_segments=capacity)
     else:
         raise ValueError(kind)
-    counts = _seg_sum(validity.astype(jnp.int64), gid, capacity)
+    counts = jax.ops.segment_sum(validity.astype(jnp.int64), gid,
+                                 num_segments=capacity)
     return agg, counts
 
 

@@ -30,7 +30,7 @@ lost/corrupt cache entry degrades to a LOCAL REBUILD — a miss, never an
 error and never a stage recompute (sessions are opened ``owner=None``
 so a loss is unattributable by design).
 
-Counters (process-global, bench.py's ``transport`` block):
+Counters (process-global, among the transport's):
 ``broadcastCacheHits``, ``broadcastCacheMisses`` (miss = built
 locally), ``broadcastCachePublishes``.
 """

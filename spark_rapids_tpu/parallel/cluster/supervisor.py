@@ -196,7 +196,7 @@ class Supervisor:
         self._next_idx = 0
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
-        # Decision/action counters bench.py's autoscale block reports.
+        # Decision/action counters.
         self.counters = {"restarts": 0, "quarantines": 0, "drains": 0,
                          "retirements": 0, "demotions": 0,
                          "promotions": 0}

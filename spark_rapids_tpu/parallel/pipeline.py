@@ -43,7 +43,7 @@ restores today's serial dispatch byte-for-byte: :func:`open_pipeline`
 then returns the no-op serial pipeline and no thread is ever created.
 
 Counters (process-global here + the per-query ``Pipeline@query`` metrics
-entry, surfaced by ``DataFrame.metrics()`` and bench.py's JSON):
+entry, surfaced by ``DataFrame.metrics()`` and :func:`counters`):
 ``hostPrefetchMs``, ``consumerWaitMs``, ``pipelineStalls``,
 ``prefetchedPartitions``, ``concurrentStages`` and the derived
 ``overlapRatio`` (fraction of host-prefetch time the consumer did NOT
@@ -86,8 +86,8 @@ def record(ctx, name: str, amount: float) -> None:
 
 
 def counters() -> Dict[str, float]:
-    """Process-global pipeline counters (bench.py's ``pipeline`` JSON
-    block), with the derived overlapRatio folded in."""
+    """Process-global pipeline counters, with the derived overlapRatio
+    folded in."""
     with _COUNTER_LOCK:
         out = dict(_COUNTERS)
     return _with_overlap_ratio(out)

@@ -56,7 +56,7 @@ def _record(name: str, amount: float = 1) -> None:
 
 
 def counters() -> Dict[str, float]:
-    """Process-global QoS counters (bench.py's ``qos`` JSON block):
+    """Process-global QoS counters:
     per-class admissions (``admitted.<class>``), rejections by kind
     (``rejected.queue-full`` / ``rejected.tenant-quota`` /
     ``rejected.deadline-unmeetable`` / ``rejected.admission-timeout``),

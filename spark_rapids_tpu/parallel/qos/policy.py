@@ -14,7 +14,7 @@ On top of the stride ordering sits a HARD starvation bound: every time
 a non-empty class is passed over for a dispatch its bypass counter
 ticks; once any class has been bypassed ``starvation_bound`` times in a
 row its head runs NEXT regardless of virtual time (the engagement is
-counted — bench.py reports it). With weights like 100:1:1 the stride
+counted). With weights like 100:1:1 the stride
 schedule alone would make background wait ~100 grants between services;
 the bound caps that wait absolutely.
 

@@ -31,8 +31,8 @@ before stage prematerialization):
 
 Counters land in the query's ``Cost@query`` metrics entry
 (``replanChecks`` / ``joinDemotions`` / ``replanObservedBytes`` /
-``estimateErrorPct``) and in the process-global cost counters bench.py
-reports.
+``estimateErrorPct``) and in the process-global cost counters
+(plan/cost.py ``counters()``).
 """
 
 from __future__ import annotations

@@ -73,8 +73,7 @@ def _record(name: str, amount: float = 1) -> None:
 
 
 def counters() -> Dict[str, float]:
-    """Process-global scheduler counters (bench.py's ``scheduler`` JSON
-    block)."""
+    """Process-global scheduler counters."""
     with _COUNTER_LOCK:
         return dict(_COUNTERS)
 
@@ -110,13 +109,13 @@ def _telemetry_reject(kind: str, depth: int, hint, tenant=None,
 
 def record_plan_cache(ctx, hit: bool) -> None:
     """Per-tenant plan-cache outcome (plan/plan_cache.py) on the query's
-    Scheduler@query entry plus the process counters bench.py's
-    ``scheduler`` block reports: ``planCacheBindOnly`` executions
+    Scheduler@query entry plus the process counters:
+    ``planCacheBindOnly`` executions
     skipped planning entirely (plan once, bind literals, dispatch);
     ``planCacheMiss`` executions paid a template plan this tenant's
     later calls amortize. Tenant-tagged queries (the ``tenant=`` kwarg
     or ``scheduler.qos.tenant``) additionally land in the per-tenant
-    QoS counters bench.py's ``qos``/``sustained`` blocks report."""
+    QoS counters."""
     name = "planCacheBindOnly" if hit else "planCacheMiss"
     metrics_entry(ctx).add(name, 1)
     _record(name)

@@ -46,7 +46,7 @@ attributed to a stage — "a root stage is gone" — or the recompute budget
 is spent). Every recompute bumps the ``stageRecomputes`` counter (plus a
 per-stage ``stageRecomputes.stage<N>`` detail) through
 spark_rapids_tpu.faults, surfacing in ``DataFrame.metrics()`` and
-bench.py's recovery JSON block.
+``faults.counters()``.
 """
 
 from __future__ import annotations
@@ -168,7 +168,7 @@ def invalidate_stage(ctx, stage: Stage) -> None:
 
 def record_recompute(ctx, stage: Stage) -> None:
     """Bump the recovery counters for one stage recompute: the global
-    aggregate, the per-stage detail (bench.py's JSON emits both), the
+    aggregate, the per-stage detail, the
     query's Recovery metrics entry, and a flight-recorder instant so
     the rework shows on the trace timeline."""
     from spark_rapids_tpu import faults, monitoring

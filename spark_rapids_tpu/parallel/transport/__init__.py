@@ -27,8 +27,7 @@ Counters (process-global here + the per-query ``Transport@query``
 metrics entry): ``transportBytesWritten``, ``transportBytesFetched``,
 ``transportShardsWritten``, ``transportShardsFetched``,
 ``remoteShardRefetches`` (CRC-failed fetches that re-read),
-``remoteShardsLost`` (losses handed to lineage recovery). bench.py
-surfaces them as the JSON ``transport`` block.
+``remoteShardsLost`` (losses handed to lineage recovery).
 """
 
 from __future__ import annotations
@@ -45,7 +44,7 @@ _COUNTERS: Dict[str, float] = {}
 
 
 def record(name: str, amount: float = 1) -> None:
-    """Bump a process-global transport counter (bench.py JSON block)."""
+    """Bump a process-global transport counter."""
     with _LOCK:
         _COUNTERS[name] = _COUNTERS.get(name, 0) + amount
 

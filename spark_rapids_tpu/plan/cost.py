@@ -44,8 +44,8 @@ from spark_rapids_tpu import config as C
 from spark_rapids_tpu.plan import logical as L
 from spark_rapids_tpu.plan.logical import LogicalPlan
 
-# Process-global counters for bench.py's `cost` JSON block (mirrors
-# pipeline.counters()): how often placement ran and what it chose.
+# Process-global counters (mirrors pipeline.counters()): how often
+# placement ran and what it chose.
 _COUNTERS: Dict[str, float] = {}
 _COUNTERS_LOCK = threading.Lock()
 

@@ -72,7 +72,7 @@ from spark_rapids_tpu.plan import logical as L
 from spark_rapids_tpu.plan.logical import Column, LogicalPlan, canonical_node
 
 # ---------------------------------------------------------------------------
-# Process-global counters (bench.py's ``plan_cache`` JSON block)
+# Process-global counters
 # ---------------------------------------------------------------------------
 
 _COUNTER_LOCK = threading.Lock()

@@ -1,4 +1,4 @@
-"""AQE-lite (VERDICT r3 item 9): stats-driven auto join strategy
+"""AQE-lite: stats-driven auto join strategy
 (autoBroadcastJoinThreshold over parquet footer estimates) and
 post-shuffle partition coalescing from exact materialized sizes
 (GpuCustomShuffleReaderExec.scala:132 analog)."""

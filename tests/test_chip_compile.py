@@ -5,13 +5,11 @@ chip that is described and not attached (guide: on-chip-measurement §2).
 These tests hold what no interpret-mode or CPU test can see: that the
 programs TPC-H SF1 really dispatches lower for a v5e at the capacities it
 dispatches them at (reader batches 3*2^18..2^20 rows, coalesced ones
-above), and — for every native Pallas kernel whose gate is default-on —
-that Mosaic accepts it and a ``tpu_custom_call`` is in the program.
+above).
 
-PR 21 found all four native kernels refused and the packed wire unpack
-program compiling for 698 s; the first are default-off since (ROADMAP A5),
-the second is gone (columnar/wire.py). A compile that passes is not a chip
-run: ``chip_smoke.py`` is.
+PR 21 found the packed wire unpack program compiling for 698 s; it is gone
+(columnar/wire.py). A compile that passes is not a chip run:
+``chip_smoke.py`` is.
 
 The topology is described inside a module-scoped fixture, never at import:
 one process at a time may load libtpu, and every xdist worker imports this
@@ -80,67 +78,6 @@ def _lineitem_like(cap):
     return DeviceBatch(cols, np.asarray(cap, np.int32))
 
 
-# -- native Pallas kernels ------------------------------------------------------
-
-def _native_cases(cap):
-    """kernel name -> (fn, args): one compile case per native kernel.
-    A kernel may be default-on only if it is here and the case passes."""
-    from spark_rapids_tpu.ops import native
-    u32 = np.zeros((cap,), np.uint32)
-    u64 = np.zeros((cap,), np.uint64)
-    i32 = np.zeros((cap,), np.int32)
-    i64 = np.zeros((cap,), np.int64)
-    return {
-        "radixSort": (native.stable_argsort_u32, (u32,)),
-        "joinProbe": (native.searchsorted_u64_pair, (u64, u64)),
-        "rleDecode": (lambda v, e, n: native.rle_decode(v, e, cap, n),
-                      (np.zeros((4096,), np.int64),
-                       np.zeros((4096,), np.int32),
-                       np.asarray(0, np.int32))),
-        "segmentReduce": (lambda v, g: native.segment_sum_sorted(v, g, cap),
-                          (i64, i32)),
-    }
-
-
-@pytest.mark.parametrize("cap", CAPS)
-def test_every_default_on_native_kernel_compiles_for_v5e(cap, one_chip,
-                                                         monkeypatch):
-    from spark_rapids_tpu.config import TpuConf
-    from spark_rapids_tpu.ops import native
-    native.maybe_configure(TpuConf())
-    default_on = [k for k in native.KERNELS if native.gate_enabled(k)]
-    cases = _native_cases(cap)
-    assert set(default_on) <= set(cases), \
-        "a native kernel is default-on without a v5e compile case"
-    # The CPU backend would trace the kernels in interpret mode (under
-    # forced()) or not at all; compile what a TPU backend would.
-    monkeypatch.setattr(native, "_interpret", lambda: False)
-    for name in default_on:
-        fn, args = cases[name]
-        text = _compile(fn, one_chip, *args).as_text()
-        assert "tpu_custom_call" in text, \
-            f"{name}: no Mosaic kernel in the compiled program"
-
-
-def test_refused_native_kernels_are_default_off(one_chip, monkeypatch):
-    """The other half of the rule: what Mosaic refuses today must not be
-    default-on. When a kernel is repaired this test says so — flip its
-    gate then, and it joins the test above."""
-    from spark_rapids_tpu.config import TpuConf
-    from spark_rapids_tpu.ops import native
-    native.maybe_configure(TpuConf())
-    monkeypatch.setattr(native, "_interpret", lambda: False)
-    for name, (fn, args) in _native_cases(CAPS[0]).items():
-        try:
-            _compile(fn, one_chip, *args)
-            compiles = True
-        except Exception:       # whatever Mosaic raised: it refused
-            compiles = False
-        if not compiles:
-            assert not native.gate_enabled(name), \
-                f"{name} does not compile for v5e and is default-on"
-
-
 # -- main-path jax.numpy kernels ------------------------------------------------
 
 @pytest.mark.parametrize("cap", CAPS)
@@ -158,9 +95,7 @@ def test_wire_decode_program(cap, one_chip):
     entries, _total = wire._batch_layout(cap, specs)
     arrays = [np.zeros(shape, np.bool_ if name == "bool" else name)
               for _off, name, shape, _nbytes in entries]
-    out = _compile(wire._decode_fn(cap, specs), one_chip,
-                   arrays[:-1], arrays[-1])
-    assert "tpu_custom_call" not in out.as_text()   # no native kernel live
+    _compile(wire._decode_fn(cap, specs), one_chip, arrays[:-1], arrays[-1])
 
 
 @pytest.mark.parametrize("cap", CAPS)

@@ -45,8 +45,7 @@ def test_cpu_rehearsal_walks_every_phase_and_names_the_cpu(capsys):
     phases = [ln.get("phase") for ln in lines[:-1]]
     for want in ("environment", "sync_round_trip", "native_build",
                  "datagen", "oracle", "collect", "query", "recovery",
-                 "to_jax",
-                 "native_kernels", "compile_cache", "device_memory"):
+                 "to_jax", "compile_cache", "device_memory"):
         assert want in phases, f"phase {want} printed nothing"
     queries = {ln["query"]: ln for ln in lines if ln.get("phase") == "query"}
     assert set(queries) == set(chip_smoke.QUERIES)
@@ -61,8 +60,8 @@ def test_cpu_rehearsal_walks_every_phase_and_names_the_cpu(capsys):
 
 
 def test_single_chip_session_is_the_default_plus_two_data_assertions():
-    # The smoke proves the DEFAULT path: its session carries what
-    # bench.py asserts about the data and not one engine setting.
+    # The smoke proves the DEFAULT path: its session carries two
+    # assertions about the data and not one engine setting.
     assert chip_smoke.session().conf.raw == {
         "spark.rapids.sql.variableFloatAgg.enabled": True,
         "spark.rapids.sql.hasNans": False}

@@ -1,7 +1,6 @@
 """Every registered config key must change behavior somewhere: semaphore
 admission, stableSort, hasNans, improvedFloatOps, cast gates,
-replaceSortMergeJoin, skipAggPassReductionRatio (VERDICT r3 item 7 — no
-decorative keys)."""
+replaceSortMergeJoin, skipAggPassReductionRatio (no decorative keys)."""
 
 import threading
 import time

@@ -217,8 +217,7 @@ class TestMath:
 
     def test_inverse_hyperbolics_datagen(self):
         """asinh/acosh/atanh dual-engine parity over adversarial doubles
-        (NaN/±inf/±0/huge), with pandas-style numpy oracles (VERDICT
-        expression-gap satellite)."""
+        (NaN/±inf/±0/huge), with pandas-style numpy oracles."""
         from data_gen import DoubleGen, unary_op_batch
         b = unary_op_batch(DoubleGen(), n=96, seed=11)
         for cls in (E.Asinh, E.Acosh, E.Atanh):
@@ -831,7 +830,7 @@ class TestSplitSubstringIndex:
 
 
 class TestMd5:
-    """Md5 (VERDICT row 8 expression-gap remainder): the vectorized
+    """Md5: the vectorized
     device/host MD5 against hashlib over data_gen strings, including
     every padding boundary (55/56/64-byte chunk edges)."""
 

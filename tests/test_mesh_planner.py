@@ -1,6 +1,6 @@
 """Planner-lowered collective shuffle: full DataFrame queries execute over
 the 8-virtual-CPU-device mesh (conftest) with mesh.enabled, and results
-match the single-process exchange and the host oracle (VERDICT r1 item 4).
+match the single-process exchange and the host oracle.
 """
 
 import numpy as np

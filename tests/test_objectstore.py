@@ -315,7 +315,7 @@ def _bcast_ctx(stub, wid, exchange, gens=None, **over):
 def test_broadcast_cache_publish_then_adopted_by_peer(stub):
     """The first process to build a broadcast single publishes it; a
     peer process of the same query adopts the committed blob instead of
-    re-collecting — and the counters bench.py records prove it."""
+    re-collecting — and the transport counters prove it."""
     ex = object()
     single = _batch([1, 2, 3], [10, 20, 30])
     BC.maybe_publish(_bcast_ctx(stub, "w0", ex), ex, single)

@@ -1,4 +1,4 @@
-"""ML hand-off + observability surfaces (VERDICT r4 item 10):
+"""ML hand-off + observability surfaces:
 DataFrame.to_jax zero-host-round-trip export (ColumnarRdd.scala:41-49),
 DataFrame.metrics (GpuExec.scala:27-56), trace annotations in timed(),
 and the catalog's alloc-debug leak report (RapidsConf.scala:288)."""

@@ -1,4 +1,4 @@
-"""Out-of-core sort (VERDICT r4 item 7): sorting a partition LARGER than
+"""Out-of-core sort: sorting a partition LARGER than
 the device budget completes via the sample-sort spill path and matches
 the host oracle — beyond the reference's v0.3 RequireSingleBatch
 (GpuSortExec.scala:50)."""
@@ -52,7 +52,7 @@ def test_sort_in_core_path_unchanged():
 
 
 def test_window_larger_than_device_budget():
-    """Partition-chunked windows (the other half of VERDICT item 7):
+    """Partition-chunked windows (the other half of out-of-core):
     a partitioned window over data beyond the device budget range-splits
     by partition key and matches the host oracle."""
     from spark_rapids_tpu.plan.logical import agg_sum, col

@@ -1,4 +1,4 @@
-"""Pandas-UDF exec family (VERDICT r4 item 8): map_in_pandas,
+"""Pandas-UDF exec family: map_in_pandas,
 apply_in_pandas (grouped map), cogrouped map, grouped-agg pandas UDFs —
 host islands inside device plans with a bounded worker pool
 (GpuMapInPandasExec / GpuFlatMapGroupsInPandasExec /

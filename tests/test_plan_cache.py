@@ -235,7 +235,7 @@ def test_scheduler_per_tenant_stats(tpch_dir):
 
 def test_plan_bind_span_under_budget(tpch_dir):
     """Acceptance: steady-state plan+bind < 5ms, measured via the trace
-    span (generous 50ms CI bound; bench.py reports the real number)."""
+    span (generous 50ms CI bound)."""
     from spark_rapids_tpu import monitoring
     s = _session()
     s.set("spark.rapids.sql.trace.enabled", True)

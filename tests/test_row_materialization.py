@@ -1,7 +1,7 @@
 """Vectorized row materialization (ISSUE 4 satellite): the one-pass
 ``HostColumn.to_list`` / ``HostBatch.to_pylist`` must produce values
-IDENTICAL (types included) to the reference per-row loop it replaced.
-scripts/bench_rows.py measures the speedup; this file pins semantics.
+IDENTICAL (types included) to the reference per-row loop it replaced:
+this file pins semantics.
 """
 
 import math

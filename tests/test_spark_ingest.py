@@ -1,4 +1,4 @@
-"""Plugin-mode slice (VERDICT r4 item 9): ingest CAPTURED Spark physical
+"""Plugin-mode slice: ingest CAPTURED Spark physical
 plans — the text a user's real cluster prints from df.explain() — and
 execute them on this engine with results matching the pandas oracle
 (SQLPlugin.scala:28 / GpuOverrides.scala:1991 identity, via plan capture

@@ -1,5 +1,5 @@
 """All 22 TPC-H queries, device engine vs the pandas oracle
-(TpchLikeSpark.scala:293-onward parity — VERDICT r4 item 4).
+(TpchLikeSpark.scala:293-onward parity).
 
 Each query runs through the full planner/device pipeline on the CPU
 backend at a small scale factor and must match the independent pandas

@@ -1,4 +1,4 @@
-"""UDF tier (VERDICT r3 item 6; ref udf-compiler/Instruction.scala +
+"""UDF tier (ref udf-compiler/Instruction.scala +
 CatalystExpressionBuilder.scala for compilation,
 GpuArrowEvalPythonExec.scala:494 for the python fallback): AST
 compilation of the restricted subset, the host-roundtrip fallback with

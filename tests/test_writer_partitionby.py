@@ -1,5 +1,5 @@
-"""Dynamic-partition writes + write stats (VERDICT r4 item 5;
-GpuFileFormatWriter.scala:338, BasicColumnarWriteStatsTracker.scala:180)."""
+"""Dynamic-partition writes + write stats
+(GpuFileFormatWriter.scala:338, BasicColumnarWriteStatsTracker.scala:180)."""
 
 import os
 
