@@ -1,0 +1,34 @@
+"""cell_counters.py — one cell of the benchmark, then the process totals
+its ``counters`` line does not carry.
+
+    python scripts/cell_counters.py --workload <cell> --seed <n> --seconds <s> --trace 0
+                                    [--rehearse-cpu --scale <sf>]
+
+The arguments are ``benchmark/run.py``'s, which runs in this process as it
+would alone; afterwards one more JSON line gives ``columnar/batch.py
+counters()`` (what ``coalesce_iter(shrink=True)`` decided, PR 32) for ALL
+the collects of the process: two warm-ups of each query of the mix, the
+window's ``queries`` (its ``window`` line) and, in a traced run, ten more.
+The counts follow from shapes and live counts alone, so a CPU rehearsal at
+the cell's scale gives the chip's counts; its times are no device numbers.
+"""
+
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [os.path.join(ROOT, "benchmark"), ROOT]
+
+
+def main() -> int:
+    import run
+    rc = run.main(sys.argv[1:])
+    from spark_rapids_tpu.columnar import batch
+    print(json.dumps({"phase": "shrink_counters", **batch.counters()}),
+          flush=True)
+    return rc
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,8 +1,9 @@
 """chip_probe.py — the small on-chip measurements the defaults and notes quote.
 
     python scripts/chip_probe.py [sync] [link] [upload] [prefix] [slots] [rowmove]
+                                 [shrinkrule]
 
-With no section named it runs all six. One process, one chip, one JSON
+With no section named it runs all seven. One process, one chip, one JSON
 object per line, every reading on the host's clock around a
 ``block_until_ready`` (on an attached chip that waits for completion):
 
@@ -41,7 +42,21 @@ object per line, every reading on the host's clock around a
              (four compactions + stack against ``split_batch``'s one pass)
              and the concat of four members, at the shard and at a
              two-phase piece capacity — the table behind the module's
-             docstring.
+             docstring;
+- ``shrinkrule`` what ``coalesce_iter(shrink=True)`` buys its two
+             consumers at 786,432 rows and 98 / 67 / 50 / 45 / 33 / 25 /
+             12 % live under a selection vector (live buckets of 1, 1,
+             2/3, 1/2, 1/3, 1/4 and 1/8 of the capacity): (a)
+             ``shrink_to_capacity`` to the live bucket and then the
+             consumer at that bucket, against
+             (b) the consumer at the full capacity reading the selection
+             vector. On q3's lineitem layout (an int64 key, two float64)
+             the consumer is the dense join probe over a build side of
+             ~147,000 unique keys in 6,000,000, with the compaction of
+             its output to 4,096 rows that the aggregate above makes
+             either way; on q1's layout it is the aggregate's update, four
+             groups (slots) and sorted. The table behind
+             ``batch.PROBE_SHRINK_RATIO`` and the rule of ``shrink_all``.
 
 Like ``chip_smoke.py`` it refuses any backend but a TPU unless
 ``--cpu-rehearsal`` is given, which runs the control flow at a tiny size
@@ -59,7 +74,8 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-SECTIONS = ("sync", "link", "upload", "prefix", "slots", "rowmove")
+SECTIONS = ("sync", "link", "upload", "prefix", "slots", "rowmove",
+            "shrinkrule")
 LABEL = {}
 
 
@@ -500,9 +516,115 @@ def probe_rowmove(jax, small: bool) -> None:
              capacity=cap)
 
 
+def q3_like_probe(rows: int, build_rows: int, key_span: int):
+    """q3's second join, lineitem against the filtered orders: a jitted
+    direct-address probe (the join's own ``_dense_step``) over a build
+    side of ``build_rows`` unique int64 keys out of ``key_span`` carrying a
+    date and an int32, and a lineitem-like probe batch of ``rows`` rows
+    (the key, two float64), ``4 * build_rows / key_span`` of whose keys are
+    in the build side (q3 at SF1: a tenth)."""
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.columnar import dtypes as dt
+    from spark_rapids_tpu.columnar.batch import DeviceBatch, DeviceColumn
+    from spark_rapids_tpu.exprs.base import BoundReference as Ref
+    from spark_rapids_tpu.ops import InMemorySourceExec
+    from spark_rapids_tpu.ops import join as J
+    rng = np.random.default_rng(3)
+    yes = lambda n: jnp.ones((n,), jnp.bool_)            # noqa: E731
+
+    def batch(cols, n):
+        return DeviceBatch(tuple(DeviceColumn(t, jnp.asarray(d), yes(n))
+                                 for t, d in cols), jnp.asarray(n, jnp.int32))
+    # Order keys are sparse (a quarter of the span exists), and a probe
+    # key is one of those that exist.
+    orders = rng.choice(key_span, key_span // 4, replace=False) \
+        .astype(np.int64)
+    keys = orders[:build_rows]
+    build = batch([(dt.INT64, keys),
+                   (dt.DATE, rng.integers(8000, 9200, build_rows)
+                    .astype(np.int32)),
+                   (dt.INT32, np.zeros(build_rows, np.int32))], build_rows)
+    probe = batch([(dt.INT64, rng.choice(orders, rows)),
+                   (dt.FLOAT64, np.round(rng.uniform(900, 105_000, rows), 2)),
+                   (dt.FLOAT64, rng.integers(0, 11, rows) / 100.0)], rows)
+    op = J.BroadcastHashJoinExec(
+        InMemorySourceExec((("l_orderkey", dt.INT64), ("price", dt.FLOAT64),
+                            ("disc", dt.FLOAT64)), [[]]),
+        InMemorySourceExec((("o_orderkey", dt.INT64), ("o_date", dt.DATE),
+                            ("o_prio", dt.INT32)), [[]]),
+        [Ref(0, dt.INT64)], [Ref(0, dt.INT64)], "inner")
+    built = J.build_side(build, [0])
+    J._maybe_build_dense(built, built.batch, built.key_ordinals)
+    assert built.table is not None, "the build side took no dense table"
+    dense = op._dense_jit_fn()
+    return probe, lambda b: dense(built, b, probe_keys=(0,),
+                                  build_is_right=True)
+
+
+def probe_shrinkrule(jax, small: bool) -> None:
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.columnar.batch import (bucket_capacity,
+                                                 shrink_to_capacity)
+    rows = 3 << (8 if small else 18)
+    n = 3 if small else 10
+    shares = (98, 50) if small else (98, 67, 50, 45, 33, 25, 12)
+    off = jnp.asarray(0, jnp.int64)
+
+    def steady(fn, *args):
+        """Median ms of a call after its first, which compiles."""
+        jax.block_until_ready(fn(*args))
+        return ms(timed(lambda: fn(*args), n))["median"]
+
+    def live_rows(b):
+        return int(jax.device_get(b.live_count()))
+
+    def selected(b, pct):
+        """``b`` with ``pct`` % of its rows selected, its live count and
+        bucket, and the same rows compacted to that bucket."""
+        keep = np.random.default_rng(pct).random(b.capacity) < pct / 100
+        b = b.with_sel(jnp.asarray(keep))
+        live = live_rows(b)
+        bucket = bucket_capacity(live)
+        return b, live, bucket, shrink_to_capacity(b, bucket)
+
+    lineitem, dense = q3_like_probe(
+        rows, *((96, 4096) if small else (147_000, 6_000_000)))
+    agg, make = q1_like_aggregate()
+    slot = jax.jit(lambda b: agg._update_batch(b, off))
+    srt = jax.jit(lambda b: agg._sorted_update(*agg._project_inputs(b), off))
+    q1_batch = make(rows, 4)
+    for pct in shares:
+        b, live, bucket, small_b = selected(lineitem, pct)
+        out_full, out_small = dense(b), dense(small_b)
+        matched = live_rows(out_full)
+        assert matched == live_rows(out_small)
+        out_cap = bucket_capacity(matched)
+        emit("shrinkrule", layout="q3_lineitem", consumer="dense_probe",
+             rows=rows, live_pct=pct, live=live, bucket=bucket,
+             matched=matched,
+             compact_ms=steady(shrink_to_capacity, b, bucket),
+             probe_at_bucket_ms=steady(dense, small_b),
+             probe_at_capacity_ms=steady(dense, b),
+             out_compact_from_bucket_ms=steady(
+                 shrink_to_capacity, out_small, out_cap),
+             out_compact_from_capacity_ms=steady(
+                 shrink_to_capacity, out_full, out_cap))
+        b, live, bucket, small_b = selected(q1_batch, pct)
+        assert int(slot(b).num_rows) == int(slot(small_b).num_rows) == 4
+        emit("shrinkrule", layout="q1_lineitem", consumer="update",
+             rows=rows, live_pct=pct, live=live, bucket=bucket,
+             compact_ms=steady(shrink_to_capacity, b, bucket),
+             slot_at_bucket_ms=steady(slot, small_b),
+             slot_at_capacity_ms=steady(slot, b),
+             sorted_at_bucket_ms=steady(srt, small_b),
+             sorted_at_capacity_ms=steady(srt, b))
+
+
 PROBES = {"sync": probe_sync, "link": probe_link, "upload": probe_upload,
           "prefix": probe_prefix, "slots": probe_slots,
-          "rowmove": probe_rowmove}
+          "rowmove": probe_rowmove, "shrinkrule": probe_shrinkrule}
 
 
 def main(argv=None) -> int:

@@ -21,8 +21,10 @@ validity False and zeroed data so results stay deterministic.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
-from typing import List, Optional, Sequence, Tuple
+import threading
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -289,10 +291,47 @@ def jit_concat_batches(batches: Sequence[DeviceBatch],
 # re-measured (ROADMAP A3).
 MIN_SHRINK_BYTES = 4 << 20
 
+# A join probe reads its input through ``row_mask()``, so a member with a
+# selection vector is compacted in front of it only where its live bucket
+# is at most 1/R of its capacity. Measured on a v5e at 786,432 rows of
+# TPC-H q3's lineitem (scripts/chip_probe.py shrinkrule, PR 32; ms a call,
+# compact + probe at the bucket + the output's compaction, against probe
+# at capacity + that compaction): bucket 2/3 27.3 / 19.4, 1/2 22.8 / 19.4,
+# 1/3 19.2 / 19.2, 1/8 11.8 / 18.7 — the probe alone breaks even at a
+# third. But a kept batch's capacity rides on in the join's output: with
+# R = 3 q3's orders batches (bucket 1/2) stayed at 196,608 rows, the next
+# join's build side sorted 1,572,864 rows where it had sorted 786,432, and
+# the query went 0.281 -> 0.340 s. R = 2 is the largest the cell bears.
+PROBE_SHRINK_RATIO = 2
+
+# -- counters -----------------------------------------------------------------
+# Process-wide totals of what ``coalesce_iter(shrink=True)`` decided, in
+# the manner of ``mesh_exchange.counters()``: ``shrinkMembers`` (members
+# a shrinking flush saw), ``shrinkCompacted`` (rewritten at a smaller
+# capacity), ``shrinkKeptSameBucket`` and ``shrinkKeptBelowRatio``
+# (selection-vector members passed on as they were: the live bucket is
+# the capacity, or is above ``1 / keep_ratio`` of it) and
+# ``shrinkRowsKept`` (rows of capacity those two did not rewrite).
+_COUNTER_LOCK = threading.Lock()
+_COUNTERS: collections.Counter = collections.Counter()
+_COUNTS = ("shrinkMembers", "shrinkCompacted", "shrinkKeptSameBucket",
+           "shrinkKeptBelowRatio", "shrinkRowsKept")
+_DECISIONS = _COUNTS[1:4]
+
+
+def counters() -> Dict[str, int]:
+    with _COUNTER_LOCK:
+        return {k: _COUNTERS[k] for k in _COUNTS}
+
+
+def reset_counters() -> None:
+    with _COUNTER_LOCK:
+        _COUNTERS.clear()
+
 
 def coalesce_iter(batches, target_rows: int, shrink: bool = False,
                   target_bytes: int = 512 * 1024 * 1024,
-                  owner: Optional[str] = None):
+                  owner: Optional[str] = None, keep_ratio: int = 1):
     """Group a batch stream into ~``target_rows``-capacity batches with
     minimal host syncs (grouping keys off static capacities, the exchange
     serving idiom — GpuCoalesceBatches.scala:115 done the TPU way).
@@ -302,10 +341,17 @@ def coalesce_iter(batches, target_rows: int, shrink: bool = False,
     batches through a join probe or partial aggregate costs 8 floors
     where one coalesced batch costs one + a single packed concat gather.
 
-    ``shrink=True`` additionally compacts sparse members first (one
-    batched sizes pull per group, skipped where rows_hint is known):
-    consumers whose kernels scale with CAPACITY (sort-based aggregation)
-    must not pay 4M-row sorts for a selective join's 30k live rows.
+    ``shrink=True`` additionally re-buckets sparse members first (one
+    batched sizes pull per group, skipped where rows_hint is known and
+    below ``MIN_SHRINK_BYTES``): consumers whose kernels scale with
+    CAPACITY (sort-based aggregation) must not pay 4M-row sorts for a
+    selective join's 30k live rows. Both consumers (the keyed
+    aggregate's update, the join probe) read selection vectors, so a
+    member is compacted only where it gets smaller: never into its own
+    capacity, and with ``keep_ratio`` R > 1 only where its live bucket
+    is at most 1/R of its capacity (``shrink_all`` has the rule). A
+    member passed on as it was keeps its selection vector and carries
+    the pulled count as ``rows_hint``.
 
     ``target_bytes`` bounds the coalesced device size as well — wide
     (many-string-column) rows must not ride the row target into
@@ -328,9 +374,14 @@ def coalesce_iter(batches, target_rows: int, shrink: bool = False,
             # The pull is a host sync, and the shrinks are dispatched
             # behind it into a queue run dry: one span for the idiom.
             from spark_rapids_tpu import monitoring
+            tally = collections.Counter(shrinkMembers=len(g))
             with monitoring.op_span(owner or "coalesce", "shrink-all",
-                                    level=monitoring.LEVEL_KERNEL):
-                g, _ = shrink_all(g, min_bytes=MIN_SHRINK_BYTES)
+                                    level=monitoring.LEVEL_KERNEL) as sp:
+                g, _ = shrink_all(g, min_bytes=MIN_SHRINK_BYTES,
+                                  keep_ratio=keep_ratio, tally=tally)
+                sp.note(**{k: tally[k] for k in _DECISIONS})
+            with _COUNTER_LOCK:
+                _COUNTERS.update(tally)
         if len(g) == 1:
             return g[0]
         cap = bucket_capacity(sum(b.capacity for b in g))
@@ -357,10 +408,15 @@ def coalesce_iter(batches, target_rows: int, shrink: bool = False,
 
 
 def shrink_to_capacity(batch: DeviceBatch, capacity: int) -> DeviceBatch:
-    """Re-bucket a batch whose live rows fit a smaller capacity (after a
-    groupby/filter the packed prefix is all that matters). Jitted;
-    requires ``live_count <= capacity``. Selection vectors compact away
-    (cost scales with the small OUTPUT capacity — rowmove.compact_batch)."""
+    """Re-bucket a batch whose live rows fit ``capacity`` as a DENSE
+    batch (after a groupby/filter the packed prefix is all that matters).
+    Jitted; requires ``live_count <= capacity``. A selection vector
+    always compacts away, at the batch's own capacity too (cost: an
+    index scatter over the INPUT capacity and a packed gather per slab
+    over the OUTPUT capacity — rowmove.compact_batch); a batch without
+    one at its own capacity comes back as it is. Whether a
+    selection-vector batch is worth compacting is the caller's question:
+    ``shrink_all(keep_ratio=...)`` asks it for consumers that mask."""
     if capacity >= batch.capacity and batch.sel is None:
         return batch
     hint = batch.rows_hint
@@ -370,8 +426,15 @@ def shrink_to_capacity(batch: DeviceBatch, capacity: int) -> DeviceBatch:
             from spark_rapids_tpu.columnar.rowmove import compact_batch
             if b.sel is not None:
                 return compact_batch(b, capacity=capacity)
-            idx = jnp.arange(capacity, dtype=jnp.int32)
-            return b.gather(idx, b.num_rows)
+            # The live rows are the prefix: slice it. (A gather by
+            # arange packs the INPUT's capacity into slabs first: 0.5 ms
+            # a call to take 4 rows of a 786,432-row partial, PR 32.)
+            # Slots past num_rows come out zeroed, as the gather left them.
+            live = jnp.arange(capacity, dtype=jnp.int32) < b.num_rows
+            heads = [jax.tree.map(lambda x: x[:capacity], c)
+                     for c in b.columns]
+            return DeviceBatch(tuple(c.with_validity(c.validity & live)
+                                     for c in heads), b.num_rows)
         return jax.jit(_shrink)
 
     out = _kernel_lookup("shrink", (capacity,), _build)(batch)
@@ -379,9 +442,27 @@ def shrink_to_capacity(batch: DeviceBatch, capacity: int) -> DeviceBatch:
     return out
 
 
+def _shrink_decision(batch: DeviceBatch, cap: int,
+                     keep_ratio: Optional[int]) -> Optional[str]:
+    """What ``shrink_all`` does with a counted member whose live bucket
+    is ``cap``, as the name it has in ``counters()``; None where there
+    is nothing to do (dense, and at its bucket already)."""
+    if batch.sel is None:
+        return "shrinkCompacted" if cap < batch.capacity else None
+    if keep_ratio is None:
+        return "shrinkCompacted"
+    if cap >= batch.capacity:
+        return "shrinkKeptSameBucket"
+    if cap * keep_ratio > batch.capacity:
+        return "shrinkKeptBelowRatio"
+    return "shrinkCompacted"
+
+
 def shrink_all(batches: Sequence[DeviceBatch],
-               min_bytes: int = 0) -> Tuple[List[DeviceBatch],
-                                            List[Optional[int]]]:
+               min_bytes: int = 0,
+               keep_ratio: Optional[int] = None,
+               tally: Optional[collections.Counter] = None,
+               ) -> Tuple[List[DeviceBatch], List[Optional[int]]]:
     """Two-phase sizes-then-shrink over a batch list (SURVEY §7): pull
     every unknown live count in ONE batched ``jax.device_get`` (one host
     sync instead of one per batch), then re-bucket
@@ -391,7 +472,22 @@ def shrink_all(batches: Sequence[DeviceBatch],
     callers that NEED exact counts (the exchange's bucket accounting)
     keep the default 0. Returns (shrunk batches, live counts — None
     where the pull was skipped). The one shared implementation of this
-    idiom for aggregates, exchanges, broadcasts and downloads."""
+    idiom for aggregates, exchanges, broadcasts and downloads.
+
+    ``keep_ratio`` is what the caller says of its consumer. None (shard
+    writers, serialisers, ``to_pylist``): the consumer wants dense
+    batches, every counted selection vector compacts away. An int R >= 1:
+    the consumer masks (``row_mask()``, ``live_count()``), so a counted
+    member with a selection vector is compacted only where that makes it
+    smaller — never when ``bucket_capacity(live)`` is its own capacity
+    (the rewrite would hand the same capacity to a consumer that masks
+    either way), and only when the bucket is at most ``capacity // R``.
+    R = 1 is "any smaller bucket", for a consumer that may sort. A member
+    kept goes on as the same object with ``rows_hint`` set to its count,
+    so that no later ``shrink_all`` pulls it again. A member without a
+    selection vector is cut to any smaller bucket as ever (its prefix).
+    ``tally``, if given, counts the decisions under the names of
+    ``counters()``."""
     import jax
     batches = list(batches)
     counts: List[Optional[int]] = [b.rows_hint for b in batches]
@@ -405,7 +501,14 @@ def shrink_all(batches: Sequence[DeviceBatch],
     out = []
     for b, c in zip(batches, counts):
         if c is not None:
-            b = shrink_to_capacity(b, bucket_capacity(max(c, 1)))
+            cap = bucket_capacity(max(c, 1))
+            what = _shrink_decision(b, cap, keep_ratio)
+            if what == "shrinkCompacted":
+                b = shrink_to_capacity(b, cap)
+            if tally is not None and what is not None:
+                tally[what] += 1
+                if what != "shrinkCompacted":
+                    tally["shrinkRowsKept"] += b.capacity
             b.rows_hint = c
         out.append(b)
     return out, counts

@@ -185,6 +185,9 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    def note(self, **args):
+        pass
+
 
 _NOOP = _NoopSpan()
 
@@ -239,6 +242,10 @@ class _Span:
         self._ann = ann
         self._t0 = _now_ns()
         return self
+
+    def note(self, **args):
+        """Add args learnt while the span is open (recorded at exit)."""
+        self.args = {**(self.args or {}), **args}
 
     def __exit__(self, *exc):
         t0 = self._t0

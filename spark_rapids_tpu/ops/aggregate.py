@@ -1328,8 +1328,11 @@ class HashAggregateExec(Exec):
             # Coalesce the input stream: one sort-based update kernel over
             # a 4M-row batch beats 8 over 512k (fixed per-dispatch floor),
             # and sparse join outputs compact before the capacity-scaled
-            # sort. Zero-key aggregates skip this: their masked reductions
-            # don't sort, so the concat gather would be pure overhead.
+            # sort (any smaller bucket: keep_ratio 1). A member whose live
+            # rows fill its own bucket comes as it is, selection vector and
+            # all: the update masks. Zero-key aggregates skip this: their
+            # masked reductions don't sort, so the concat gather would be
+            # pure overhead.
             from spark_rapids_tpu.columnar.batch import coalesce_iter
             from spark_rapids_tpu.memory.oom import effective_batch_target
             child_iter = coalesce_iter(

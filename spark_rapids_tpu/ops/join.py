@@ -493,7 +493,8 @@ class _JoinKernelMixin:
                             probe_keys, build_is_right: bool):
         import itertools
         from spark_rapids_tpu import config as C
-        from spark_rapids_tpu.columnar.batch import coalesce_iter
+        from spark_rapids_tpu.columnar.batch import (
+            PROBE_SHRINK_RATIO, coalesce_iter)
         jt = self.join_type
         cond = self.condition
         build_cap = built.batch.capacity
@@ -507,12 +508,14 @@ class _JoinKernelMixin:
         # members first (an upstream selective join's output would
         # otherwise make EVERY downstream probe gather pay its full
         # capacity); the sizes pull is batched per group and skipped
-        # where rows_hint is known (scans).
+        # where rows_hint is known (scans). Every probe path below reads
+        # its input through row_mask(), so a member whose live bucket is
+        # over 1/PROBE_SHRINK_RATIO of its capacity comes as it is.
         probe_iter = coalesce_iter(
             probe_iter, int(ctx.conf.get(C.BATCH_SIZE_ROWS)),
             shrink=True,
             target_bytes=int(ctx.conf.get(C.BATCH_SIZE_BYTES)),
-            owner=self.name)
+            owner=self.name, keep_ratio=PROBE_SHRINK_RATIO)
         # Dispatch the FIRST probe batch's upstream work before blocking on
         # the build stats: the async stats copy then overlaps probe-side
         # scan/decode instead of serializing ahead of it.

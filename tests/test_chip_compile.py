@@ -124,11 +124,14 @@ def test_filter_and_global_aggregate(cap, one_chip):
     _compile(step, one_chip, _lineitem_like(cap))
 
 
-def test_grouped_aggregate_update(one_chip):
+@pytest.mark.parametrize("sel", [False, True],
+                         ids=["dense", "selection-vector"])
+def test_grouped_aggregate_update(sel, one_chip):
     """q1's grouped update at a reader batch's 786,432 rows: the probe
     for the batch's distinct keys, the ``cond``, and both branches (the
     slot loops and the sort) in one program. One capacity: the sort in
-    it takes the chip's compiler half a minute."""
+    it takes the chip's compiler half a minute. Since PR 32 the filtered
+    scan batch reaches it uncompacted, under its selection vector."""
     import os
     import sys
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
@@ -139,6 +142,8 @@ def test_grouped_aggregate_update(one_chip):
     batch = jax.tree.map(
         lambda x: np.zeros((cap,) + x.shape[1:], x.dtype) if x.ndim
         else np.asarray(cap, x.dtype), make(8, 4))
+    if sel:
+        batch = batch.with_sel(np.ones((cap,), np.bool_))
     out = _compile(lambda b: agg._update_batch(b, jnp.asarray(0, jnp.int64)),
                    one_chip, batch)
     text = out.as_text()
