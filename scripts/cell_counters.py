@@ -8,7 +8,12 @@ The arguments are ``benchmark/run.py``'s, which runs in this process as it
 would alone; afterwards one more JSON line gives ``columnar/batch.py
 counters()`` (what ``coalesce_iter(shrink=True)`` decided, PR 32) for ALL
 the collects of the process: two warm-ups of each query of the mix, the
-window's ``queries`` (its ``window`` line) and, in a traced run, ten more.
+window's ``queries`` (its ``window`` line) and, in a traced run, ten more;
+and, in a traced run, a line with the flight recorder's ``counters()``
+(PR 33: ``collects``, ``expandRowsIn`` / ``expandRowsOut`` /
+``expandProjections``, ``aggUpdateRows``, ``aggConsolidateLevels``,
+``windowRowsIn`` / ``windowBatches`` / ``windowOutOfCoreSplits``; counted
+only while the recorder is on, so empty at ``--trace 0``).
 The counts follow from shapes and live counts alone, so a CPU rehearsal at
 the cell's scale gives the chip's counts; its times are no device numbers.
 """
@@ -25,7 +30,10 @@ def main() -> int:
     import run
     rc = run.main(sys.argv[1:])
     from spark_rapids_tpu.columnar import batch
+    from spark_rapids_tpu.monitoring import recorder
     print(json.dumps({"phase": "shrink_counters", **batch.counters()}),
+          flush=True)
+    print(json.dumps({"phase": "recorder_counters", **recorder.counters()}),
           flush=True)
     return rc
 

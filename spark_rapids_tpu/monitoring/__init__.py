@@ -22,7 +22,8 @@ everything engine-shaped is lazy.
 from spark_rapids_tpu.monitoring import history, telemetry  # noqa: F401
 from spark_rapids_tpu.monitoring.recorder import (     # noqa: F401
     LEVEL_KERNEL, LEVEL_OPERATOR, LEVEL_QUERY, adopt, category_breakdown,
-    configure, current, enabled, events, export_chrome, instant, level,
-    maybe_configure, now_ns, op_span, open_span_count, process_tag, query_ids,
-    record_span, reset, self_ns, self_times, set_process_tag, snapshot,
-    span, thread_names, trace_enabled)
+    configure, count, counters, current, enabled, events, export_chrome,
+    instant, level, maybe_configure, now_ns, op_span, open_span_count,
+    process_tag, query_ids, record_span, reset, reset_counters, self_ns,
+    self_times, set_process_tag, snapshot, span, thread_names,
+    trace_enabled)

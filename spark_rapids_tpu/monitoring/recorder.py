@@ -108,6 +108,11 @@ _LISTENERS: Tuple[str, ...] = ()
 # the next ordinary record (or read) moves them into their rings.
 _PENDING: collections.deque = collections.deque(maxlen=4096)
 
+# Process-wide counts that operators add to while the recorder is on
+# (:func:`count`): capacity rows and the like, known to the host without a
+# read of the device. Totals of the process, kept through :func:`reset`.
+_COUNTERS: Dict[str, int] = {}
+
 # Epoch all timestamps are relative to (perf_counter_ns at import), so
 # exported traces start near 0 instead of at an arbitrary boot offset.
 _EPOCH_NS = time.perf_counter_ns()
@@ -321,6 +326,30 @@ def instant(name: str, cat: str, args: Optional[dict] = None,
              threading.get_ident(), q, args, 0, current()), q)
 
 
+def count(name: str, n: int = 1) -> None:
+    """Add ``n`` to the process-wide counter ``name``; nothing while the
+    recorder is off. For what an operator knows on the host as it
+    dispatches (rows of capacity, batches, levels of a merge tree): a
+    counter never reads the device. ``collects`` (one a device collect,
+    from the funnel) is what a per-query mean divides by."""
+    if not _ENABLED:
+        return
+    with _LOCK:
+        _COUNTERS[name] = _COUNTERS.get(name, 0) + n
+
+
+def counters() -> Dict[str, int]:
+    """The counts of :func:`count` since the process began (or since
+    :func:`reset_counters`): every collect the recorder was on for."""
+    with _LOCK:
+        return dict(_COUNTERS)
+
+
+def reset_counters() -> None:
+    with _LOCK:
+        _COUNTERS.clear()
+
+
 # -- what only an enabled recorder listens to ---------------------------------
 
 _GC_OPEN = None     # (t0, annotation, generation, parent): gcs do not nest
@@ -464,7 +493,8 @@ def configure(enabled_: bool, level_: int = LEVEL_OPERATOR,
 
 
 def reset() -> None:
-    """Drop every recorded event (test isolation; keeps configuration)."""
+    """Drop every recorded event (test isolation; keeps configuration
+    and the counters, which have :func:`reset_counters`)."""
     _PENDING.clear()
     with _LOCK:
         _RINGS.clear()

@@ -1244,6 +1244,7 @@ class HashAggregateExec(Exec):
         accumulated. mixed_final's distinct-update kernel is chunk-safe:
         its distinct inputs are globally unique rows, so chunk updates
         followed by plain merges count each value exactly once."""
+        from spark_rapids_tpu import monitoring
         from spark_rapids_tpu.columnar.batch import (
             jit_concat_batches, shrink_all)
         _, merge, finalize, mixed, _pt = self._jits()
@@ -1252,33 +1253,44 @@ class HashAggregateExec(Exec):
         level = 0
         batches = pending
         while True:
-            with timed(m, "sizesPullTime"):
-                batches, counts = shrink_all(batches)
-            if level == 0 and fresh:
-                # An update's output has its input's capacity.
-                _count_updates(m, zip((b.capacity for b in pending[-fresh:]),
-                                      counts[-fresh:]))
-            if len(batches) == 1:
-                single = batches[0]
-                if level == 0 and first_stage is not None:
-                    single = first_stage(single)
-                break
-            stage = first_stage if (level == 0 and
-                                    first_stage is not None) else merge
-            nxt = []
-            for i in range(0, len(batches), self._CONSOLIDATE_CHUNK):
-                grp = batches[i:i + self._CONSOLIDATE_CHUNK]
-                if len(grp) == 1:
-                    # Level >= 1 singletons are already merge outputs.
-                    nxt.append(stage(grp[0]) if level == 0 else grp[0])
-                    continue
-                cap = bucket_capacity(sum(b.capacity for b in grp))
-                nxt.append(stage(jit_concat_batches(grp, cap)))
-            batches = nxt
-            level += 1
-            if len(batches) == 1:
-                single = batches[0]
-                break
+            # One span a level (its sizes pull, concats and merge
+            # dispatches): a host clock over asynchronous dispatch, so
+            # the pull holds the wait for the level before.
+            monitoring.count("aggConsolidateLevels")
+            with monitoring.span(
+                    "level", "agg-consolidate",
+                    args={"op": self.name, "level": level,
+                          "members": len(batches),
+                          "capacities": [b.capacity for b in batches]}
+                    if monitoring.enabled() else None):
+                with timed(m, "sizesPullTime"):
+                    batches, counts = shrink_all(batches)
+                if level == 0 and fresh:
+                    # An update's output has its input's capacity.
+                    _count_updates(
+                        m, zip((b.capacity for b in pending[-fresh:]),
+                               counts[-fresh:]))
+                if len(batches) == 1:
+                    single = batches[0]
+                    if level == 0 and first_stage is not None:
+                        single = first_stage(single)
+                    break
+                stage = first_stage if (level == 0 and
+                                        first_stage is not None) else merge
+                nxt = []
+                for i in range(0, len(batches), self._CONSOLIDATE_CHUNK):
+                    grp = batches[i:i + self._CONSOLIDATE_CHUNK]
+                    if len(grp) == 1:
+                        # Level >= 1 singletons are already merge outputs.
+                        nxt.append(stage(grp[0]) if level == 0 else grp[0])
+                        continue
+                    cap = bucket_capacity(sum(b.capacity for b in grp))
+                    nxt.append(stage(jit_concat_batches(grp, cap)))
+                batches = nxt
+                level += 1
+                if len(batches) == 1:
+                    single = batches[0]
+                    break
         if final_stage and self.mode in ("final", "complete",
                                          "mixed_final"):
             single = finalize(single)
@@ -1347,6 +1359,7 @@ class HashAggregateExec(Exec):
             if update_stage:
                 from spark_rapids_tpu.memory.oom import retry_on_oom
                 skipping = can_skip and ctx.cache.get(skip_key, False)
+                monitoring.count("aggUpdateRows", batch.capacity)
                 with timed(m):
                     if skipping:
                         partial = retry_on_oom(

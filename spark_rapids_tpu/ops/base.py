@@ -670,6 +670,7 @@ class Exec:
                 # ladder's tier occupancy and device high watermark —
                 # read off the catalog only if this query built one.
                 telemetry.inc("srt_collects")
+                monitoring.count("collects")
                 telemetry.observe(
                     "srt_collect_ms",
                     (time.perf_counter() - t0_collect) * 1e3)

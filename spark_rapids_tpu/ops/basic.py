@@ -540,11 +540,33 @@ class ExpandExec(Exec):
                      for n, e in zip(self.names, self.projections[0]))
 
     def execute_device(self, ctx, partition):
+        m = ctx.metrics_for(self)
         for batch in self.children[0].execute_device(ctx, partition):
-            for proj in self.projections:
-                yield eval_exprs(proj, batch)
+            # Every projection of a batch at once, as the fused stage
+            # gives them: one span an input batch.
+            with timed(m):
+                outs = [eval_exprs(proj, batch) for proj in self.projections]
+            count_expand(batch.capacity, [len(self.projections)])
+            yield from outs
 
     def execute_host(self, ctx, partition):
         for hb in self.children[0].execute_host(ctx, partition):
             for proj in self.projections:
                 yield eval_exprs_host(proj, hb, self.names)
+
+
+def count_expand(capacity: int, fanouts: Sequence[int]) -> None:
+    """The recorder's counters of one input batch of ``capacity`` rows
+    going through Expands of ``fanouts`` projections, one after the
+    other (``ExpandExec`` alone, or the members of a fused stage): every
+    projection is at its input's capacity, so the counts are rows of
+    capacity, known without a read of the device."""
+    from spark_rapids_tpu import monitoring
+    if not monitoring.enabled():
+        return
+    batches = 1
+    for k in fanouts:
+        monitoring.count("expandRowsIn", batches * capacity)
+        monitoring.count("expandProjections", batches * k)
+        batches *= k
+        monitoring.count("expandRowsOut", batches * capacity)

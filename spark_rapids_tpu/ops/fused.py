@@ -36,6 +36,7 @@ from spark_rapids_tpu.exprs.bindslots import (
 from spark_rapids_tpu.ops import kernel_cache as kc
 from spark_rapids_tpu.ops.base import (Exec, ExecContext, Schema,
     record_batch, timed)
+from spark_rapids_tpu.ops.basic import count_expand
 
 
 def _stage_specs(ops: Sequence[Exec]) -> List[Tuple[str, object]]:
@@ -132,6 +133,8 @@ class FusedStageExec(Exec):
         self._limits = [op.limit for op in self.ops
                         if isinstance(op, LocalLimitExec)]
         self._pure_project = all(k == "project" for k, _ in self._specs)
+        self._expand_fanouts = [len(payload) for k, payload in self._specs
+                                if k == "expand"]
         self._fp = kc.fingerprint(tuple(self._specs))
         self._has_binds = has_bind_slots(_spec_exprs(self._specs))
 
@@ -160,6 +163,8 @@ class FusedStageExec(Exec):
                 lambda: jax.jit(_build_fused(specs)), m)
             with timed(m):
                 outs, rems = kc.call(entry, m, batch, rems, binds)
+            if self._expand_fanouts:
+                count_expand(batch.capacity, self._expand_fanouts)
             for out in outs:
                 if self._pure_project:
                     # Row count unchanged by pure projection chains —

@@ -8,10 +8,11 @@ lowers to fused bitonic sorts — the TPU replacement for cuDF Table.orderBy.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import struct
-from typing import Iterator, List, Sequence, Tuple
+from typing import Iterator, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -126,42 +127,63 @@ def staged_exchange(spillables, schema, partitioning):
 
 
 def out_of_core_partition(ctx, metrics, child_iter, schema,
-                          split_orders: Sequence[SortOrder], batch_fn):
+                          split_orders: Sequence[SortOrder], batch_fn,
+                          trace_cat: Optional[str] = None):
     """Shared out-of-core scaffold (SortExec's sample-sort shape, also
     used by partition-chunked windows): stage the partition's batches as
     catalog spillables; small partitions run ``batch_fn`` over one
     coalesced batch, larger ones range-split by ``split_orders`` through
     the exchange into bounded spillable buckets and run ``batch_fn`` per
-    bucket (equal keys always share a bucket). Yields output batches."""
+    bucket (equal keys always share a bucket). Yields output batches.
+
+    With ``trace_cat`` the steps after the child's pull are spans of
+    that category (``gather``: the staged batches into one; ``split``:
+    the range exchange of the out-of-core path; ``compute``: the
+    dispatch of ``batch_fn``), never nested in one another and never
+    held across a yield, so their sum is a time; a split also counts
+    ``<trace_cat>OutOfCoreSplits``."""
+    from spark_rapids_tpu import monitoring
     from spark_rapids_tpu.memory.oom import retry_on_oom
     from spark_rapids_tpu.parallel.partitioning import RangePartitioning
     m = metrics
+
+    def phase(name):
+        if trace_cat is None:
+            return contextlib.nullcontext()
+        return monitoring.span(name, trace_cat)
+
     spillables, total_bytes = stage_spillables(ctx, child_iter)
     if not spillables:
         return
     bucket_budget = max(ctx.catalog.device_budget // 3, 1 << 16)
     if total_bytes <= bucket_budget or not split_orders:
-        batches = [sb.get() for sb in spillables]
-        single = coalesce_to_single_batch(batches)
-        for sb in spillables:
-            sb.close()
-        with timed(m):
+        with phase("gather"):
+            batches = [sb.get() for sb in spillables]
+            single = coalesce_to_single_batch(batches)
+            for sb in spillables:
+                sb.close()
+        with phase("compute"), timed(m):
             out = retry_on_oom(batch_fn, single)
         record_batch(m, out)
         yield out
         return
     nb = max(2, -(-total_bytes // bucket_budget))
     m.add("outOfCoreBuckets", nb)
+    if trace_cat is not None:
+        monitoring.count(trace_cat + "OutOfCoreSplits")
     ex = staged_exchange(spillables, schema,
                          RangePartitioning(list(split_orders), nb))
     try:
         for p in range(nb):
-            bucket = list(ex.execute_device(ctx, p))
+            # The first bucket's pull materializes the whole exchange.
+            with phase("split"):
+                bucket = list(ex.execute_device(ctx, p))
             if not bucket:
                 continue
-            with timed(m):
-                out = retry_on_oom(batch_fn,
-                                   coalesce_to_single_batch(bucket))
+            with phase("gather"):
+                single = coalesce_to_single_batch(bucket)
+            with phase("compute"), timed(m):
+                out = retry_on_oom(batch_fn, single)
             record_batch(m, out)
             yield out
     finally:

@@ -540,6 +540,7 @@ class WindowExec(Exec):
         return tuple(base)
 
     def _window_fn(self, ctx):
+        from spark_rapids_tpu import monitoring
         from spark_rapids_tpu.ops import kernel_cache as kc
         m = ctx.metrics_for(self)
         exprs = list(self.exprs)
@@ -551,6 +552,8 @@ class WindowExec(Exec):
                 "window", (fp, schema_fp, b.capacity),
                 lambda: jax.jit(
                     lambda bb: compute_window(bb, exprs)), m)
+            monitoring.count("windowBatches")
+            monitoring.count("windowRowsIn", b.capacity)
             return kc.call(entry, m, b)
         return fn
 
@@ -564,7 +567,8 @@ class WindowExec(Exec):
         yield from out_of_core_partition(
             ctx, ctx.metrics_for(self),
             self.children[0].execute_device(ctx, partition),
-            self.children[0].schema, orders, self._window_fn(ctx))
+            self.children[0].schema, orders, self._window_fn(ctx),
+            trace_cat="window")
 
     # -- host engine ---------------------------------------------------------
     def execute_host(self, ctx, partition):
