@@ -1,9 +1,9 @@
 """chip_probe.py — the small on-chip measurements the defaults and notes quote.
 
     python scripts/chip_probe.py [sync] [link] [upload] [prefix] [slots] [rowmove]
-                                 [shrinkrule]
+                                 [shrinkrule] [sortpath] [sortgrid] [--rows N,N,...]
 
-With no section named it runs all seven. One process, one chip, one JSON
+With no section named it runs all nine. One process, one chip, one JSON
 object per line, every reading on the host's clock around a
 ``block_until_ready`` (on an attached chip that waits for completion):
 
@@ -57,6 +57,35 @@ object per line, every reading on the host's clock around a
              either way; on q1's layout it is the aggregate's update, four
              groups (slots) and sorted. The table behind
              ``batch.PROBE_SHRINK_RATIO`` and the rule of ``shrink_all``.
+- ``sortpath`` what the sorted grouping path is made of (PR 34), first call
+             with compile and steady ms. (a) Primitives at 98,304 / 262,144
+             / 393,216 / 524,288 / 786,432 / 1,048,576 / 1,572,864 rows
+             (``--rows`` picks among them; three are powers of two, to see
+             whether those are slow by themselves): one 1-D ``take`` of a
+             uint32 and of a float64 column, a float64 stack of two, one
+             packed gather of 4 and of 16 words, an index scatter,
+             ``lax.sort`` of four operands keyed by one and, at 262,144
+             and 786,432 rows, a stable ``argsort``. (b) The operators on
+             q67's key layout (five string keys, three int32, one float64
+             sum, ~2 rows a group) at 4,096, 98,304 and 786,432 rows — a
+             q3 update, an Expand projection, q67's coalesced batch; the
+             merge's 1,572,864 is read from the cell's trace — as shipped
+             and as they were (``old_loop``: ``take``, ``argsort``,
+             ``take``, and the per-class ``take`` of ``_segment_sums``):
+             ``group_ids``, the sorted update, and (not at 98,304: two more
+             long compiles) the window's frame, rank by the float64
+             descending within the first key. At 4,096 and 786,432 rows
+             also the candidates that lost: ``group_ids`` over ONE sort of
+             three keys (``lax.sort(passes + [iota], num_keys=all)``), and
+             the sorted update with its group sums read at ``ends`` by
+             packed gathers (``_segment_sums`` with no word allowed to
+             ride: what it does for more than ``_SUMS_RIDE_WORDS``) in
+             place of the sort that brings q67's three to their slots. The forms' answers are
+             compared on the way.
+- ``sortgrid`` ``lax.sort`` at 786,432 rows with 2 / 4 / 8 operands and
+             1 / 3 / 8 keys, stable: what a rider costs and what a key
+             costs, to run and to COMPILE (a sort of eight keys compiles
+             for six minutes: 393.6 s for a described v5e in the sandbox).
 
 Like ``chip_smoke.py`` it refuses any backend but a TPU unless
 ``--cpu-rehearsal`` is given, which runs the control flow at a tiny size
@@ -75,8 +104,10 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 SECTIONS = ("sync", "link", "upload", "prefix", "slots", "rowmove",
-            "shrinkrule")
+            "shrinkrule", "sortpath", "sortgrid")
+SORTPATH_ROWS = (98304, 262144, 393216, 524288, 786432, 1048576, 1572864)
 LABEL = {}
+OPTIONS = {"rows": SORTPATH_ROWS}
 
 
 def emit(section: str, **facts) -> None:
@@ -622,9 +653,237 @@ def probe_shrinkrule(jax, small: bool) -> None:
              sorted_at_capacity_ms=steady(srt, b))
 
 
+def old_radix(passes, capacity, unstable_first=False):
+    """``kernels.radix_sort`` as it was until PR 34 (kept here alone, and
+    in ``tests/test_sort_core.py`` as the reference): two 1-D gathers a
+    pass, and one more for every pass the caller reads back."""
+    import jax.numpy as jnp
+    perm = jnp.arange(capacity, dtype=jnp.int32)
+    first = True
+    for words in reversed(passes):
+        keyed = jnp.take(words, perm, axis=0)
+        order = jnp.argsort(keyed, stable=not (unstable_first and first))
+        perm = jnp.take(perm, order, axis=0)
+        first = False
+    return perm, [jnp.take(p, perm, axis=0) for p in passes]
+
+
+def onesort_radix(passes, capacity, unstable_first=False):
+    """The form ``radix_sort`` did not take: ONE sort keyed by every
+    pass; stable, so ties keep the original order as the LSD passes do."""
+    import jax
+    import jax.numpy as jnp
+    out = jax.lax.sort(
+        list(passes) + [jnp.arange(capacity, dtype=jnp.int32)],
+        num_keys=len(passes), is_stable=not unstable_first)
+    return out[-1], list(out[:-1])
+
+
+def old_segment_sums(stacks, gid, slive, capacity):
+    """``HashAggregateExec._segment_sums`` as it was until PR 34: the
+    prefix sums of each dtype class read at the groups' ends by a
+    ``take`` of their own."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops import aggregate
+    idx = jnp.arange(capacity, dtype=jnp.int32)
+    nxt_gid = jnp.concatenate([gid[1:], gid[-1:]])
+    nxt_live = jnp.concatenate([slive[1:], jnp.zeros((1,), jnp.bool_)])
+    last = slive & ((idx == capacity - 1) | (nxt_gid != gid) | ~nxt_live)
+    ends = jnp.zeros((capacity,), jnp.int32).at[
+        jnp.where(last, gid, capacity)].set(idx, mode="drop")
+    out = {}
+    for cls, arrs in stacks.items():
+        se = jnp.take(aggregate._prefix_sums(jnp.stack(arrs, axis=1)),
+                      ends, axis=0)
+        d = jnp.concatenate([se[:1], se[1:] - se[:-1]], axis=0)
+        out[cls] = [d[:, j] for j in range(len(arrs))]
+    return out
+
+
+def q67_like(rows: int, seed: int = 0):
+    """q67's rollup aggregate (nine keys, five of them strings, one
+    float64 sum) and a batch of ``rows`` rows of it, ~2 rows a group,
+    NULLs where a rollup level and the data put them; ~98 % live under a
+    selection vector."""
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.columnar import dtypes as dt
+    from spark_rapids_tpu.columnar.batch import DeviceBatch, DeviceColumn
+    from spark_rapids_tpu.exprs.base import BoundReference as Ref
+    from spark_rapids_tpu.ops import (AggSpec, HashAggregateExec,
+                                      InMemorySourceExec, Sum)
+    rng = np.random.default_rng(seed)
+    widths = {"i_category": 16, "i_class": 16, "i_brand": 32,
+              "i_product_name": 32, "s_store_id": 16}
+    schema, cols = [], []
+    item = rng.integers(0, max(rows // 24, 4), rows)
+    for name in ("i_category", "i_class", "i_brand", "i_product_name",
+                 "d_year", "d_qoy", "d_moy", "s_store_id"):
+        valid = jnp.asarray(rng.random(rows) < 0.8)
+        if name in widths:
+            w = widths[name]
+            key = item if name.startswith("i_") else rng.integers(0, 12, rows)
+            text = np.zeros((rows, w), np.uint8)
+            digits = 8
+            for d in range(digits):
+                text[:, d] = 48 + (key // 10 ** (digits - 1 - d)) % 10
+            text[:, digits:w - 2] = 97 + len(name) % 26
+            schema.append((name, dt.STRING))
+            cols.append(DeviceColumn(dt.STRING, jnp.asarray(text), valid,
+                                     jnp.full((rows,), w - 2, jnp.int32)))
+        else:
+            schema.append((name, dt.INT32))
+            cols.append(DeviceColumn(
+                dt.INT32, jnp.asarray(rng.integers(1, 13, rows), jnp.int32),
+                valid))
+    schema.append(("sales", dt.FLOAT64))
+    cols.append(DeviceColumn(
+        dt.FLOAT64, jnp.asarray(rng.integers(0, 20000, rows).astype(float)),
+        jnp.asarray(rng.random(rows) < 0.96)))
+    agg = HashAggregateExec(
+        InMemorySourceExec(tuple(schema), [[]]),
+        [(n, Ref(i, t)) for i, (n, t) in enumerate(schema[:-1])],
+        [AggSpec("sumsales", Sum(Ref(len(schema) - 1, dt.FLOAT64)))],
+        mode="partial")
+    agg._has_nans = False
+    batch = DeviceBatch(tuple(cols), jnp.asarray(rows, jnp.int32),
+                        sel=jnp.asarray(rng.random(rows) < 0.98))
+    return agg, batch
+
+
+def probe_sortpath(jax, small: bool) -> None:
+    import contextlib
+    import dataclasses
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.exprs.base import BoundReference as Ref
+    from spark_rapids_tpu.columnar import dtypes as dt
+    from spark_rapids_tpu.ops import aggregate, kernels, window
+    from spark_rapids_tpu.ops.sort import SortOrder
+    n = 3 if small else 10
+    sizes = (256, 1024) if small else OPTIONS["rows"]
+    argsort_at = sizes if small else (262144, 786432)
+    operators_at = (256, 1024) if small else tuple(
+        r for r in (4096, 98304, 786432) if r == 4096 or r in sizes)
+    off = jnp.asarray(0, jnp.int64)
+
+    def read(what, fn, *args, **facts):
+        """First call (with compile) and steady ms of ``jit(fn)``."""
+        f = jax.jit(fn)
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(f(*args))
+        first = time.perf_counter() - t0
+        emit("sortpath", what=what, first_call_s=round(first, 3),
+             steady_ms=ms(timed(lambda: f(*args), n)), **facts)
+        return out
+
+    @contextlib.contextmanager
+    def sort_core(form):
+        """The operators traced with another sort core, another rule for
+        the group sums or, with the old loop, the old ``_segment_sums``:
+        all are read while tracing, so one jit a form."""
+        shipped = (kernels.radix_sort,
+                   aggregate.HashAggregateExec.__dict__["_segment_sums"],
+                   aggregate._SUMS_RIDE_WORDS)
+        if form == "one_sort":
+            kernels.radix_sort = onesort_radix
+        elif form == "sums_by_take":
+            aggregate._SUMS_RIDE_WORDS = 0
+        elif form == "old_loop":
+            kernels.radix_sort = old_radix
+            aggregate.HashAggregateExec._segment_sums = staticmethod(
+                old_segment_sums)
+        try:
+            yield
+        finally:
+            (kernels.radix_sort,
+             aggregate.HashAggregateExec._segment_sums,
+             aggregate._SUMS_RIDE_WORDS) = shipped
+
+    def with_core(form, fn):
+        def traced(*args):
+            with sort_core(form):
+                return fn(*args)
+        return traced
+
+    for rows in sizes:
+        rng = np.random.default_rng(rows)
+        idx = jnp.asarray(rng.permutation(rows), jnp.int32)
+        u32 = [jnp.asarray(rng.integers(0, 2 ** 32, rows, dtype=np.uint32))
+               for _ in range(8)]
+        f64 = jnp.asarray(rng.normal(0, 1, rows))
+        take = lambda x, i: jnp.take(x, i, axis=0, mode="clip")  # noqa: E731
+        read("take_1d_u32", take, u32[0], idx, rows=rows)
+        read("take_1d_f64", take, f64, idx, rows=rows)
+        read("take_f64_x2", take, jnp.stack([f64, f64 + 1], 1), idx,
+             rows=rows)
+        read("take_u32_x4", take, jnp.stack(u32[:4], 1), idx, rows=rows)
+        read("take_u32_x16", take, jnp.stack(u32 + u32, 1), idx, rows=rows)
+        read("scatter_index", lambda i: jnp.zeros((rows,), jnp.int32).at[
+            i].set(jnp.arange(rows, dtype=jnp.int32), mode="drop"), idx,
+            rows=rows)
+        read("lax_sort", lambda *xs: jax.lax.sort(
+            list(xs), num_keys=1, is_stable=True), *u32[:3], idx, rows=rows,
+            operands=4, num_keys=1)
+        if rows in argsort_at:
+            read("argsort_stable", lambda x: jnp.argsort(x, stable=True),
+                 u32[0], rows=rows)
+    for rows in operators_at:
+        agg, batch = q67_like(rows)
+        nkeys = len(agg.group_exprs)
+        fp = jax.jit(lambda b: kernels.key_fingerprint(
+            b.columns[:nkeys], rows))(batch)          # noqa: B023
+        spec = window.WindowSpec(
+            [Ref(0, dt.STRING)],
+            [SortOrder(Ref(nkeys, dt.FLOAT64), ascending=False)])
+        # The candidates that lost and the frame (two long compiles more)
+        # are not read at 98,304 rows.
+        full = rows != 98304
+        want = {}
+        for what, fn, args, forms in (
+                ("group_ids", lambda b, a, c: dataclasses.astuple(
+                    kernels.group_ids(b, (), (a, c))), (batch, *fp),
+                 ("shipped", "one_sort", "old_loop") if full
+                 else ("shipped", "old_loop")),
+                ("sorted_update", lambda b: agg._sorted_update(  # noqa: B023
+                    *agg._project_inputs(b), off), (batch,),     # noqa: B023
+                 ("shipped", "sums_by_take", "old_loop") if full
+                 else ("shipped", "old_loop")),
+                ("window_frame", lambda b: window._sorted_frame(
+                    b, spec), (batch,),                          # noqa: B023
+                 ("shipped", "old_loop") if full else ())):
+            for form in forms:
+                got = jax.tree.leaves(read(what, with_core(form, fn), *args,
+                                           rows=rows, sort_core=form))
+                for x, y in zip(want.setdefault(what, got), got):
+                    assert (np.asarray(x) == np.asarray(y)).all(), (
+                        what, rows, form)
+        del batch, want, got
+
+
+def probe_sortgrid(jax, small: bool) -> None:
+    import jax.numpy as jnp
+    import numpy as np
+    rows = 1024 if small else 786432
+    n = 3 if small else 10
+    rng = np.random.default_rng(rows)
+    xs = [jnp.asarray(rng.integers(0, 2 ** 32, rows, dtype=np.uint32))
+          for _ in range(7)] + [jnp.arange(rows, dtype=jnp.int32)]
+    for operands, keys in ((2, 1), (4, 1), (8, 1), (4, 3), (8, 3), (8, 8)):
+        f = jax.jit(lambda *ops: jax.lax.sort(
+            list(ops), num_keys=keys, is_stable=True))          # noqa: B023
+        args = xs[:operands - 1] + xs[-1:]
+        t0 = time.perf_counter()
+        jax.block_until_ready(f(*args))
+        first = time.perf_counter() - t0
+        emit("sortgrid", rows=rows, operands=operands, num_keys=keys,
+             first_call_s=round(first, 3),
+             steady_ms=ms(timed(lambda: f(*args), n)))            # noqa: B023
+
 PROBES = {"sync": probe_sync, "link": probe_link, "upload": probe_upload,
           "prefix": probe_prefix, "slots": probe_slots,
-          "rowmove": probe_rowmove, "shrinkrule": probe_shrinkrule}
+          "rowmove": probe_rowmove, "shrinkrule": probe_shrinkrule,
+          "sortpath": probe_sortpath, "sortgrid": probe_sortgrid}
 
 
 def main(argv=None) -> int:
@@ -632,12 +891,17 @@ def main(argv=None) -> int:
     ap.add_argument("sections", nargs="*", metavar="section",
                     help=f"which of {', '.join(SECTIONS)} to run "
                          f"(default: all)")
+    ap.add_argument("--rows", type=lambda v: tuple(map(int, v.split(","))),
+                    default=SORTPATH_ROWS,
+                    help="sortpath: the sizes to read, of "
+                         f"{','.join(map(str, SORTPATH_ROWS))}")
     ap.add_argument("--cpu-rehearsal", action="store_true",
                     help="control flow at a tiny size on the CPU backend")
     args = ap.parse_args(argv)
     unknown = set(args.sections) - set(SECTIONS)
     if unknown:
         ap.error(f"unknown section(s) {sorted(unknown)}")
+    OPTIONS["rows"] = args.rows
     if args.cpu_rehearsal:
         os.environ["JAX_PLATFORMS"] = "cpu"
         LABEL["cpu_rehearsal"] = True

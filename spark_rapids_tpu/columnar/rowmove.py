@@ -209,6 +209,38 @@ def gather_rows(batch: DeviceBatch, indices: jax.Array,
     return unpack_batch(out, batch, new_num_rows)
 
 
+def take_columns(columns: Sequence[jax.Array],
+                 indices: jax.Array) -> List[jax.Array]:
+    """``[c[indices] for c in columns]`` for (N,) integer and float64
+    columns, in ONE gather per slab: every integer column as uint32 words
+    side by side (``WORDS_PER_SLAB`` a slab), the float64 columns stacked
+    (N, k). Taken one by one, each column is a 1-D gather that costs the
+    chip as much as a whole slab, and a 64-bit one is two (fact 1 above;
+    PERF.md, PR 34). ``indices`` must be in range."""
+    parts = [None if c.dtype == jnp.float64 else _to_words(c)
+             for c in columns]
+    words = [w for p in parts if p is not None for w in p]
+    f64 = [c for c, p in zip(columns, parts) if p is None]
+    if words:
+        row = jnp.stack(words, axis=1)
+        row = jnp.concatenate(
+            [jnp.take(row[:, lo:lo + WORDS_PER_SLAB], indices, axis=0,
+                      mode="clip")
+             for lo in range(0, row.shape[1], WORDS_PER_SLAB)], axis=1)
+    if f64:
+        taken = jnp.take(jnp.stack(f64, axis=1), indices, axis=0,
+                         mode="clip")
+    out, off, f64_i = [], 0, 0
+    for c, p in zip(columns, parts):
+        if p is None:
+            out.append(taken[:, f64_i])
+            f64_i += 1
+        else:
+            out.append(_from_words(row[:, off:off + len(p)], c.dtype))
+            off += len(p)
+    return out
+
+
 def _live_sources(live: jax.Array, capacity: int) -> jax.Array:
     """``(capacity,)`` int32: slot r holds the row id of the r-th live row
     (stable order); slots past the live count hold 0 and are masked by the

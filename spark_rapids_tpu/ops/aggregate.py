@@ -740,6 +740,11 @@ _SLOT_MAX_GROUPS = 256
 _SLOT_ROWS_PER_GROUP = 4096
 
 
+# The most 32-bit words of prefix sums that ride ONE sort to their groups'
+# slots (``HashAggregateExec._segment_sums``); more are gathered in slabs.
+_SUMS_RIDE_WORDS = 4
+
+
 def _slot_limit(capacity: int) -> int:
     """The most groups a batch of ``capacity`` rows may show and be
     grouped without a sort; 0: the batch is too small to ask."""
@@ -869,19 +874,47 @@ class HashAggregateExec(Exec):
         """ALL group sums with one cumsum + boundary shift-diff per dtype
         class. Values arrive pre-masked (dead/null rows contribute 0).
         Groups are contiguous ascending runs of ``gid`` in sorted order, so
-        group g's sum = prefix(end_g) - prefix(end_{g-1})."""
+        group g's sum = prefix(end_g) - prefix(end_{g-1}).
+
+        How the prefix sums at the groups' last rows reach the groups'
+        slots follows from how many 32-bit words they are (static):
+
+        - up to ``_SUMS_RIDE_WORDS``: the groups' last rows, in order, are
+          the groups in order, so ONE stable sort keyed by "not a group's
+          last row" with the prefix sums riding leaves group g's at slot
+          g. No scatter of the ends, no gather: a sum and its count (q67)
+          are three words and three 1-D gathers, a float64 having no words
+          to share a slab with — 48 ms of a 786,432-row update's 128 on
+          one v5e (PERF.md, PR 34). Slots past the last group hold other
+          rows' prefix sums, masked by ``_buf_column`` as every slot past
+          ``num_groups`` is;
+        - more: the ends' indices by one scatter and the sums by packed
+          gathers (``rowmove.take_columns``): every operand of a sort
+          costs the chip's compiler ~12 s, a slab gather's column nothing.
+
+        Returns class -> its sums, one (N,) array a stream, by group."""
         idx = jnp.arange(capacity, dtype=jnp.int32)
         nxt_gid = jnp.concatenate([gid[1:], gid[-1:]])
         nxt_live = jnp.concatenate([slive[1:], jnp.zeros((1,), jnp.bool_)])
         last = slive & ((idx == capacity - 1) | (nxt_gid != gid)
                         | ~nxt_live)
-        ends = jnp.zeros((capacity,), jnp.int32).at[
-            jnp.where(last, gid, capacity)].set(idx, mode="drop")
-        out = {}
-        for cls, arrs in stacks.items():
+        prefix = []
+        for arrs in stacks.values():
             S = _prefix_sums(jnp.stack(arrs, axis=1))
-            Se = jnp.take(S, ends, axis=0)
-            out[cls] = jnp.concatenate([Se[:1], Se[1:] - Se[:-1]], axis=0)
+            prefix.extend(S[:, j] for j in range(len(arrs)))
+        if sum(p.dtype.itemsize // 4 for p in prefix) <= _SUMS_RIDE_WORDS:
+            at_ends = jax.lax.sort([(~last).astype(jnp.uint32)] + prefix,
+                                   num_keys=1, is_stable=True)[1:]
+        else:
+            from spark_rapids_tpu.columnar.rowmove import take_columns
+            ends = jnp.zeros((capacity,), jnp.int32).at[
+                jnp.where(last, gid, capacity)].set(idx, mode="drop")
+            at_ends = take_columns(prefix, ends)
+        out, off = {}, 0
+        for cls, arrs in stacks.items():
+            out[cls] = [jnp.concatenate([e[:1], e[1:] - e[:-1]])
+                        for e in at_ends[off:off + len(arrs)]]
+            off += len(arrs)
         return out
 
     def _run_specs(self, spec_inputs, gid, slive, capacity, row_index,
@@ -912,7 +945,7 @@ class HashAggregateExec(Exec):
         out = []
         for spec, plan in zip(self.aggs, plans):
             if plan[0] == "sum":
-                vals = [sums[cls][:, pos] for cls, pos in plan[1]]
+                vals = [sums[cls][pos] for cls, pos in plan[1]]
                 out.append(spec.fn.bufs_from_sums(vals, capacity,
                                                   has_nans))
             else:

@@ -2,15 +2,15 @@
 
 These are the TPU-first replacements for the cuDF kernels the reference
 reaches through JNI (Table.orderBy, Table.groupBy, hash partition): everything
-is expressed as stable argsorts, segmented reductions and scatters over
+is expressed as stable sorts, segmented reductions and scatters over
 fixed-capacity arrays, so XLA can fuse and tile them (no dynamic allocations,
 no data-dependent shapes — SURVEY.md §7 "hard parts" #1/#3).
 
 Key ideas:
 - ``sort_key_passes`` turns any key column into a list of uint32 radix words,
   most-significant first, already adjusted for asc/desc and null ordering.
-  A multi-column sort is then a sequence of stable argsorts over the reversed
-  pass list (LSD radix over words).
+  A multi-column sort is then a sequence of stable sorts over the reversed
+  pass list (LSD radix over words, ``radix_sort``).
 - ``group_ids`` gives each live row a dense group index by sorting rows by a
   128-bit key fingerprint (two independent murmur3 streams + null pattern);
   equal keys become adjacent, segment boundaries fall where the fingerprint
@@ -31,6 +31,7 @@ import numpy as np
 
 from spark_rapids_tpu.columnar import dtypes as dt
 from spark_rapids_tpu.columnar.batch import DeviceBatch, DeviceColumn
+from spark_rapids_tpu.columnar.rowmove import take_columns
 from spark_rapids_tpu.exprs import hash as mh
 
 
@@ -113,25 +114,87 @@ def sort_key_passes(col: DeviceColumn, ascending: bool,
     return [null_word] + words
 
 
-def _radix_perm(passes: List[jnp.ndarray], capacity: int,
-                unstable_first: bool = False) -> jnp.ndarray:
-    """Stable LSD radix argsort: the ONE traced implementation every
-    multi-pass sort in this engine shares (full sorts, grouping, per-group
-    string min/max — and through the kernel cache, the fused paths).
+# The most passes whose words ride one another's sorts (``radix_sort``): a
+# longer sort goes in chunks. A sort's compile time is its operands — ~12 s
+# each over every distinct sort from 32,768 rows up, whatever the size, for
+# a described v5e in the sandbox — and its run time hardly: 2.4 / 3.2 / 5.0
+# ms with 2 / 4 / 8 operands at 786,432 rows against 5.5 for one packed
+# gather (my chip run, PR 34). With all seven passes of q67's window frame
+# riding one another the frame ran in 25.0 ms and compiled in 253 s, where
+# the loop before it took 169.9 ms and 112.5 s; chunks of four keep
+# every sort at seven operands.
+_RIDE_PASSES = 4
 
-    ``passes`` are per-row word arrays, most significant first; the
-    returned permutation orders rows by the lexicographic pass tuple.
+
+def radix_sort(passes: Sequence[jnp.ndarray], capacity: int,
+               unstable_first: bool = False
+               ) -> Tuple[jnp.ndarray, List[jnp.ndarray]]:
+    """Stable LSD radix sort: the ONE traced implementation every
+    multi-pass sort in this engine shares (full sorts, grouping, the
+    window's frame, per-group string min/max — and through the kernel
+    cache, the fused paths).
+
+    ``passes`` are per-row word arrays, most significant first. Returns
+    ``(perm, sorted_passes)``: the permutation that orders rows by the
+    lexicographic pass tuple, ties in original row order, and every pass
+    in that order (``sorted_passes[i] == passes[i][perm]``).
     ``unstable_first`` relaxes tie order on the least-significant pass
     only (spark.rapids.sql.stableSort.enabled off) — every later pass
-    must stay stable for multi-key correctness."""
+    must stay stable for multi-key correctness.
+
+    No column is gathered by itself. Each pass is one ``lax.sort`` keyed
+    by its word in the order the passes so far left, with the other words
+    of its chunk and the permutation riding as operands: on the chip a
+    1-D gather of a batch's length costs 5.6-7.6 ms at 786,432 rows, as
+    much as a packed 16-word row gather and twice a four-operand sort
+    (PERF.md, PR 34), and the loop this replaces paid two a pass and one
+    more a word its caller read back. More than ``_RIDE_PASSES`` passes
+    go in chunks, least significant first: a chunk's words enter it
+    through ONE packed gather by the permutation so far
+    (``rowmove.take_columns``) and ride only their own chunk's sorts — but
+    a float64 word, which has no slab to share, rides on through the
+    later chunks'. The sorted words of the last chunk and the float64
+    ones are the sorts' outputs, the others' one more packed gather; what
+    the caller does not read XLA drops, with the operand that carried it.
+
+    The riders keep one operand order whatever the key, so the passes
+    of a chunk that share a key dtype are ONE sort to the compiler."""
+    perm, words = _radix_chunks(passes, capacity, unstable_first)
+    stale = [i for i in range(len(passes)) if i not in words]
+    if stale:
+        words.update(zip(stale, take_columns([passes[i] for i in stale],
+                                             perm)))
+    return perm, [words[i] for i in range(len(passes))]
+
+
+def _radix_chunks(passes: Sequence[jnp.ndarray], capacity: int,
+                  unstable_first: bool) -> Tuple[jnp.ndarray, dict]:
+    """``radix_sort``'s passes: the permutation, and by their index in
+    ``passes`` the words that left the last sort in sorted order."""
+    k = len(passes)
+    n_chunks = max(-(-k // _RIDE_PASSES), 1)
+    # as few chunks as ``_RIDE_PASSES`` allows, their sizes one apart at most
+    bounds = [(c * k) // n_chunks for c in range(n_chunks + 1)]
     perm = jnp.arange(capacity, dtype=jnp.int32)
-    first = True
-    for words in reversed(passes):
-        keyed = jnp.take(words, perm, axis=0)
-        order = jnp.argsort(keyed, stable=not (unstable_first and first))
-        perm = jnp.take(perm, order, axis=0)
-        first = False
-    return perm
+    words: dict = {}
+    for lo, hi in reversed(list(zip(bounds, bounds[1:]))):  # LSD first
+        chunk = list(range(lo, hi))
+        # Float64 words of the chunks before ride on: uint32 riders first,
+        # then float64 ones, one order for every key.
+        words = {j: w for j, w in words.items() if w.dtype == jnp.float64}
+        riders = sorted(chunk + list(words),
+                        key=lambda j: (passes[j].dtype == jnp.float64, j))
+        words.update(zip(chunk, [passes[i] for i in chunk] if hi == k
+                         else take_columns([passes[i] for i in chunk],
+                                           perm)))
+        for i in reversed(chunk):
+            rest = [j for j in riders if j != i]
+            out = jax.lax.sort(
+                [words[i]] + [words[j] for j in rest] + [perm], num_keys=1,
+                is_stable=not (unstable_first and i == k - 1))
+            words.update(zip([i] + rest, out[:-1]))
+            perm = out[-1]
+    return perm, words
 
 
 def lex_sort_perm(passes: List[jnp.ndarray], live: jnp.ndarray,
@@ -143,8 +206,8 @@ def lex_sort_perm(passes: List[jnp.ndarray], live: jnp.ndarray,
         live = jnp.arange(capacity, dtype=jnp.int32) < live
     # Padding pass first (most significant of all): dead rows sort last.
     pad_last = jnp.where(live, jnp.uint32(0), jnp.uint32(0xFFFFFFFF))
-    return _radix_perm([pad_last] + list(passes), capacity,
-                       unstable_first=not stable)
+    return _radix_chunks([pad_last] + list(passes), capacity,
+                         unstable_first=not stable)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -251,11 +314,9 @@ def group_ids(batch: DeviceBatch, key_ordinals: Sequence[int],
     ha, hb = fingerprints
     live = batch.row_mask()
     # Sort rows by (live desc, ha, hb): padding last.
-    passes = [jnp.where(live, jnp.uint32(0), jnp.uint32(0xFFFFFFFF)), ha, hb]
-    perm = _radix_perm(passes, cap)
-    sa = jnp.take(ha, perm, axis=0)
-    sb = jnp.take(hb, perm, axis=0)
-    slive = jnp.take(live, perm, axis=0)
+    dead = jnp.where(live, jnp.uint32(0), jnp.uint32(0xFFFFFFFF))
+    perm, (sdead, sa, sb) = radix_sort([dead, ha, hb], cap)
+    slive = sdead == 0
     prev_a = jnp.concatenate([sa[:1] ^ jnp.uint32(1), sa[:-1]])
     prev_b = jnp.concatenate([sb[:1], sb[:-1]])
     new_seg = ((sa != prev_a) | (sb != prev_b)) & slive
@@ -347,8 +408,8 @@ def segment_minmax_string(data: jnp.ndarray, lengths: jnp.ndarray,
     words = [jnp.where(validity, w, jnp.uint32(0)) for w in words]
     lenword = jnp.where(validity, lenword, jnp.uint32(0))
     passes = [gid.astype(jnp.uint32), loser] + words + [lenword]
-    perm = _radix_perm(passes, capacity)
-    sorted_gid = jnp.take(gid, perm, axis=0)
+    perm, words = _radix_chunks(passes, capacity, unstable_first=False)
+    sorted_gid = words[0].astype(gid.dtype)
     prev = jnp.concatenate([sorted_gid[:1] ^ 1, sorted_gid[:-1]])
     new_seg = sorted_gid != prev
     new_seg = new_seg | (jnp.arange(capacity) == 0)

@@ -2,8 +2,9 @@
 
 Full sort requires the whole partition as one batch (same RequireSingleBatch
 restriction the reference has in v0.3); the device kernel is an LSD radix of
-stable argsorts over orderable uint32 words (ops/kernels.py), which XLA
-lowers to fused bitonic sorts — the TPU replacement for cuDF Table.orderBy.
+stable sorts over orderable uint32 words, each carrying the words of the
+passes to come (ops/kernels.py ``radix_sort``) — the TPU replacement for
+cuDF Table.orderBy.
 """
 
 from __future__ import annotations

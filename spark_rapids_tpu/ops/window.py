@@ -143,42 +143,31 @@ def _sorted_frame(batch: DeviceBatch, spec: WindowSpec):
     """Sort rows into (partition, order) frame; return sort context."""
     cap = batch.capacity
     live = batch.row_mask()
+    passes = [jnp.where(live, jnp.uint32(0), jnp.uint32(0xFFFFFFFF))]
     pcols = [as_device_column(e.eval(batch), batch)
              for e in spec.partition_by]
-    ha, hb = kernels.key_fingerprint(pcols, cap) if pcols else (
-        jnp.zeros((cap,), jnp.uint32), jnp.zeros((cap,), jnp.uint32))
-    passes = [jnp.where(live, jnp.uint32(0), jnp.uint32(0xFFFFFFFF)),
-              ha, hb]
-    order_word_counts = []
+    if pcols:
+        passes.extend(kernels.key_fingerprint(pcols, cap))
+    order_from = len(passes)
     for o in spec.order_by:
         col = as_device_column(o.child.eval(batch), batch)
-        words = kernels.sort_key_passes(col, o.ascending, o.nulls_first)
-        order_word_counts.append(len(words))
-        passes.extend(words)
-    perm = jnp.arange(cap, dtype=jnp.int32)
-    for words in reversed(passes):
-        keyed = jnp.take(words, perm, axis=0)
-        order = jnp.argsort(keyed, stable=True)
-        perm = jnp.take(perm, order, axis=0)
-    s_live = jnp.take(live, perm, axis=0)
-    s_ha = jnp.take(ha, perm, axis=0)
-    s_hb = jnp.take(hb, perm, axis=0)
+        passes.extend(kernels.sort_key_passes(col, o.ascending,
+                                              o.nulls_first))
+    perm, sorted_passes = kernels.radix_sort(passes, cap)
+    s_live = sorted_passes[0] == 0
+
+    def changes(sw):
+        return sw != jnp.concatenate([sw[:1], sw[:-1]])
+
     # Partition boundary at sorted position i (first row of a partition).
-    prev_a = jnp.concatenate([s_ha[:1] ^ jnp.uint32(1), s_ha[:-1]])
-    prev_b = jnp.concatenate([s_hb[:1], s_hb[:-1]])
-    new_part = ((s_ha != prev_a) | (s_hb != prev_b) |
-                (jnp.arange(cap) == 0)) & s_live
+    new_part = jnp.arange(cap) == 0
+    for sw in sorted_passes[1:order_from]:
+        new_part = new_part | changes(sw)
+    new_part = new_part & s_live
     # Peer boundary: partition boundary OR any order key differs.
     new_peer = new_part
-    if spec.order_by:
-        off = 3
-        for o, nw in zip(spec.order_by, order_word_counts):
-            for wi in range(nw):
-                w = passes[off + wi]
-                sw = jnp.take(w, perm, axis=0)
-                pw = jnp.concatenate([sw[:1], sw[:-1]])
-                new_peer = new_peer | ((sw != pw) & s_live)
-            off += nw
+    for sw in sorted_passes[order_from:]:
+        new_peer = new_peer | (changes(sw) & s_live)
     return perm, s_live, new_part, new_peer
 
 

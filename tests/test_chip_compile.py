@@ -151,15 +151,20 @@ def test_grouped_aggregate_update(sel, one_chip):
 
 
 def test_radix_permutation(one_chip):
-    """The LSD radix argsort every sort and grouping shares
-    (ops/kernels.py _radix_perm), one key word: two stable u32 argsort
-    passes. One capacity only — each pass takes the chip's compiler
-    ~17 s at 2^20 rows (PR 21), which is ROADMAP A1's business."""
+    """The LSD radix sort every sort and grouping shares (ops/kernels.py
+    radix_sort) as ``group_ids`` runs it: three word passes, each ONE
+    stable sort with the other words and the permutation riding, and no
+    gather in the compiled program. One capacity only — the chip's
+    compiler takes ~12 s an operand over each distinct sort at any size
+    from 32,768 rows up (PR 34; the three passes here are one to it),
+    which is ROADMAP A5's business."""
     from spark_rapids_tpu.ops import kernels
-    cap = CAPS[0]
-    _compile(lambda a, n: kernels.lex_sort_perm([a], n, cap),
-             one_chip, np.zeros((cap,), np.uint32),
-             np.asarray(cap, np.int32))
+    cap = CAPS[1]
+    word = np.zeros((cap,), np.uint32)
+    out = _compile(lambda *p: kernels.radix_sort(p, cap), one_chip,
+                   word, word, word)
+    text = out.as_text()
+    assert text.count(" sort(") == 3 and " gather(" not in text
 
 
 @pytest.mark.parametrize("cap", CAPS)

@@ -1,6 +1,6 @@
 """The four hot kernels against numpy.
 
-The stable radix permutation (ops/kernels.py ``_radix_perm``), the join
+The stable radix permutation (ops/kernels.py ``radix_sort``), the join
 probe's double search (ops/join.py ``probe_ranges``), the wire's RLE
 decode (columnar/wire.py, through the real decode program) and the
 sorted-segment reduce (ops/kernels.py ``segment_reduce``) each have one
@@ -43,7 +43,7 @@ def assert_bit_equal(want, got, msg=None):
 
 
 # ---------------------------------------------------------------------------
-# _radix_perm: a stable sort's permutation is unique, so numpy's is THE answer
+# radix_sort: a stable sort's permutation is unique, so numpy's is THE answer
 # ---------------------------------------------------------------------------
 
 def _lexsort(passes) -> np.ndarray:
@@ -57,7 +57,7 @@ def _lexsort(passes) -> np.ndarray:
 def test_radix_perm_one_pass(cap, hi):
     rng = np.random.default_rng(cap)
     keys = rng.integers(0, hi, cap, dtype=np.uint32)
-    got = kernels._radix_perm([jnp.asarray(keys)], cap)
+    got = kernels.radix_sort([jnp.asarray(keys)], cap)[0]
     assert np.array_equal(np.argsort(keys, kind="stable"), np.asarray(got))
 
 
@@ -65,7 +65,7 @@ def test_radix_perm_three_word_passes():
     rng = np.random.default_rng(3)
     cap = 384
     passes = [rng.integers(0, 9, cap, dtype=np.uint32) for _ in range(3)]
-    got = kernels._radix_perm([jnp.asarray(p) for p in passes], cap)
+    got = kernels.radix_sort([jnp.asarray(p) for p in passes], cap)[0]
     assert np.array_equal(_lexsort(passes), np.asarray(got))
 
 
@@ -77,7 +77,7 @@ def test_radix_perm_float64_pass_between_word_passes():
     passes = [rng.integers(0, 3, cap, dtype=np.uint32),
               rng.choice(np.asarray([-1.5, -0.25, 0.5, 2.0, np.inf]), cap),
               rng.integers(0, 3, cap, dtype=np.uint32)]
-    got = kernels._radix_perm([jnp.asarray(p) for p in passes], cap)
+    got = kernels.radix_sort([jnp.asarray(p) for p in passes], cap)[0]
     assert np.array_equal(_lexsort(passes), np.asarray(got))
 
 
@@ -89,8 +89,8 @@ def test_radix_perm_unstable_first_gives_a_valid_order():
     cap = 96
     passes = [rng.integers(0, 4, cap, dtype=np.uint32),
               rng.integers(0, 5, cap, dtype=np.uint32)]
-    got = np.asarray(kernels._radix_perm(
-        [jnp.asarray(p) for p in passes], cap, unstable_first=True))
+    got = np.asarray(kernels.radix_sort(
+        [jnp.asarray(p) for p in passes], cap, unstable_first=True)[0])
     assert np.array_equal(np.sort(got), np.arange(cap))
     ordered = list(zip(passes[0][got].tolist(), passes[1][got].tolist()))
     assert ordered == sorted(ordered)
