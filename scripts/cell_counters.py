@@ -13,7 +13,15 @@ and, in a traced run, a line with the flight recorder's ``counters()``
 (PR 33: ``collects``, ``expandRowsIn`` / ``expandRowsOut`` /
 ``expandProjections``, ``aggUpdateRows``, ``aggConsolidateLevels``,
 ``windowRowsIn`` / ``windowBatches`` / ``windowOutOfCoreSplits``; counted
-only while the recorder is on, so empty at ``--trace 0``).
+only while the recorder is on, so empty at ``--trace 0``; PR 35:
+``joinBuildRows``, ``exchangeRows``, ``coalesceAloneRows``), and two lines that are there at
+``--trace 0`` too (PR 35): the device scan cache's ``io/scan.py
+counters()`` (units and bytes hit, missed, refilled, evicted, rejected,
+resident) and ``plan/cost.py counters()`` (``replanChecks``,
+``joinDemotions``: which plan the joins ran; ``replanObservedBytes``,
+``replanFootprintBytes``, ``replanUncountedShards``: what the rule read,
+what it would have read of padded shards, and shards without a row
+count, each summed over the checks).
 The counts follow from shapes and live counts alone, so a CPU rehearsal at
 the cell's scale gives the chip's counts; its times are no device numbers.
 """
@@ -34,6 +42,12 @@ def main() -> int:
     print(json.dumps({"phase": "shrink_counters", **batch.counters()}),
           flush=True)
     print(json.dumps({"phase": "recorder_counters", **recorder.counters()}),
+          flush=True)
+    from spark_rapids_tpu.io import scan
+    from spark_rapids_tpu.plan import cost
+    print(json.dumps({"phase": "scan_cache_counters", **scan.counters()}),
+          flush=True)
+    print(json.dumps({"phase": "cost_counters", **cost.counters()}),
           flush=True)
     return rc
 

@@ -1,9 +1,10 @@
 """chip_probe.py — the small on-chip measurements the defaults and notes quote.
 
     python scripts/chip_probe.py [sync] [link] [upload] [prefix] [slots] [rowmove]
-                                 [shrinkrule] [sortpath] [sortgrid] [--rows N,N,...]
+                                 [shrinkrule] [sortpath] [sortgrid] [coalescealone]
+                                 [--rows N,N,...]
 
-With no section named it runs all nine. One process, one chip, one JSON
+With no section named it runs all ten. One process, one chip, one JSON
 object per line, every reading on the host's clock around a
 ``block_until_ready`` (on an attached chip that waits for completion):
 
@@ -87,6 +88,25 @@ object per line, every reading on the host's clock around a
              costs, to run and to COMPILE (a sort of eight keys compiles
              for six minutes: 393.6 s for a described v5e in the sandbox).
 
+- ``coalescealone`` what ``coalesce_iter`` buys its consumers by moving
+             a member into a concatenated batch (PR 35): members of
+             262,144 / 524,288 / 786,432 / 1,048,576 rows of capacity
+             (``--rows`` picks others), in a group of two and in the
+             group that ``batchSizeRows`` = 4 Mi makes of them (16, 8, 5,
+             4). (a) TOGETHER: ``jit_concat_batches`` into one batch of
+             the group's capacity, the consumer once; (b) ALONE: the
+             consumer once a member, and the same with a blocking read
+             of each output's row count behind it (the sizes pull a round
+             costs further on, at its dearest: one a member). Each form
+             is timed whole, dispatch and device, to its last output
+             ready. On q1's layout (two string keys, four float64, ~98 %
+             live under a selection vector) the consumer is the
+             aggregate's slot update of four groups; on q3's lineitem
+             layout (~54 % live) the dense join probe over SF10's build
+             side (1.46 M unique keys in 15 M). Also the concat and one
+             consumer call by themselves. The table behind
+             ``batch.COALESCE_ALONE_ROWS``.
+
 Like ``chip_smoke.py`` it refuses any backend but a TPU unless
 ``--cpu-rehearsal`` is given, which runs the control flow at a tiny size
 on the CPU and says so on every line: a CPU reading is not a device number.
@@ -104,10 +124,11 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 SECTIONS = ("sync", "link", "upload", "prefix", "slots", "rowmove",
-            "shrinkrule", "sortpath", "sortgrid")
+            "shrinkrule", "sortpath", "sortgrid", "coalescealone")
 SORTPATH_ROWS = (98304, 262144, 393216, 524288, 786432, 1048576, 1572864)
+COALESCE_ROWS = (262144, 524288, 786432, 1048576)
 LABEL = {}
-OPTIONS = {"rows": SORTPATH_ROWS}
+OPTIONS = {"rows": None}
 
 
 def emit(section: str, **facts) -> None:
@@ -761,7 +782,7 @@ def probe_sortpath(jax, small: bool) -> None:
     from spark_rapids_tpu.ops import aggregate, kernels, window
     from spark_rapids_tpu.ops.sort import SortOrder
     n = 3 if small else 10
-    sizes = (256, 1024) if small else OPTIONS["rows"]
+    sizes = (256, 1024) if small else OPTIONS["rows"] or SORTPATH_ROWS
     argsort_at = sizes if small else (262144, 786432)
     operators_at = (256, 1024) if small else tuple(
         r for r in (4096, 98304, 786432) if r == 4096 or r in sizes)
@@ -880,10 +901,69 @@ def probe_sortgrid(jax, small: bool) -> None:
              first_call_s=round(first, 3),
              steady_ms=ms(timed(lambda: f(*args), n)))            # noqa: B023
 
+def probe_coalescealone(jax, small: bool) -> None:
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.columnar.batch import (bucket_capacity,
+                                                 jit_concat_batches)
+    goal = 1 << (12 if small else 22)           # batchSizeRows
+    sizes = tuple(r >> 10 for r in COALESCE_ROWS) if small \
+        else OPTIONS["rows"] or COALESCE_ROWS
+    n = 3 if small else 10
+    off = jnp.asarray(0, jnp.int64)
+    agg, make = q1_like_aggregate()
+    slot = jax.jit(lambda b: agg._update_batch(b, off))
+
+    def whole(fn):
+        """Median ms of ``fn`` to its last output ready, after a first
+        call that compiles."""
+        jax.block_until_ready(fn())
+        return ms(timed(fn, n))["median"]
+
+    def selected(b, pct):
+        keep = np.random.default_rng(pct).random(b.capacity) < pct / 100
+        return b.with_sel(jnp.asarray(keep))
+
+    def pulled(consumer, members):
+        outs = []
+        for m in members:
+            outs.append(consumer(m))
+            jax.device_get(outs[-1].num_rows)
+        return outs
+
+    for rows in sizes:
+        lineitem, dense = q3_like_probe(
+            rows, *((96, 4096) if small else (1_460_000, 15_000_000)))
+        layouts = (("q1_lineitem", "slot_update", slot,
+                    selected(make(rows, 4), 98)),
+                   ("q3_lineitem", "dense_probe", dense,
+                    selected(lineitem, 54)))
+        for layout, name, consumer, member in layouts:
+            for k in sorted({2, max(goal // rows, 2)}):
+                members = [member] * k
+                cap = bucket_capacity(k * rows)
+                concat = lambda: jit_concat_batches(members, cap)  # noqa: E731
+                big = concat()
+                emit("coalescealone", layout=layout, consumer=name,
+                     rows=rows, members=k, out_capacity=cap,
+                     concat_ms=whole(concat),
+                     consumer_at_member_ms=whole(lambda: consumer(member)),
+                     consumer_at_group_ms=whole(lambda: consumer(big)),
+                     together_ms=whole(lambda: consumer(concat())),
+                     together_pulled_ms=whole(
+                         lambda: pulled(consumer, [concat()])),
+                     alone_ms=whole(
+                         lambda: [consumer(m) for m in members]),
+                     alone_pulled_ms=whole(
+                         lambda: pulled(consumer, members)))
+                del big
+
+
 PROBES = {"sync": probe_sync, "link": probe_link, "upload": probe_upload,
           "prefix": probe_prefix, "slots": probe_slots,
           "rowmove": probe_rowmove, "shrinkrule": probe_shrinkrule,
-          "sortpath": probe_sortpath, "sortgrid": probe_sortgrid}
+          "sortpath": probe_sortpath, "sortgrid": probe_sortgrid,
+          "coalescealone": probe_coalescealone}
 
 
 def main(argv=None) -> int:
@@ -892,9 +972,11 @@ def main(argv=None) -> int:
                     help=f"which of {', '.join(SECTIONS)} to run "
                          f"(default: all)")
     ap.add_argument("--rows", type=lambda v: tuple(map(int, v.split(","))),
-                    default=SORTPATH_ROWS,
+                    default=None,
                     help="sortpath: the sizes to read, of "
-                         f"{','.join(map(str, SORTPATH_ROWS))}")
+                         f"{','.join(map(str, SORTPATH_ROWS))}; "
+                         "coalescealone: the members' capacities "
+                         f"(default {','.join(map(str, COALESCE_ROWS))})")
     ap.add_argument("--cpu-rehearsal", action="store_true",
                     help="control flow at a tiny size on the CPU backend")
     args = ap.parse_args(argv)

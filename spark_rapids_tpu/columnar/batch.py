@@ -240,6 +240,18 @@ class DeviceBatch:
             total += self.sel.size
         return total
 
+    def live_size_bytes(self) -> Optional[int]:
+        """What the rows known to be live hold, ESTIMATED from
+        ``rows_hint`` (where a sizes pull left one): the footprint scaled
+        by live rows over capacity, so without the padding of the
+        capacity bucket. Exact for fixed-width columns; a string column's
+        bytes are taken to be spread evenly over the rows. None where no
+        count is known: the caller decides what stands for it."""
+        if self.rows_hint is None or self.capacity <= 0:
+            return None
+        return self.device_size_bytes() * min(self.rows_hint,
+                                              self.capacity) // self.capacity
+
 
 def concat_batches(batches: Sequence[DeviceBatch], capacity: int) -> DeviceBatch:
     """Concatenate the live rows of ``batches`` into one dense batch of
@@ -304,6 +316,28 @@ MIN_SHRINK_BYTES = 4 << 20
 # the query went 0.281 -> 0.340 s. R = 2 is the largest the cell bears.
 PROBE_SHRINK_RATIO = 2
 
+# ``coalesce_iter`` concatenates batches to spare their consumer a round
+# of dispatches and pulls a batch. Moving a member costs an index pass and
+# a packed gather a slab over the OUTPUT capacity, and that is never cheap.
+# Measured on a v5e (scripts/chip_probe.py coalescealone, PR 35; ms, concat
+# then one consumer call against a consumer call a member, with a blocking
+# read of each output's count behind it; q1's layout and slot update / q3's
+# lineitem layout and dense probe), a PAIR of members: 262,144 rows 13.5
+# against 7.0 / 18.5 against 10.8; 524,288 rows 25.4 against 7.5 / 34.8
+# against 16.6; 786,432 rows 37.3 against 8.8 / 94.5 against 22.0;
+# 1,048,576 rows 53.6 against 10.5 / 124.9 against 27.9; in the group the
+# 4-Mi goal makes (16, 8, 5, 4 members) 184-188 against 20-55 / 151-268
+# against 54-85. A concat is 21-23 ns a row of output up to 1,048,576 rows
+# and 42-96 above; the slot update 3.3 ms a call and 1.7 ns a row, the
+# probe 19.5 ns a row: the break-even is ~150,000-250,000 rows. In the
+# cells (kept traces, TPC-H SF10): four 1,048,576-row scan batches into
+# 4,194,304 took 304 ms to save three slot updates of 2.7 ms, 4.29 of q1's
+# 4.48 s of device time a query. The constant sits above the break-even,
+# where it changes no program of a cell measured before it (no one-chip
+# SF1 cell holds a member of even 262,144 rows in a group of several, the
+# mesh cell none of 524,288; CPU rehearsals at scale 1).
+COALESCE_ALONE_ROWS = 1 << 19
+
 # -- counters -----------------------------------------------------------------
 # Process-wide totals of what ``coalesce_iter(shrink=True)`` decided, in
 # the manner of ``mesh_exchange.counters()``: ``shrinkMembers`` (members
@@ -336,10 +370,13 @@ def coalesce_iter(batches, target_rows: int, shrink: bool = False,
     minimal host syncs (grouping keys off static capacities, the exchange
     serving idiom — GpuCoalesceBatches.scala:115 done the TPU way).
 
-    Per-batch device work has a fixed floor on this chip (dispatch +
-    kernel latency ~tens of ms at any size), so streaming 8 scan-file
-    batches through a join probe or partial aggregate costs 8 floors
-    where one coalesced batch costs one + a single packed concat gather.
+    Every batch costs its consumer a round of dispatches and, further
+    on, sizes pulls (a few ms of host time), so many SMALL batches
+    through a join probe or partial aggregate cost that many rounds where
+    one coalesced batch costs one and a packed concat gather. A member of
+    ``COALESCE_ALONE_ROWS`` rows of capacity or more is not moved for
+    that: it goes on as it is, in its place among the runs of smaller
+    members, which are concatenated as ever.
 
     ``shrink=True`` additionally re-buckets sparse members first (one
     batched sizes pull per group, skipped where rows_hint is known and
@@ -382,29 +419,47 @@ def coalesce_iter(batches, target_rows: int, shrink: bool = False,
                 sp.note(**{k: tally[k] for k in _DECISIONS})
             with _COUNTER_LOCK:
                 _COUNTERS.update(tally)
-        if len(g) == 1:
-            return g[0]
-        cap = bucket_capacity(sum(b.capacity for b in g))
-        out = jit_concat_batches(g, cap)
-        hints = [b.rows_hint for b in g]
-        if all(h is not None for h in hints):
-            out.rows_hint = sum(hints)
-        return out
+        run: List[DeviceBatch] = []
+        for b in g:
+            if b.capacity < COALESCE_ALONE_ROWS:
+                run.append(b)
+                continue
+            if run:
+                yield _concat_run(run)
+                run = []
+            if len(g) > 1:
+                from spark_rapids_tpu import monitoring
+                monitoring.count("coalesceAloneRows", b.capacity)
+            yield b
+        if run:
+            yield _concat_run(run)
 
     for b in batches:
         bb = b.device_size_bytes()
         if group and (group_cap + b.capacity > target_rows
                       or group_bytes + bb > target_bytes):
-            yield flush()
+            yield from flush()
             group, group_cap, group_bytes = [], 0, 0
         group.append(b)
         group_cap += b.capacity
         group_bytes += bb
         if group_cap >= target_rows or group_bytes >= target_bytes:
-            yield flush()
+            yield from flush()
             group, group_cap, group_bytes = [], 0, 0
     if group:
-        yield flush()
+        yield from flush()
+
+
+def _concat_run(run: List[DeviceBatch]) -> DeviceBatch:
+    """The members of ``run`` as one batch (itself, where it is one)."""
+    if len(run) == 1:
+        return run[0]
+    out = jit_concat_batches(run, bucket_capacity(sum(b.capacity
+                                                      for b in run)))
+    hints = [b.rows_hint for b in run]
+    if all(h is not None for h in hints):
+        out.rows_hint = sum(hints)
+    return out
 
 
 def shrink_to_capacity(batch: DeviceBatch, capacity: int) -> DeviceBatch:

@@ -309,47 +309,133 @@ class DeviceScanCache:
     scan-unit granularity: a unit's decoded DeviceBatches stay in HBM,
     keyed by file identity (path, mtime, size), unit ordinal and the
     pruned column set, so a repeated query serves them without touching
-    the host->device link or the host decode in front of it. LRU-evicted down to the configured byte budget;
-    rewritten files miss naturally via the mtime/size key."""
+    the host->device link or the host decode in front of it. Rewritten
+    files miss naturally via the mtime/size key.
+
+    Kept to the configured byte budget by evicting from the least
+    recently used end, with one exception (PR 35). A query reads a
+    table's units in one order every time, and under such a cyclic scan
+    a plain LRU has a cliff: a working set ONE unit over the budget
+    evicts, for each unit it takes in, the unit the next step needs, and
+    every unit misses in every query. So a unit whose admission would
+    evict a unit of its OWN scan (same format, columns, options and
+    batch size) is turned away and what is held stays, unless that
+    unit has not been served since this one was last turned away: the
+    part of the table that does not fit misses every query, the rest
+    hits, and stale entries (an overwritten table's old files, which
+    nothing serves any more) yield on the new units' second pass."""
+
+    _COUNTS = ("scanCacheHitUnits", "scanCacheMissUnits",
+               "scanCacheHitBytes", "scanCacheMissBytes",
+               "scanCacheRefillBytes", "scanCacheEvictedBytes",
+               "scanCacheRejectedBytes")
+    # Keys remembered after they left or were turned away (the oldest
+    # forgotten first): what makes a miss a REFILL and not a first fill.
+    _GONE_KEYS = 1 << 16
 
     def __init__(self):
         self._entries: "dict" = {}     # key -> [DeviceBatch]
         self._bytes: Dict[Any, int] = {}
         self._total = 0
+        self._tick = 0
+        self._served: Dict[Any, int] = {}   # key -> tick of its last use
+        self._gone: Dict[Any, int] = {}     # key -> tick it left at
+        self._counts = dict.fromkeys(self._COUNTS, 0)
         # Probed/filled from pipeline prefetch threads and concurrent
         # consumers: LRU reorder + eviction accounting must be atomic.
         self._lock = threading.Lock()
 
-    def get(self, key):
+    def get(self, key, probe: bool = False):
+        """The unit's batches, or None. ``probe=True`` only asks (the
+        prefetch thread deciding what to decode): the consumer's own
+        ``get`` is the hit that counts and that marks the unit served."""
         with self._lock:
-            e = self._entries.pop(key, None)
-            if e is not None:
-                self._entries[key] = e     # move to MRU position
+            e = self._entries.get(key)
+            if e is not None and not probe:
+                self._entries[key] = self._entries.pop(key)   # to MRU
+                self._tick += 1
+                self._served[key] = self._tick
+                self._counts["scanCacheHitUnits"] += 1
+                self._counts["scanCacheHitBytes"] += self._bytes[key]
             return e
 
     def put(self, key, batches, budget: int):
+        """Offer a unit that was just decoded and uploaded because the
+        cache did not hold it: counted as a miss whatever becomes of it."""
         size = sum(b.device_size_bytes() for b in batches)
-        if size > budget:
-            return
         with self._lock:
+            c = self._counts
+            c["scanCacheMissUnits"] += 1
+            c["scanCacheMissBytes"] += size
             if key in self._entries:
                 return                     # concurrent filler won
-            while self._total + size > budget and self._entries:
-                old_key = next(iter(self._entries))
-                self._entries.pop(old_key)
-                self._total -= self._bytes.pop(old_key)
+            self._tick += 1
+            since = self._gone.pop(key, None)   # it left, or was turned
+            if since is not None:               # away, at this tick
+                c["scanCacheRefillBytes"] += size
+            if size > budget:
+                c["scanCacheRejectedBytes"] += size
+                self._forget(key)
+                return
+            victims, freed = [], 0
+            for old in self._entries:
+                if self._total - freed + size <= budget:
+                    break
+                if self._same_scan(old, key) and (
+                        since is None or self._served[old] > since):
+                    self._forget(key)
+                    return
+                victims.append(old)
+                freed += self._bytes[old]
+            for old in victims:
+                self._entries.pop(old)
+                self._served.pop(old)
+                c["scanCacheEvictedBytes"] += self._bytes[old]
+                self._total -= self._bytes.pop(old)
+                self._forget(old)
             self._entries[key] = list(batches)
             self._bytes[key] = size
+            self._served[key] = self._tick
             self._total += size
+
+    @staticmethod
+    def _same_scan(a, b) -> bool:
+        """A key is ``(scan, unit)`` (``FileScanExec._unit_cache_key``):
+        the units of one scan share the first."""
+        return a[0] == b[0]
+
+    def _forget(self, key) -> None:
+        self._gone[key] = self._tick
+        if len(self._gone) > self._GONE_KEYS:
+            self._gone.pop(next(iter(self._gone)))
+
+    def counters(self) -> Dict[str, int]:
+        with self._lock:
+            return {**self._counts, "scanCacheResidentBytes": self._total}
 
     def clear(self):
         with self._lock:
             self._entries.clear()
             self._bytes.clear()
+            self._served.clear()
+            self._gone.clear()
             self._total = 0
 
 
 DEVICE_SCAN_CACHE = DeviceScanCache()
+
+
+def counters() -> Dict[str, int]:
+    """Process totals of the device scan cache, in the manner of
+    ``columnar/batch.py counters()``: units and device bytes served from
+    it (``scanCacheHitUnits`` / ``HitBytes``), decoded and uploaded
+    because it did not hold them (``MissUnits`` / ``MissBytes``), the
+    part of those bytes whose key it had met before — held and evicted,
+    or turned away (``scanCacheRefillBytes``: 0 for ever where the
+    working set is resident, warm-ups included), bytes evicted, bytes of
+    units larger than the whole budget (``RejectedBytes``) and what it
+    holds now (``scanCacheResidentBytes``)."""
+    return DEVICE_SCAN_CACHE.counters()
 
 
 class FileScanExec(LeafExec):
@@ -499,7 +585,8 @@ class FileScanExec(LeafExec):
         else:
             for unit in units:
                 if use_cache and DEVICE_SCAN_CACHE.get(
-                        self._unit_cache_key(unit, rows)) is not None:
+                        self._unit_cache_key(unit, rows),
+                        probe=True) is not None:
                     payload.append((unit, "cached"))
                     continue
                 faults.fault_point("scan")
@@ -600,11 +687,14 @@ class FileScanExec(LeafExec):
             st = os.stat(unit.path)
         except OSError:
             return None
-        # Reader options and the user schema change how the same bytes
-        # decode (CSV delimiter/header, imposed types): they must key the
-        # cache or two differently-configured scans would share entries.
-        return (self.fmt, unit.path, st.st_mtime_ns, st.st_size, unit.index,
-                self._schema, self._opts_key, rows)
+        # ``(scan, unit)``. Reader options and the user schema change how
+        # the same bytes decode (CSV delimiter/header, imposed types):
+        # they must key the cache or two differently-configured scans
+        # would share entries. They are what one scan's units have in
+        # common (``DeviceScanCache._same_scan``); the file's identity
+        # and the unit's ordinal tell its units apart.
+        return ((self.fmt, self._schema, self._opts_key, rows),
+                (unit.path, st.st_mtime_ns, st.st_size, unit.index))
 
     def execute_device(self, ctx, partition):
         m = ctx.metrics_for(self)

@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from spark_rapids_tpu import monitoring
 from spark_rapids_tpu.columnar import dtypes as dt
 from spark_rapids_tpu.columnar.batch import (
     DeviceBatch, DeviceColumn, bucket_capacity, concat_batches)
@@ -259,6 +260,19 @@ def _maybe_build_dense(built: BuiltSide, batch: DeviceBatch,
                      built.table_spans, tuple(key_ordinals))
 
 
+def _settle(x) -> None:
+    """Where the flight recorder is on, wait for ``x`` on the device, so
+    that a ``join-build`` span ends when the work it dispatched is done
+    and its time is the build's and not the dispatch's. The wait would
+    otherwise land wherever the host next reads the device (the first
+    probe batch's sizes pull, as a rule): the span would read a few ms of
+    a build that holds the chip for 200 (PR 35, q3 at SF10). With the
+    recorder off nothing waits: the build overlaps the host's dispatch
+    of the first probe batch, as ``_device_join_stream`` arranges."""
+    if monitoring.enabled():
+        jax.block_until_ready(x)
+
+
 def _pair_keys_equal(built: BuiltSide, b_idx: jnp.ndarray,
                      probe: DeviceBatch, p_idx: jnp.ndarray,
                      probe_ordinals: Sequence[int],
@@ -379,6 +393,34 @@ class _JoinKernelMixin:
                 type(self).__name__, self.join_type,
                 kc.fingerprint(self.condition))
         return fp
+
+    def _build(self, ctx, bbatches, key_ordinals) -> BuiltSide:
+        """One build side, from its child's last batch to the sorted
+        side READY on the device: the concat into one batch and
+        ``_build_side``'s fingerprint sort, as one ``join-build`` span
+        (``_device_join_stream`` adds the second, the dense table).
+        Never around the child's pull."""
+        with monitoring.span(
+                "build-side", "join-build",
+                args={"op": self.name, "batches": len(bbatches),
+                      "capacities": [b.capacity for b in bbatches]}
+                if monitoring.enabled() else None):
+            single = coalesce_to_single_batch(bbatches)
+            monitoring.count("joinBuildRows", single.capacity)
+            built = build_side(single, key_ordinals,
+                               metrics=ctx.metrics_for(self))
+            _settle(built.fp)
+        return built
+
+    def _probe_span(self, pbatch: DeviceBatch, path: str):
+        """One ``join-probe`` span a probe batch: the dispatch of its
+        probe and emit programs (and, off the fast paths, the pull of the
+        pair count), not the pull of the batch from the child."""
+        return monitoring.span(
+            "probe", "join-probe",
+            args={"op": self.name, "path": path,
+                  "capacity": pbatch.capacity}
+            if monitoring.enabled() else None)
 
     def _probe_jit_fn(self):
         """Jitted probe step from the process-global cache: fingerprint
@@ -529,55 +571,35 @@ class _JoinKernelMixin:
         # with no further syncs) and the dense direct-address table.
         jittable = cond is None or getattr(cond, "jittable", False)
         mr = None
-        if built.stats is not None:
-            mr = built.stats_host()[0]
-        elif built.max_run is not None:
-            mr = int(built.max_run)
-        if mr is not None and jt in ("inner", "left", "right", "semi",
-                                     "anti") and jittable:
-            _maybe_build_dense(built, built.batch, built.key_ordinals)
+        with monitoring.span(
+                "table", "join-build",
+                args={"op": self.name, "capacity": build_cap}
+                if monitoring.enabled() else None):
+            if built.stats is not None:
+                mr = built.stats_host()[0]
+            elif built.max_run is not None:
+                mr = int(built.max_run)
+            if mr is not None and jt in ("inner", "left", "right", "semi",
+                                         "anti") and jittable:
+                _maybe_build_dense(built, built.batch, built.key_ordinals)
+                _settle(built.table)
         from spark_rapids_tpu.memory.oom import retry_on_oom
         if built.table is not None:
             dense = self._dense_jit_fn()
             for pbatch in probe_iter:
-                yield retry_on_oom(
-                    dense, built, pbatch, probe_keys=tuple(probe_keys),
-                    build_is_right=build_is_right)
+                with self._probe_span(pbatch, "dense"):
+                    out = retry_on_oom(
+                        dense, built, pbatch,
+                        probe_keys=tuple(probe_keys),
+                        build_is_right=build_is_right)
+                yield out
             return
         fast = mr is not None and 0 < mr <= self._FAST_PATH_MAX_RUN
         for pbatch in probe_iter:
-            if fast:
-                out_cap = bucket_capacity(max(pbatch.capacity * mr, 1))
-                if jittable:
-                    out, covered = retry_on_oom(
-                        self._probe_jit_fn(),
-                        built, pbatch, out_cap=out_cap,
-                        build_is_right=build_is_right,
-                        probe_keys=tuple(probe_keys))
-                else:
-                    lo, counts, plive = probe_ranges(
-                        built, pbatch, probe_keys, built.null_safe)
-                    out, covered = self._emit_expanded(
-                        built, pbatch, lo, counts, plive, out_cap,
-                        build_is_right, probe_keys)
-            else:
-                # (Semi/anti also go through expansion: candidate
-                # fingerprint ranges must be key-verified before deciding
-                # hit/miss.) The eagerly-computed ranges are reused by the
-                # emit step — probe keys are hashed once per batch.
-                lo, counts, plive = probe_ranges(built, pbatch, probe_keys,
-                                                 built.null_safe)
-                total = int(jnp.sum(counts))
-                out_cap = bucket_capacity(max(total, 1))
-                if jittable:
-                    out, covered = self._emit_jit_fn()(
-                        built, pbatch, lo, counts, plive, out_cap=out_cap,
-                        build_is_right=build_is_right,
-                        probe_keys=tuple(probe_keys))
-                else:
-                    out, covered = self._emit_expanded(
-                        built, pbatch, lo, counts, plive, out_cap,
-                        build_is_right, probe_keys)
+            with self._probe_span(pbatch, "fast" if fast else "expand"):
+                out, covered = self._probe_batch(
+                    built, pbatch, mr if fast else None, jittable,
+                    build_is_right, probe_keys)
             if covered_acc is not None and covered is not None:
                 covered_acc = covered_acc | covered
             yield out
@@ -587,6 +609,44 @@ class _JoinKernelMixin:
             yield self._null_extend_build(
                 built, build_unmatched, self._probe_schema_batch(),
                 build_is_right)
+
+    def _probe_batch(self, built: BuiltSide, pbatch: DeviceBatch,
+                     fast_run: Optional[int], jittable: bool,
+                     build_is_right: bool, probe_keys):
+        """One probe batch off the dense path: ``(out, covered)``. With
+        ``fast_run`` (the build side's longest key run, small) the output
+        is sized from the probe's capacity and nothing is pulled; without
+        it the pair count is."""
+        from spark_rapids_tpu.memory.oom import retry_on_oom
+        if fast_run is not None:
+            out_cap = bucket_capacity(max(pbatch.capacity * fast_run, 1))
+            if jittable:
+                return retry_on_oom(
+                    self._probe_jit_fn(),
+                    built, pbatch, out_cap=out_cap,
+                    build_is_right=build_is_right,
+                    probe_keys=tuple(probe_keys))
+            lo, counts, plive = probe_ranges(
+                built, pbatch, probe_keys, built.null_safe)
+            return self._emit_expanded(
+                built, pbatch, lo, counts, plive, out_cap,
+                build_is_right, probe_keys)
+        # (Semi/anti also go through expansion: candidate fingerprint
+        # ranges must be key-verified before deciding hit/miss.) The
+        # eagerly-computed ranges are reused by the emit step — probe
+        # keys are hashed once per batch.
+        lo, counts, plive = probe_ranges(built, pbatch, probe_keys,
+                                         built.null_safe)
+        total = int(jnp.sum(counts))
+        out_cap = bucket_capacity(max(total, 1))
+        if jittable:
+            return self._emit_jit_fn()(
+                built, pbatch, lo, counts, plive, out_cap=out_cap,
+                build_is_right=build_is_right,
+                probe_keys=tuple(probe_keys))
+        return self._emit_expanded(
+            built, pbatch, lo, counts, plive, out_cap,
+            build_is_right, probe_keys)
 
     def _probe_schema_batch(self) -> DeviceBatch:
         build_right = self.join_type != "right"
@@ -773,10 +833,8 @@ class ShuffledHashJoinExec(Exec, _JoinKernelMixin):
                 build_keys, probe_keys, build_right, total_bytes,
                 grace_budget)
             return
-        single = coalesce_to_single_batch(bbatches)
-        built = build_side(single, self._key_ordinals(build_child,
-                                                      build_keys),
-                           metrics=ctx.metrics_for(self))
+        built = self._build(ctx, bbatches,
+                            self._key_ordinals(build_child, build_keys))
         yield from self._device_join_stream(
             ctx, built, probe_iter,
             self._key_ordinals(probe_child, probe_keys), build_right)
@@ -867,8 +925,7 @@ class ShuffledHashJoinExec(Exec, _JoinKernelMixin):
                                 pbatch, pbatch.row_mask(), built,
                                 build_right)
                     continue
-                built = build_side(coalesce_to_single_batch(bucket),
-                                   bords, metrics=ctx.metrics_for(self))
+                built = self._build(ctx, bucket, bords)
                 yield from self._device_join_stream(
                     ctx, built, probe_bucket, pords, build_right)
         finally:
@@ -942,10 +999,8 @@ class BroadcastHashJoinExec(ShuffledHashJoinExec):
             for cp in range(build_child.num_partitions(ctx)):
                 bbatches.extend(build_child.execute_device(ctx, cp))
             if bbatches:
-                single = coalesce_to_single_batch(bbatches)
-                built = build_side(single, self._key_ordinals(
-                    build_child, build_keys),
-                    metrics=ctx.metrics_for(self))
+                built = self._build(ctx, bbatches, self._key_ordinals(
+                    build_child, build_keys))
             else:
                 built = "EMPTY"
             ctx.cache[cache_key] = built

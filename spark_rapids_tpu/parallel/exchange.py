@@ -20,7 +20,7 @@ GpuRangePartitioner's reservoir sample.
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -414,6 +414,7 @@ class ShuffleExchangeExec(Exec):
         finally:
             pipe.close()
         sess.commit()
+        monitoring.count("exchangeRows", sum(bucket_rows))
         ctx.cache[key] = sess
         ctx.cache[key + ":rows"] = bucket_rows
         return sess
@@ -533,12 +534,22 @@ class ShuffleExchangeExec(Exec):
         yield from iter(buckets[partition])
 
     # -- runtime adaptive re-planning ----------------------------------------
-    def observed_total_bytes(self, ctx) -> int:
-        """Materialize (idempotent) and return the EXACT total bytes the
-        transport session observed across all map shards — the number
-        runtime re-planning (parallel/replan.py) demotes joins on."""
+    def observed_sizes(self, ctx) -> Tuple[int, int, int]:
+        """Materialize (idempotent) and return ``(live bytes, footprint
+        bytes, shards without a row count)`` of the map side, as the
+        transport session observed them across all shards. Runtime
+        re-planning (parallel/replan.py) demotes joins on the FIRST: the
+        bytes of the live rows, estimated from each shard's ``rows_hint``
+        (``DeviceBatch.live_size_bytes()``; a shard written here has one:
+        ``_materialize_device`` pulls the pieces' counts before it
+        writes). Not on the shards' footprint, as until PR 35: a piece
+        pads to its capacity bucket, up to a third more, and a plan must
+        not hang on padding (q3 at SF10: 53.96 MB of live rows against a
+        threshold of 67.1, in shards whose footprint was over it). The
+        third says how many device shards went into the first at their
+        footprint for want of a count."""
         sess = self._materialize_device(ctx)
-        return sess.observed_bytes()
+        return sess.live_bytes, sess.observed_bytes(), sess.uncounted_shards
 
     # -- pipelined execution -------------------------------------------------
     def stage_prematerialize(self, ctx) -> None:

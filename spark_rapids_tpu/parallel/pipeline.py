@@ -201,6 +201,10 @@ class PartitionPipeline:
         self._slots: Dict[int, _Slot] = {}
         self._submitted = -1
         self._closed = False
+        # Whether a consume finds its prefetch unfinished is the host's
+        # load's to say: publish the count, 0 included, so that the
+        # query's Pipeline entry has one shape on every run.
+        _record(ctx, "pipelineStalls", 0)
 
     # -- producers -----------------------------------------------------------
     def _prefetch_task(self, partition: int, cancel,

@@ -13,8 +13,11 @@ before stage prematerialization):
    both inputs are materialized exchanges (the stage-DAG boundaries of
    parallel/stages.py), bottom-up so inner joins decide first.
 2. For each candidate, materialize ONLY the build-side exchange — its
-   transport session records the exact per-partition byte sizes
-   (`ShuffleSession.record_shard_bytes`, the size-observation hook).
+   transport session records the exact byte sizes of what it wrote
+   (`ShuffleSession.record_shard_bytes`, the size-observation hook), and
+   of those the bytes of LIVE rows: the threshold is stated in bytes of
+   data, as the planner's estimate is, and the padding of a shard's
+   capacity bucket (up to a third) is none.
 3. When the observed build size fits ``autoBroadcastJoinThreshold``, the
    join DEMOTES to a broadcast hash join: a rewritten subtree whose
    build input is the already-materialized exchange (served as broadcast
@@ -30,9 +33,12 @@ before stage prematerialization):
    the sizes and re-derives the same demotion deterministically.
 
 Counters land in the query's ``Cost@query`` metrics entry
-(``replanChecks`` / ``joinDemotions`` / ``replanObservedBytes`` /
-``estimateErrorPct``) and in the process-global cost counters
-(plan/cost.py ``counters()``).
+(``replanChecks`` / ``joinDemotions`` / ``replanObservedBytes`` (live
+rows, what the rule reads) / ``replanFootprintBytes`` (the same shards
+with their padding, what it read until PR 35) / ``replanUncountedShards``
+(shards in the first at their footprint, for want of a row count: 0
+where the rule read no padding) / ``estimateErrorPct``) and, but for the
+last, in the process-global cost counters (plan/cost.py ``counters()``).
 """
 
 from __future__ import annotations
@@ -105,8 +111,12 @@ def plan_adaptive(ctx, root) -> None:
         build_right = join.join_type != "right"
         build_ex = join.children[1] if build_right else join.children[0]
         probe_ex = join.children[0] if build_right else join.children[1]
-        observed = build_ex.observed_total_bytes(ctx)
-        m.add("replanObservedBytes", observed)
+        observed, footprint, uncounted = build_ex.observed_sizes(ctx)
+        for name, n in (("replanObservedBytes", observed),
+                        ("replanFootprintBytes", footprint),
+                        ("replanUncountedShards", uncounted)):
+            m.add(name, n)
+            COST._record(name, n)
         est = getattr(join, "est_build_bytes", None)
         if est is not None and observed > 0:
             m.add("estimateErrorPct",

@@ -79,10 +79,31 @@ class ShuffleSession:
         # hostfile) — EXACT sizes, the GpuCustomShuffleReaderExec
         # materialized-stats analog.
         self.shard_bytes: Dict[int, int] = {}
+        # The same without the padding of capacity buckets, where the
+        # transport knows the live rows of what it wrote (a device shard
+        # that carries a ``rows_hint``: an estimate, the footprint scaled
+        # by it; a framed blob holds live rows alone): what a runtime
+        # re-plan holds against a threshold stated in bytes of data
+        # (parallel/replan.py). A device shard WITHOUT a count goes in at
+        # its footprint and is counted, so that a plan which hung on
+        # whether a sizes pull happened upstream says so.
+        self.live_bytes = 0
+        self.uncounted_shards = 0
 
-    def record_shard_bytes(self, partition: int, nbytes: int) -> None:
+    def record_shard_bytes(self, partition: int, nbytes: int,
+                          live_bytes: Optional[int] = None) -> None:
         self.shard_bytes[partition] = \
             self.shard_bytes.get(partition, 0) + int(nbytes)
+        self.live_bytes += int(nbytes if live_bytes is None
+                               else live_bytes)
+
+    def record_device_shard(self, partition: int, batch) -> None:
+        """A device batch written as a shard: its footprint, and its live
+        bytes as ``DeviceBatch.live_size_bytes()`` estimates them."""
+        live = batch.live_size_bytes()
+        if live is None:
+            self.uncounted_shards += 1
+        self.record_shard_bytes(partition, batch.device_size_bytes(), live)
 
     def observed_bytes(self, partition: Optional[int] = None) -> int:
         """Total observed bytes of one partition, or of the whole map

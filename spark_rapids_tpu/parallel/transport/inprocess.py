@@ -30,7 +30,7 @@ class InProcessSession(ShuffleSession):
         from spark_rapids_tpu.memory.stores import (
             PRIORITY_SHUFFLE_OUTPUT, SpillableBatch)
         faults.fault_point("transport.write", owner=self.owner)
-        self.record_shard_bytes(partition, batch.device_size_bytes())
+        self.record_device_shard(partition, batch)
         self.buckets[partition].append(SpillableBatch(
             self._catalog, batch, PRIORITY_SHUFFLE_OUTPUT))
 

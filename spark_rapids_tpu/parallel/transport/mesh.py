@@ -32,7 +32,7 @@ class MeshSession(ShuffleSession):
     def write_shard(self, partition: int, batch) -> None:
         from spark_rapids_tpu.memory.stores import (
             PRIORITY_SHUFFLE_OUTPUT, SpillableBatch)
-        self.record_shard_bytes(partition, batch.device_size_bytes())
+        self.record_device_shard(partition, batch)
         self.buckets[partition].append(SpillableBatch(
             self._catalog, batch, PRIORITY_SHUFFLE_OUTPUT))
 

@@ -138,6 +138,18 @@ def test_overlap_counters_flow(data_dir, baselines):
 
 
 @requires_pipeline
+def test_stall_count_is_published_whatever_the_load(data_dir):
+    """``pipelineStalls`` is in the query's Pipeline entry whether or
+    not a consume found its prefetch unfinished (the host's load decides
+    that), so the entry has one shape on every run."""
+    for _ in range(2):
+        m = tpch.QUERIES["q6"](_session(), data_dir)
+        m.collect()
+        entry = m.metrics()["Pipeline@query"]
+        assert entry.get("pipelineStalls", -1) >= 0, entry
+
+
+@requires_pipeline
 def test_concurrent_stage_materialization(data_dir):
     """Shuffled join (auto-broadcast off): the build- and probe-side
     exchanges are independent stages and materialize concurrently."""

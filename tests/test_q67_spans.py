@@ -19,7 +19,7 @@ from spark_rapids_tpu.monitoring import recorder
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 COUNTERS = ("expandRowsIn", "expandRowsOut", "expandProjections",
             "aggUpdateRows", "aggConsolidateLevels", "windowRowsIn",
-            "windowBatches")
+            "windowBatches", "joinBuildRows", "exchangeRows")  # PR 35's two
 
 
 def _tpcds():

@@ -299,3 +299,41 @@ def test_download_of_a_kept_batch_is_dense():
     b, live = member(98)
     (kept,) = list(B.coalesce_iter([b], 1 << 20, shrink=True))
     assert device_to_host(kept).num_rows == live
+
+
+@pytest.mark.parametrize("shrink", [False, True])
+def test_a_large_member_goes_on_alone_among_the_runs_of_small_ones(shrink):
+    """PR 35: a member of ``COALESCE_ALONE_ROWS`` rows or more is not
+    moved to spare its consumer a round of dispatches; the smaller ones
+    around it are concatenated as ever, and the order stays."""
+    big_cap, small_cap = B.COALESCE_ALONE_ROWS, B.COALESCE_ALONE_ROWS // 4
+    (s1, n1), (s2, n2), (s3, n3) = (member(98, small_cap, sel=False),
+                                    member(98, small_cap, sel=False),
+                                    member(98, small_cap, sel=False))
+    (b1, m1), (b2, m2) = member(98, big_cap), member(98, big_cap)
+    recorder.configure(True)
+    recorder.reset_counters()
+    try:
+        out = list(B.coalesce_iter([s1, s2, b1, b2, s3], 4 << 20,
+                                   shrink=shrink, keep_ratio=PROBE))
+        alone = recorder.counters().get("coalesceAloneRows")
+    finally:
+        recorder.configure(False)
+        recorder.reset_counters()
+    assert [o.capacity for o in out] == [2 * small_cap, big_cap, big_cap,
+                                         small_cap]
+    assert out[1] is b1 and out[2] is b2        # not rewritten
+    assert alone == 2 * big_cap
+    got = [sorted(live_values(o).tolist()) for o in out]
+    assert got[0] == sorted(live_values(s1).tolist()
+                            + live_values(s2).tolist())
+    assert len(got[1]) == m1 and len(got[2]) == m2 and len(got[3]) == n3
+    # a group of one is no decision: nothing counted
+    recorder.configure(True)
+    try:
+        (only,) = list(B.coalesce_iter([b1], 4 << 20, shrink=shrink))
+        assert only is b1
+        assert "coalesceAloneRows" not in recorder.counters()
+    finally:
+        recorder.configure(False)
+        recorder.reset_counters()
