@@ -2,9 +2,9 @@
 
     python scripts/chip_probe.py [sync] [link] [upload] [prefix] [slots] [rowmove]
                                  [shrinkrule] [sortpath] [sortgrid] [coalescealone]
-                                 [--rows N,N,...]
+                                 [lategather] [--rows N,N,...]
 
-With no section named it runs all ten. One process, one chip, one JSON
+With no section named it runs all eleven. One process, one chip, one JSON
 object per line, every reading on the host's clock around a
 ``block_until_ready`` (on an attached chip that waits for completion):
 
@@ -107,6 +107,20 @@ object per line, every reading on the host's clock around a
              consumer call by themselves. The table behind
              ``batch.COALESCE_ALONE_ROWS``.
 
+- ``lategather`` where the dense join probe gathers the build side's
+             rows (PR 37): q3's lineitem probe, 1,048,576 rows against
+             SF10's build side (1.46 M keys, capacity 2,097,152, nine
+             words a row) and 786,432 rows against SF1's (147,000 keys,
+             capacity 786,432), 0.5 / 10 / 40 / 60 / 100 % of the probe's
+             rows matching. EAGER (the parent): ``_dense_step``, and the
+             consumer's ``shrink_to_capacity`` of its output where
+             ``shrink_all``'s rule for a probe compacts it. LATE: the
+             lookup program, then the emit at the count's bucket (index
+             pass, probe rows and build rows gathered there) and the emit
+             at the probe's capacity under a selection vector; ``shipped``
+             names the one ``_dense_stream``'s rule takes. The forms'
+             outputs are compared on the way.
+
 Like ``chip_smoke.py`` it refuses any backend but a TPU unless
 ``--cpu-rehearsal`` is given, which runs the control flow at a tiny size
 on the CPU and says so on every line: a CPU reading is not a device number.
@@ -124,7 +138,8 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 SECTIONS = ("sync", "link", "upload", "prefix", "slots", "rowmove",
-            "shrinkrule", "sortpath", "sortgrid", "coalescealone")
+            "shrinkrule", "sortpath", "sortgrid", "coalescealone",
+            "lategather")
 SORTPATH_ROWS = (98304, 262144, 393216, 524288, 786432, 1048576, 1572864)
 COALESCE_ROWS = (262144, 524288, 786432, 1048576)
 LABEL = {}
@@ -959,11 +974,130 @@ def probe_coalescealone(jax, small: bool) -> None:
                 del big
 
 
+def q3_like_build(build_rows: int, capacity: int, key_span: int, rng):
+    """SF10 q3's second build side (orders x customer, nine words a row:
+    the order key, a date, an int32, two more int64) of ``build_rows``
+    unique EVEN keys below ``key_span`` in a batch of ``capacity`` rows,
+    with its dense table, and the keys."""
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.columnar import dtypes as dt
+    from spark_rapids_tpu.columnar.batch import DeviceBatch, DeviceColumn
+    from spark_rapids_tpu.ops import join as J
+    keys = 2 * rng.choice(key_span // 2, build_rows, replace=False) \
+        .astype(np.int64)
+    live = np.arange(capacity) < build_rows
+
+    def col(t, values):
+        data = np.zeros(capacity, t.np_dtype)
+        data[:build_rows] = values
+        return DeviceColumn(t, jnp.asarray(data), jnp.asarray(live))
+    build = DeviceBatch(
+        (col(dt.INT64, keys),
+         col(dt.DATE, rng.integers(8000, 9200, build_rows)),
+         col(dt.INT32, 0), col(dt.INT64, keys // 4), col(dt.INT64, keys % 97)),
+        jnp.asarray(build_rows, jnp.int32))
+    built = J.build_side(build, [0])
+    J._maybe_build_dense(built, built.batch, built.key_ordinals)
+    assert built.table is not None, "the build side took no dense table"
+    return built, keys
+
+
+def probe_lategather(jax, small: bool) -> None:
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.columnar import dtypes as dt
+    from spark_rapids_tpu.columnar.batch import (
+        PROBE_SHRINK_RATIO, DeviceBatch, DeviceColumn, bucket_capacity,
+        shrink_to_capacity)
+    from spark_rapids_tpu.columnar.rowmove import pack_batch
+    from spark_rapids_tpu.exprs.base import BoundReference as Ref
+    from spark_rapids_tpu.ops import InMemorySourceExec
+    from spark_rapids_tpu.ops import join as J
+    n = 3 if small else 10
+    shapes = ((1 << 10, 1_400, 1 << 11, 15_000),
+              (3 << 8, 140, 3 << 8, 6_000)) if small else (
+        (1 << 20, 1_460_000, 1 << 21, 15_000_000),
+        (3 << 18, 147_000, 3 << 18, 6_000_000))
+    op = J.BroadcastHashJoinExec(
+        InMemorySourceExec((("l_orderkey", dt.INT64), ("price", dt.FLOAT64),
+                            ("disc", dt.FLOAT64)), [[]]),
+        InMemorySourceExec((("o_orderkey", dt.INT64), ("o_date", dt.DATE),
+                            ("o_prio", dt.INT32), ("a", dt.INT64),
+                            ("b", dt.INT64)), [[]]),
+        [Ref(0, dt.INT64)], [Ref(0, dt.INT64)], "inner")
+    dense_fn = op._dense_jit_fn()
+    lookup_fn, emit_fn = J._late_jit_fns()
+
+    def steady(fn):
+        jax.block_until_ready(fn())
+        return ms(timed(fn, n))["median"]
+
+    for rows, build_rows, build_cap, key_span in shapes:
+        rng = np.random.default_rng(rows)
+        built, keys = q3_like_build(build_rows, build_cap, key_span, rng)
+        dense = lambda b: dense_fn(built, b, probe_keys=(0,),  # noqa: E731
+                                   build_is_right=True)
+        lookup = lambda b: lookup_fn(built, b, probe_keys=(0,))  # noqa: E731
+        slabs = {k: list(v.shape) for k, v in pack_batch(built.batch).items()}
+        for pct in (0.5, 10, 40, 60, 100):
+            hit = rng.random(rows) * 100 < pct
+            # a miss is an odd key: in the table's range, held by no order
+            k = np.where(hit, rng.choice(keys, rows),
+                         2 * rng.integers(0, key_span // 2, rows) + 1)
+            ones = jnp.ones((rows,), jnp.bool_)
+            pbatch = DeviceBatch(
+                (DeviceColumn(dt.INT64, jnp.asarray(k), ones),
+                 DeviceColumn(dt.FLOAT64, jnp.asarray(np.round(
+                     rng.uniform(900, 105_000, rows), 2)), ones),
+                 DeviceColumn(dt.FLOAT64, jnp.asarray(
+                     rng.integers(0, 11, rows) / 100.0), ones)),
+                jnp.asarray(rows, jnp.int32))
+            pos, found, count = lookup(pbatch)
+            matched = int(jax.device_get(count))
+            bucket = bucket_capacity(max(matched, 1))
+            compacts = bucket * PROBE_SHRINK_RATIO <= rows
+            emit_at = lambda cap: emit_fn(  # noqa: E731
+                built.batch, pbatch, pos, found, count, out_cap=cap,
+                build_is_right=True)
+            eager = (lambda: shrink_to_capacity(dense(pbatch), bucket)) \
+                if compacts else (lambda: dense(pbatch))
+            late = lambda cap: emit_fn(  # noqa: E731
+                built.batch, pbatch, *lookup(pbatch), out_cap=cap,
+                build_is_right=True)
+            want = eager()
+            got = late(bucket if compacts else None)
+            same = all(bool((a == b).all()) for a, b in zip(
+                jax.tree_util.tree_leaves(got),
+                jax.tree_util.tree_leaves(want)))
+            assert same, f"late and eager differ at {rows} rows, {pct} %"
+            facts = {"dense_step_ms": steady(lambda: dense(pbatch)),
+                     "lookup_ms": steady(lambda: lookup(pbatch)),
+                     "emit_at_capacity_ms": steady(lambda: emit_at(None)),
+                     "eager_whole_ms": steady(eager),
+                     "late_at_capacity_ms": steady(lambda: late(None))}
+            if bucket < rows:
+                out = dense(pbatch)
+                facts.update(
+                    shrink_ms=steady(
+                        lambda: shrink_to_capacity(out, bucket)),
+                    emit_at_bucket_ms=steady(lambda: emit_at(bucket)),
+                    late_at_bucket_ms=steady(lambda: late(bucket)))
+                del out
+            emit("lategather", rows=rows, build_capacity=build_cap,
+                 build_keys=build_rows, slabs=slabs, match_pct=pct,
+                 matched=matched, bucket=bucket,
+                 shipped="late_at_bucket" if compacts
+                 else "late_at_capacity", same_output=same, **facts)
+        del built
+
+
 PROBES = {"sync": probe_sync, "link": probe_link, "upload": probe_upload,
           "prefix": probe_prefix, "slots": probe_slots,
           "rowmove": probe_rowmove, "shrinkrule": probe_shrinkrule,
           "sortpath": probe_sortpath, "sortgrid": probe_sortgrid,
-          "coalescealone": probe_coalescealone}
+          "coalescealone": probe_coalescealone,
+          "lategather": probe_lategather}
 
 
 def main(argv=None) -> int:

@@ -4,7 +4,8 @@ A cold process is a compiler benchmark (PERF.md, PR 21), and how rows are
 packed (``columnar/rowmove.py``) is in every program that gathers a batch.
 This runs a cell's CPU rehearsal at scale 1 in a checkout, records the
 argument shapes of every ``_dense_step`` program (the dense join probe,
-``ops/join.py``) the cell dispatches, then lowers and compiles each for a
+``ops/join.py``; since PR 37 its late forms ``_late_lookup`` and
+``_late_emit`` too) the cell dispatches, then lowers and compiles each for a
 DESCRIBED v5e chip with the persistent cache off, and prints seconds per
 program. Point it at two checkouts to compare them on one host::
 
@@ -35,21 +36,26 @@ def main(argv) -> int:
     from spark_rapids_tpu.ops import join
 
     seen = {}
-    lookup = join._JoinKernelMixin._dense_jit_fn
 
-    def recording(self):
-        kernel = lookup(self)
-
+    def recording(name, kernel):
         def call(*args, **static):
             avals = jax.tree.map(
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
-            key = (str(jax.tree.structure(avals)),
+            key = (name, str(jax.tree.structure(avals)),
                    str(jax.tree.leaves(avals)), str(sorted(static.items())))
-            seen.setdefault(key, (kernel.fn, avals, static))
+            seen.setdefault(key, (name, kernel.fn, avals, static))
             return kernel(*args, **static)
         return call
 
-    join._JoinKernelMixin._dense_jit_fn = recording
+    dense = join._JoinKernelMixin._dense_jit_fn
+    join._JoinKernelMixin._dense_jit_fn = \
+        lambda self: recording("_dense_step", dense(self))
+    # the late dense probe's two programs (PR 37; absent before)
+    late = getattr(join, "_late_jit_fns", None)
+    if late is not None:
+        join._late_jit_fns = lambda: tuple(
+            recording(name, kernel)
+            for name, kernel in zip(("_late_lookup", "_late_emit"), late()))
     import run as bench_run
     rc = bench_run.main(["--workload", cell, "--seed", "1", "--seconds", "1",
                          "--trace", "0", "--rehearse-cpu", "--scale", "1"])
@@ -64,7 +70,7 @@ def main(argv) -> int:
     chip = SingleDeviceSharding(topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2").devices[0])
     total = 0.0
-    for fn, avals, static in seen.values():
+    for name, fn, avals, static in seen.values():
         avals = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
             x.shape, x.dtype, sharding=chip), avals)
         lowered = fn.lower(*avals, **static)
@@ -73,7 +79,8 @@ def main(argv) -> int:
         seconds = time.perf_counter() - t0
         total += seconds
         print(json.dumps({
-            "program": "_dense_step", "compile_s": round(seconds, 2),
+            "program": name, "compile_s": round(seconds, 2),
+            "static": {k: v for k, v in static.items() if k == "out_cap"},
             "leaves": sorted({f"{x.dtype}{list(x.shape)}"
                               for x in jax.tree.leaves(avals)})}),
               flush=True)

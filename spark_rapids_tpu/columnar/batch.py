@@ -363,6 +363,33 @@ def reset_counters() -> None:
         _COUNTERS.clear()
 
 
+def group_by_goal(batches, target_rows: int, target_bytes: int,
+                  size_of=None):
+    """The stream in lists, as ``coalesce_iter`` groups it: a list is
+    closed as its capacities reach ``target_rows`` or its bytes
+    (``size_of(batch)``, by default the batch's device size)
+    ``target_bytes``, or before the member that would carry it past
+    either. A producer that wants to act once per group its consumer will
+    make (the join's late probe: one count pull a group) cuts here too."""
+    size_of = size_of or DeviceBatch.device_size_bytes
+    group: List[DeviceBatch] = []
+    cap = nbytes = 0
+    for b in batches:
+        nb = size_of(b)
+        if group and (cap + b.capacity > target_rows
+                      or nbytes + nb > target_bytes):
+            yield group
+            group, cap, nbytes = [], 0, 0
+        group.append(b)
+        cap += b.capacity
+        nbytes += nb
+        if cap >= target_rows or nbytes >= target_bytes:
+            yield group
+            group, cap, nbytes = [], 0, 0
+    if group:
+        yield group
+
+
 def coalesce_iter(batches, target_rows: int, shrink: bool = False,
                   target_bytes: int = 512 * 1024 * 1024,
                   owner: Optional[str] = None, keep_ratio: int = 1):
@@ -399,12 +426,7 @@ def coalesce_iter(batches, target_rows: int, shrink: bool = False,
     (the generator runs when that operator pulls, so only here can the
     compaction be told from the child's own work).
     """
-    group: List[DeviceBatch] = []
-    group_cap = 0
-    group_bytes = 0
-
-    def flush():
-        g = group
+    def flush(g: List[DeviceBatch]):
         if shrink:
             # Only batches worth compacting pay a sizes pull (below the
             # threshold the kernel-time saved can't repay a round trip).
@@ -434,20 +456,8 @@ def coalesce_iter(batches, target_rows: int, shrink: bool = False,
         if run:
             yield _concat_run(run)
 
-    for b in batches:
-        bb = b.device_size_bytes()
-        if group and (group_cap + b.capacity > target_rows
-                      or group_bytes + bb > target_bytes):
-            yield from flush()
-            group, group_cap, group_bytes = [], 0, 0
-        group.append(b)
-        group_cap += b.capacity
-        group_bytes += bb
-        if group_cap >= target_rows or group_bytes >= target_bytes:
-            yield from flush()
-            group, group_cap, group_bytes = [], 0, 0
-    if group:
-        yield from flush()
+    for group in group_by_goal(batches, target_rows, target_bytes):
+        yield from flush(group)
 
 
 def _concat_run(run: List[DeviceBatch]) -> DeviceBatch:

@@ -366,6 +366,78 @@ def _gather_cols(batch: DeviceBatch, rows: jnp.ndarray,
     return cols
 
 
+def _dense_lookup(built: BuiltSide, pbatch: DeviceBatch, probe_keys):
+    """The direct-address table lookup: ``(pos, found, plive)``, each of
+    the probe's capacity — the build row a probe row's keys address (-1
+    where the slot is empty), whether the row is live, non-NULL, in range
+    and addressed a build row, and the probe's ``row_mask()``."""
+    base, spans = built.table_base, built.table_spans
+    size = built.table.shape[0]
+    plive = pbatch.row_mask()
+    combined = jnp.zeros((pbatch.capacity,), jnp.int64)
+    inrange = plive
+    for i, o in enumerate(probe_keys):
+        c = pbatch.columns[o]
+        v = c.data.astype(jnp.int64)
+        inrange = inrange & c.validity & (v >= base[i]) & \
+            (v < base[i] + spans[i])
+        combined = combined * spans[i] + (v - base[i])
+    idx = jnp.clip(combined, 0, size - 1)
+    pos = jnp.take(built.table, idx, axis=0)
+    return pos, inrange & (pos >= 0), plive
+
+
+def _late_lookup(built: BuiltSide, pbatch: DeviceBatch, probe_keys):
+    """First half of the late dense probe of an inner join without a
+    condition: the lookup, and the count of rows that found a build row
+    as a device scalar. Nothing of the payload moves yet."""
+    pos, found, _ = _dense_lookup(built, pbatch, probe_keys)
+    return pos, found, jnp.sum(found.astype(jnp.int32))
+
+
+def _late_emit(build: DeviceBatch, pbatch: DeviceBatch, pos, found, count,
+               out_cap: Optional[int], build_is_right: bool) -> DeviceBatch:
+    """Second half, once ``count`` is known on the host. With ``out_cap``
+    (a capacity bucket that holds ``count``): the matched rows alone, in
+    the probe's order, as a dense batch — one index pass over the probe's
+    capacity, then the probe's rows and the build side's rows gathered at
+    ``out_cap``: what ``_dense_step`` followed by ``shrink_to_capacity``
+    gives, row for row, without having gathered the build side's rows of
+    the probe rows that are dropped. ``out_cap`` None (the bucket is no
+    smaller than the consumer would keep): ``_dense_step``'s own output,
+    at the probe's capacity under a selection vector, no index pass."""
+    from spark_rapids_tpu.columnar.rowmove import _live_sources, gather_rows
+    if out_cap is None:
+        probe_out, at, valid = pbatch, pos, found
+    else:
+        src = _live_sources(found, out_cap)
+        valid = jnp.arange(out_cap, dtype=jnp.int32) < count
+        probe_out = gather_rows(pbatch, src, count, valid_dst=valid)
+        at = jnp.take(pos, src, axis=0)
+    build_out = gather_rows(build, jnp.clip(at, 0, build.capacity - 1),
+                            probe_out.num_rows, valid_dst=valid)
+    if build_is_right:
+        cols = tuple(probe_out.columns) + tuple(build_out.columns)
+    else:
+        cols = tuple(build_out.columns) + tuple(probe_out.columns)
+    out = DeviceBatch(cols, probe_out.num_rows)
+    return out.with_sel(found) if out_cap is None else out
+
+
+def _late_jit_fns():
+    """The two programs of the late dense probe, from the process-global
+    cache. Neither reads a join's type or condition (the caller has
+    checked both), so every join shares them."""
+    from spark_rapids_tpu.ops import kernel_cache as kc
+    return (kc.lookup("join-late-lookup", (),
+                      lambda: jax.jit(_late_lookup,
+                                      static_argnames=("probe_keys",))),
+            kc.lookup("join-late-emit", (),
+                      lambda: jax.jit(_late_emit,
+                                      static_argnames=("out_cap",
+                                                       "build_is_right"))))
+
+
 def _join_schema(left: Schema, right: Schema, join_type: str) -> Schema:
     if join_type in ("semi", "anti"):
         return left
@@ -475,20 +547,7 @@ class _JoinKernelMixin:
         from spark_rapids_tpu.columnar.rowmove import gather_rows
         jt = self.join_type
         cond = self.condition
-        base, spans = built.table_base, built.table_spans
-        size = built.table.shape[0]
-        plive = pbatch.row_mask()
-        combined = jnp.zeros((pbatch.capacity,), jnp.int64)
-        inrange = plive
-        for i, o in enumerate(probe_keys):
-            c = pbatch.columns[o]
-            v = c.data.astype(jnp.int64)
-            inrange = inrange & c.validity & (v >= base[i]) & \
-                (v < base[i] + spans[i])
-            combined = combined * spans[i] + (v - base[i])
-        idx = jnp.clip(combined, 0, size - 1)
-        pos = jnp.take(built.table, idx, axis=0)
-        found = inrange & (pos >= 0)
+        pos, found, plive = _dense_lookup(built, pbatch, probe_keys)
         if jt in ("semi", "anti") and cond is None:
             keep = found if jt == "semi" else ~found
             return pbatch.with_sel(keep & plive)
@@ -583,16 +642,9 @@ class _JoinKernelMixin:
                                          "anti") and jittable:
                 _maybe_build_dense(built, built.batch, built.key_ordinals)
                 _settle(built.table)
-        from spark_rapids_tpu.memory.oom import retry_on_oom
         if built.table is not None:
-            dense = self._dense_jit_fn()
-            for pbatch in probe_iter:
-                with self._probe_span(pbatch, "dense"):
-                    out = retry_on_oom(
-                        dense, built, pbatch,
-                        probe_keys=tuple(probe_keys),
-                        build_is_right=build_is_right)
-                yield out
+            yield from self._dense_stream(ctx, built, probe_iter,
+                                          tuple(probe_keys), build_is_right)
             return
         fast = mr is not None and 0 < mr <= self._FAST_PATH_MAX_RUN
         for pbatch in probe_iter:
@@ -609,6 +661,106 @@ class _JoinKernelMixin:
             yield self._null_extend_build(
                 built, build_unmatched, self._probe_schema_batch(),
                 build_is_right)
+
+    def _dense_stream(self, ctx, built: BuiltSide, probe_iter,
+                      probe_keys: Tuple[int, ...], build_is_right: bool):
+        """The direct-address probe over a probe stream. One algorithm
+        whose gather site follows what it observes.
+
+        An inner join without a condition is the one shape in which a
+        probe row can vanish with no build column needed to decide it, and
+        there the build side's rows are gathered LATE: a window of probe
+        batches (the group the consumer's ``coalesce_iter`` would make of
+        their outputs) has its lookups dispatched, its match counts pulled
+        in ONE ``device_get`` — the pull the consumer pays today, which it
+        now finds as ``rows_hint`` — and then its emits: at the count's
+        capacity bucket where the consumer's own rule would have compacted
+        the output (``bucket * PROBE_SHRINK_RATIO <= capacity``), else at
+        the probe's capacity under a selection vector. A 0.5 %-match probe
+        of 1,048,576 rows thus gathers 6,144 build rows, not 1,048,576 of
+        which ``_shrink`` drops all but those (20.7 of 36.7 ms a batch on
+        a v5e: PERF.md, PR 37).
+
+        Everything else takes ``_dense_step``'s single program as ever
+        (``joinEagerBatches``): outer and semi/anti joins, a condition; a
+        batch whose output is too small for any consumer to count
+        (``MIN_SHRINK_BYTES``); and a join whose last window compacted
+        nothing — there the count buys nothing, and a consumer that never
+        shrinks (an Expand, a repartitioning exchange) would pay a pull a
+        window for it, so the join stops asking for the rest of the
+        query."""
+        from spark_rapids_tpu import config as C
+        from spark_rapids_tpu.columnar.batch import (
+            MIN_SHRINK_BYTES, PROBE_SHRINK_RATIO, group_by_goal)
+        from spark_rapids_tpu.memory.oom import (effective_batch_target,
+                                                 retry_on_oom)
+        dense = self._dense_jit_fn()
+
+        def eager(pbatch):
+            monitoring.count("joinEagerBatches")
+            with self._probe_span(pbatch, "dense"):
+                return retry_on_oom(dense, built, pbatch,
+                                    probe_keys=probe_keys,
+                                    build_is_right=build_is_right)
+
+        if self.join_type != "inner" or self.condition is not None:
+            for pbatch in probe_iter:
+                yield eager(pbatch)
+            return
+        lookup, emit = _late_jit_fns()
+        build_row_bytes = -(-built.batch.device_size_bytes()
+                            // max(built.batch.capacity, 1))
+        quiet_key = f"join-late-quiet:{id(self):x}"
+
+        def out_bytes(pbatch):
+            return (pbatch.device_size_bytes()
+                    + pbatch.capacity * build_row_bytes)
+
+        for window in group_by_goal(
+                probe_iter,
+                effective_batch_target(int(ctx.conf.get(C.BATCH_SIZE_ROWS))),
+                int(ctx.conf.get(C.BATCH_SIZE_BYTES)), out_bytes):
+            quiet = ctx.cache.get(quiet_key)
+            late = [not quiet and out_bytes(pbatch) > MIN_SHRINK_BYTES
+                    for pbatch in window]
+            if not any(late):
+                for pbatch in window:
+                    yield eager(pbatch)
+                continue
+            outs: List = []
+            for pbatch, ask in zip(window, late):
+                if not ask:
+                    outs.append(eager(pbatch))
+                    continue
+                with self._probe_span(pbatch, "late-lookup"):
+                    outs.append(retry_on_oom(lookup, built, pbatch,
+                                             probe_keys=probe_keys))
+            asked = [i for i, ask in enumerate(late) if ask]
+            monitoring.count("joinLateWindows")
+            with monitoring.span(
+                    "counts", "join-probe",
+                    args={"op": self.name, "path": "late-counts",
+                          "batches": len(asked)}
+                    if monitoring.enabled() else None):
+                counts = jax.device_get([outs[i][2] for i in asked])
+            compacted = False
+            for i, c in zip(asked, counts):
+                pbatch, (pos, found, count) = window[i], outs[i]
+                cap = bucket_capacity(max(int(c), 1))
+                if cap * PROBE_SHRINK_RATIO > pbatch.capacity:
+                    cap = None
+                compacted |= cap is not None
+                monitoring.count("joinLateEmitCapacity" if cap is None
+                                 else "joinLateEmitBucket")
+                with self._probe_span(pbatch, "late-emit"):
+                    out = retry_on_oom(emit, built.batch, pbatch, pos,
+                                       found, count, out_cap=cap,
+                                       build_is_right=build_is_right)
+                out.rows_hint = int(c)
+                outs[i] = out
+            if not compacted:
+                ctx.cache[quiet_key] = True
+            yield from outs
 
     def _probe_batch(self, built: BuiltSide, pbatch: DeviceBatch,
                      fast_run: Optional[int], jittable: bool,

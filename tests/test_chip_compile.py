@@ -192,6 +192,42 @@ def test_join_probe(cap, one_chip):
              built, probe)
 
 
+@pytest.mark.parametrize("cap,build_cap", [(1 << 20, 1 << 21),
+                                           (3 << 18, 3 << 18)],
+                         ids=["sf10-q3", "sf1-q3"])
+def test_late_dense_probe(cap, build_cap, one_chip):
+    """The late dense probe's two programs (PR 37) at q3's shapes: the
+    lookup over a selection-vector lineitem batch against a 2^24-slot
+    table, and the emit at the count's bucket (0.5 % match: 6,144 rows)
+    and at the probe's capacity, out of a nine-word build side."""
+    import dataclasses
+    from spark_rapids_tpu.columnar.batch import DeviceBatch, DeviceColumn
+    from spark_rapids_tpu.ops import join
+    ones = np.ones((build_cap,), np.bool_)
+    kinds = (dt.INT64, dt.DATE, dt.INT32, dt.INT64, dt.INT64)
+    build = DeviceBatch(tuple(
+        DeviceColumn(t, np.zeros((build_cap,), t.np_dtype), ones)
+        for t in kinds), np.asarray(build_cap, np.int32))
+    built = dataclasses.replace(
+        jax.eval_shape(lambda b: join.build_side(b, [0]), build),
+        table=np.zeros((1 << 24,), np.int32),
+        table_base=np.zeros((1,), np.int64),
+        table_spans=np.ones((1,), np.int64))
+    probe = _lineitem_like(cap)
+    probe = DeviceBatch(probe.columns, probe.num_rows,
+                        sel=np.ones((cap,), np.bool_))
+    pos, found, count = jax.eval_shape(
+        lambda bs, p: join._late_lookup(bs, p, (3,)), built, probe)
+    _compile(lambda bs, p: join._late_lookup(bs, p, (3,)), one_chip,
+             built, probe)
+    for out_cap in (6_144, None):
+        hlo = _compile(
+            lambda b, p, at, f, n: join._late_emit(b, p, at, f, n, out_cap,
+                                                   True),
+            one_chip, built.batch, probe, pos, found, count).as_text()
+        assert ("scatter" in hlo) == (out_cap is not None)
+
+
 # -- packed row movers (columnar/rowmove.py) ------------------------------------
 
 MESH_SHARD = 3 << 19        # TPC-H SF1 q5's big mesh shards: 1,572,864 rows

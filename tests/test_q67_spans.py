@@ -19,7 +19,8 @@ from spark_rapids_tpu.monitoring import recorder
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 COUNTERS = ("expandRowsIn", "expandRowsOut", "expandProjections",
             "aggUpdateRows", "aggConsolidateLevels", "windowRowsIn",
-            "windowBatches", "joinBuildRows", "exchangeRows")  # PR 35's two
+            "windowBatches", "joinBuildRows", "exchangeRows",  # PR 35's two
+            "joinEagerBatches")     # PR 37: tiny batches take one program
 
 
 def _tpcds():

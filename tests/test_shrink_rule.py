@@ -243,6 +243,61 @@ def test_tpch_equals_pandas_with_the_rule_engaged(
     assert B.counters()[kept] > 0, B.counters()
 
 
+def test_q3_probes_late_and_the_aggregate_pulls_nothing(
+        data_dir, rule_engages_at_small_sizes):
+    """PR 37: q3's inner joins over direct-address tables gather the
+    build side's rows after the compaction. The answer is the single
+    program's to the bit; the aggregate's ``coalesce_iter`` finds the
+    counts on the batches and pulls nothing; and the only reads the
+    query gained are the joins' own count pulls, one a window."""
+    from spark_rapids_tpu.monitoring import syncs
+    from spark_rapids_tpu.ops import join as J
+
+    def run(late):
+        real = J._JoinKernelMixin._dense_stream
+        if not late:
+            # the parent's loop: every batch through the single program
+            def eager_only(self, ctx, built, probe_iter, keys, right):
+                dense = self._dense_jit_fn()
+                for p in probe_iter:
+                    yield dense(built, p, probe_keys=keys,
+                                build_is_right=right)
+            J._JoinKernelMixin._dense_stream = eager_only
+        syncs.install()
+        recorder.reset()
+        recorder.reset_counters()
+        try:
+            s = TpuSession()
+            s.set("spark.rapids.sql.variableFloatAgg.enabled", True)
+            s.set("spark.rapids.sql.trace.enabled", True)
+            s.set("spark.rapids.sql.trace.level", "kernel")
+            got = tpch.QUERIES["q3"](s, data_dir).collect()
+            return (got, recorder.counters(),
+                    {k: n for k, (n, _) in syncs.sync_stats().items()})
+        finally:
+            J._JoinKernelMixin._dense_stream = real
+            recorder.configure(False)
+            recorder.reset()
+            recorder.reset_counters()
+
+    def owned(stats, owner):
+        return sum(n for k, n in stats.items() if k.endswith(owner))
+
+    want = tpch.pandas_query("q3", data_dir)
+    run(late=True)      # a process's first collect reads more (calibration)
+    got, counts, late = run(late=True)
+    assert tpch.check_result("q3", got, want), (got, want)
+    assert counts.get("joinLateEmitBucket", 0) > 0, counts
+    eager_rows, _, eager = run(late=False)
+    assert eager_rows == got
+    agg = "HashAggregateExec:shrink-all"
+    assert owned(eager, agg) > 0 and owned(late, agg) == 0, (eager, late)
+    pulls = owned(late, "BroadcastHashJoinExec:counts")
+    assert pulls == counts["joinLateWindows"]
+    assert sum(late.values()) - pulls == \
+        sum(eager.values()) - owned(eager, agg), (late, eager)
+
+
 PROBE_ROWS = 200
 BUILD = {"b": [k for k in range(0, 260, 2) for _ in (0, 1)],
          "w": list(range(260))}        # every build key twice: not dense

@@ -183,6 +183,57 @@ class TestJoins:
         # (other tests' joins of this shape may have traced it before)
         assert traces[0] >= 1 and traces[1:] == traces[:1] * 2
 
+    @pytest.mark.parametrize("join", [BroadcastHashJoinExec,
+                                      ShuffledHashJoinExec],
+                             ids=["broadcast", "shuffled"])
+    @pytest.mark.parametrize("match_every", [1, 3, 40])
+    def test_inner_dense_join_gathers_late(self, join, match_every,
+                                           monkeypatch):
+        """PR 37: an inner join over a direct-address table looks up,
+        pulls its window's match counts and emits at their buckets (or,
+        where nearly every row matches, at the probe's capacity): the
+        rows are the host engine's either way, through the join's own
+        input coalescing, several batches a partition, NULL and absent
+        keys and a string payload."""
+        from spark_rapids_tpu.columnar import batch as B
+        from spark_rapids_tpu.monitoring import recorder
+        monkeypatch.setattr(B, "MIN_SHRINK_BYTES", 0)
+        monkeypatch.setattr(B, "COALESCE_ALONE_ROWS", 64)
+        n = 600
+        left = source(
+            [("o_key", dt.INT32), ("o_cust", dt.INT64),
+             ("o_note", dt.STRING)],
+            {"o_key": list(range(n)),
+             "o_cust": [None if i % 11 == 0 else
+                        (i % 97 if i % match_every == 0 else 5_000 + i)
+                        for i in range(n)],
+             "o_note": [f"n{i % 13}" for i in range(n)]},
+            num_partitions=2, batches_per_partition=3)
+        right = source([("c_key", dt.INT64), ("c_name", dt.STRING)],
+                       {"c_key": list(range(97)),
+                        "c_name": [f"c{i}" for i in range(97)]})
+        if join is ShuffledHashJoinExec:     # co-partition both sides
+            left = ShuffleExchangeExec(
+                left, HashPartitioning([Ref(1, dt.INT64)], 2))
+            right = ShuffleExchangeExec(
+                right, HashPartitioning([Ref(0, dt.INT64)], 2))
+        plan = join(left, right, [Ref(1, dt.INT64)], [Ref(0, dt.INT64)],
+                    "inner")
+        monkeypatch.setenv("SRT_TRACE", "1")    # every collect adopts it
+        recorder.reset_counters()
+        try:
+            dev = compare_engines(plan, sort_result=True)
+            counts = recorder.counters()
+        finally:
+            recorder.configure(False)
+            recorder.reset_counters()
+        assert len(dev) == sum(1 for i in range(n)
+                               if i % 11 and i % match_every == 0)
+        bucket = counts.get("joinLateEmitBucket", 0)
+        capacity = counts.get("joinLateEmitCapacity", 0)
+        assert counts["joinLateWindows"] >= 1 and bucket + capacity >= 1
+        assert (bucket > 0) == (match_every > 1), counts
+
     def test_inner_shuffled(self):
         # Co-partition both sides by key first.
         left, right = join_sources()
