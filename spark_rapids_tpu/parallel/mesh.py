@@ -214,10 +214,37 @@ def distributed_join_agg_step(mesh: Mesh, join_exec, agg_exec,
     return jax.jit(sharded)
 
 
+def _lead_axis(tree):
+    return tree_map(lambda x: x[None], tree)
+
+
 def shard_batches(mesh: Mesh, per_device: List[DeviceBatch],
                   axis: str = DATA_AXIS) -> DeviceBatch:
-    """Assemble per-device shards into one globally-sharded DeviceBatch
-    (leaves get a leading device axis mapped onto the mesh)."""
-    stacked = tree_map(lambda *xs: jnp.stack(xs), *per_device)
+    """Assemble per-device shards into one globally-sharded DeviceBatch:
+    every leaf gets a leading device axis mapped onto the mesh, row i
+    being ``per_device[i]`` (one shard per mesh device).
+
+    Whole trees, not leaf by leaf: one cached program a shard gives its
+    leaves their leading axis of 1, ONE batched ``device_put`` sends the
+    n trees to the devices that hold rows 0..n-1, and each global leaf is
+    assembled from its n single-device pieces on the host
+    (``make_array_from_single_device_arrays``: no device work)."""
+    from spark_rapids_tpu.ops import kernel_cache as kc
+    n = len(per_device)
+    if n != mesh.devices.size:
+        raise ValueError(f"{n} shards for a mesh of {mesh.devices.size}")
     sharding = NamedSharding(mesh, P(axis))
-    return tree_map(lambda x: jax.device_put(x, sharding), stacked)
+    lead = kc.lookup("mesh-lead", (), lambda: jax.jit(_lead_axis))
+    rows = [tree_flatten(lead(b)) for b in per_device]
+    treedef = rows[0][1]
+    first = rows[0][0]
+    # the device of row i, as the sharding lays the leading axis out
+    devices = [None] * n
+    for d, idx in sharding.addressable_devices_indices_map(
+            (n,) + first[0].shape[1:]).items():
+        devices[idx[0].start or 0] = d
+    placed = jax.device_put([leaves for leaves, _ in rows], devices)
+    return tree_unflatten(treedef, [
+        jax.make_array_from_single_device_arrays(
+            (n,) + x.shape[1:], sharding, [p[j] for p in placed])
+        for j, x in enumerate(first)])

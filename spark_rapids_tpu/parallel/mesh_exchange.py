@@ -11,6 +11,10 @@ GpuShuffleExchangeExec.scala:69,145), and each output partition serves its
 device's post-exchange shard to the normal per-partition operator stream
 above. Operators (aggregate final stage, shuffled join) compose unchanged.
 
+Shards go out and come back as whole trees: ``mesh.shard_batches`` hands
+the n shards to their devices in one batched ``device_put`` and
+``_addressable_parts`` lands them in another; the programs either phase
+dispatches follow the shards, never the leaves.
 Today every post-exchange shard is moved to ``jax.devices()[0]``
 (``_addressable_parts``) and every operator above an exchange runs there:
 the other chips take part in the collectives alone (``meshLandedBytes``
@@ -23,8 +27,9 @@ on the virtual CPU devices they ask for (``tests/conftest.py``).
 What one exchange costs is visible as spans of the category
 ``mesh-exchange`` (``shard``, ``pids``, ``counts``, ``collective``,
 ``land``, ``unfold``; docs/observability.md) and as the counters
-``meshLiveBytes`` / ``meshWireBytes`` / ``meshLandedBytes.dev<i>``, on the
-operator's ``Metrics`` and process-wide (:func:`counters`).
+``meshLiveBytes`` / ``meshWireBytes`` / ``meshLandedBytes.dev<i>`` and
+``meshHandoutPuts`` / ``meshLandPuts``, on the operator's ``Metrics`` and
+process-wide (:func:`counters`).
 """
 
 from __future__ import annotations
@@ -92,9 +97,10 @@ def _add_counters(counts: Dict[str, int]) -> None:
 
 
 def counters() -> Dict[str, int]:
-    """``meshExchanges``, ``meshLiveBytes``, ``meshWireBytes`` and
-    ``meshLandedBytes.dev<id>`` of this process. Reads what the exchanges
-    since the last call left on the device."""
+    """``meshExchanges``, ``meshHandoutPuts``, ``meshLandPuts``,
+    ``meshLiveBytes``, ``meshWireBytes`` and ``meshLandedBytes.dev<id>``
+    of this process. Reads what the exchanges since the last call left on
+    the device."""
     while _UNREAD:
         try:
             read = _UNREAD.popleft()
@@ -174,6 +180,16 @@ def _count_exchange(m, landed_rows, landed_on: List[int], row_width: int,
     _UNREAD.append(unread)
 
 
+def _count_put(m, name: str) -> None:
+    """One batched transfer of a phase (``meshHandoutPuts``: the
+    ``device_put`` of ``shard_batches``; ``meshLandPuts``: that of
+    ``_addressable_parts``), on the operator and process-wide. Each phase
+    issues one an exchange, whatever its leaves: the ratio of either to
+    ``meshExchanges`` is 1 where the whole-tree hand-out and landing ran."""
+    m.add(name, 1)
+    _add_counters({name: 1})
+
+
 def _phase(name: str):
     """A span of the category ``mesh-exchange`` (profiler annotation
     ``mesh-exchange:<name>``). The phases are never nested in one another
@@ -187,6 +203,7 @@ def _uniform_shards(batches_per_dev: List[List[DeviceBatch]],
                     schema: Schema) -> List[DeviceBatch]:
     """Coalesce each device's batches and pad all shards to one common
     capacity + per-column string width (shard_map needs uniform shapes)."""
+    from spark_rapids_tpu.ops import kernel_cache as kc
     from spark_rapids_tpu.ops.sort import coalesce_to_single_batch
     from spark_rapids_tpu.columnar.rowmove import compact_batch
     shards = []
@@ -197,7 +214,8 @@ def _uniform_shards(batches_per_dev: List[List[DeviceBatch]],
                 # A lone filtered batch passes through coalesce with its
                 # selection vector; shard_map shards are sel-less, so
                 # materialize the live rows first.
-                single = jax.jit(compact_batch)(single)
+                single = kc.lookup("mesh-compact", (), lambda: jax.jit(
+                    compact_batch))(single)
             shards.append(single)
         else:
             shards.append(None)
@@ -211,57 +229,99 @@ def _uniform_shards(batches_per_dev: List[List[DeviceBatch]],
             widths.append(max(ws) if ws else 8)
         else:
             widths.append(None)
+    widths = tuple(widths)
     out = []
     for s in shards:
         if s is None:
-            cols = tuple(
-                DeviceColumn.full_null(t, cap, widths[ci] or 8)
-                for ci, (_, t) in enumerate(schema))
-            out.append(DeviceBatch(cols, jnp.asarray(0, jnp.int32)))
-            continue
-        cols = []
-        for ci, c in enumerate(s.columns):
-            if c.dtype.is_string and c.string_width != widths[ci]:
-                c = string_repad(c, widths[ci])
-            cols.append(c)
-        s = DeviceBatch(tuple(cols), s.num_rows)
-        if s.capacity != cap:
-            # jitted: eagerly a packed gather is ~40 one-op programs
-            s = _pad_shard(s, cap)
-        out.append(s)
+            out.append(_empty_shard(schema, cap, widths))
+        elif s.capacity != cap or any(
+                w is not None and c.string_width != w
+                for c, w in zip(s.columns, widths)):
+            out.append(_fit_shard(s, cap, widths))
+        else:
+            out.append(s)
     return out
+
+
+def _empty_shard(schema: Schema, capacity: int, widths) -> DeviceBatch:
+    """A device's shard when no child partition was dealt to it: every
+    column null, no row; ONE cached program, not a ``zeros`` per leaf."""
+    from spark_rapids_tpu.ops import kernel_cache as kc
+
+    def empty():
+        return DeviceBatch(tuple(
+            DeviceColumn.full_null(t, capacity, w or 8)
+            for (_, t), w in zip(schema, widths)),
+            jnp.asarray(0, jnp.int32))
+
+    return kc.lookup("mesh-empty", (kc.schema_fingerprint(schema), capacity,
+                                    widths), lambda: jax.jit(empty))()
+
+
+def _fit_shard(shard: DeviceBatch, capacity: int, widths) -> DeviceBatch:
+    """A dense shard at the exchange's common ``capacity`` and string
+    ``widths`` (``_uniform_shards``): ONE program per capacity and
+    widths. Run op by op, the re-pad of each string column and the pack,
+    gather and unpack of columnar/rowmove.py are some forty one-op
+    programs, each compiled anew in every process and dispatched one by
+    one in every query."""
+    from spark_rapids_tpu.ops import kernel_cache as kc
+
+    def fit(b):
+        b = DeviceBatch(tuple(c if w is None else string_repad(c, w)
+                              for c, w in zip(b.columns, widths)),
+                        b.num_rows)
+        if b.capacity == capacity:
+            return b
+        return b.gather(jnp.arange(capacity, dtype=jnp.int32), b.num_rows)
+
+    return kc.lookup("mesh-fit", (capacity, widths),
+                     lambda: jax.jit(fit))(shard)
+
+
+def _drop_lead(offsets):
+    """The landing's one program: row ``offsets[i][j]`` of leaf j of part
+    i, that is its leading axis dropped (0 for a leaf sharded one row a
+    device; i for a replicated leaf, whose every shard holds all rows)."""
+    def land(parts):
+        return [[x[o] for x, o in zip(p, offs)]
+                for p, offs in zip(parts, offsets)]
+    return jax.jit(land)
 
 
 def _addressable_parts(out, n: int):
     """Device i's post-exchange shard as an ordinary per-device batch.
 
-    Extracts each leaf's per-device shard via ``addressable_shards``
-    (device-local data, one tiny local slice per leaf) instead of ``x[i]``
+    Takes each leaf's shards from ``addressable_shards`` as they are
+    (device-local data, leading size 1, no indexing) instead of ``x[i]``
     gathers on the global sharded array — a cross-device lazy gather that
     XLA re-dispatches whenever a consumer (including the range-bounds
     sampling pass re-executing this tree) touches it, and the trigger of
     the r4 SIGABRT inside apply_primitive.
 
     The downstream operator stream is single-process and mixes partitions
-    freely (concat across buckets), so every shard is eagerly
-    ``device_put`` onto the default device — an explicit transfer now, not
-    a lazy gather later."""
+    freely (concat across buckets), so every shard is ``device_put`` onto
+    the default device — an explicit transfer now, not a lazy gather
+    later: ONE batched transfer for every leaf of every part, then ONE
+    cached program drops the leading axis of them all."""
+    from spark_rapids_tpu.ops import kernel_cache as kc
     leaves, treedef = tree_flatten(out)
     per_dev = [[] for _ in range(n)]
+    offsets = [[] for _ in range(n)]
     for leaf in leaves:
-        by_row = {}
-        for s in leaf.addressable_shards:
-            row = s.index[0].start or 0 if s.index else 0
-            by_row[row] = s.data
+        shards = leaf.addressable_shards
         for i in range(n):
-            if i in by_row:
-                per_dev[i].append(by_row[i][0])
-            else:       # replicated / unsharded leaf: plain slice is local
-                per_dev[i].append(leaf[i])
-    # ONE batched transfer for every shard of every partition (device_put
-    # takes pytrees) — not a put per leaf per device.
+            # the shard whose leading slice holds row i (every shard of a
+            # replicated leaf does: its row i is at offset i)
+            s = next(s for s in shards
+                     if (s.index[0].start or 0) <= i
+                     < (s.index[0].stop or leaf.shape[0]))
+            per_dev[i].append(s.data)
+            offsets[i].append(i - (s.index[0].start or 0))
     per_dev = jax.device_put(per_dev, jax.devices()[0])
-    return [tree_unflatten(treedef, ls) for ls in per_dev]
+    offsets = tuple(map(tuple, offsets))
+    land = kc.lookup("mesh-land", (offsets,), lambda: _drop_lead(offsets))
+    return [tree_unflatten(treedef, ls) for ls in land(per_dev)]
 
 
 class MeshExchangeExec(Exec):
@@ -411,6 +471,7 @@ class MeshExchangeExec(Exec):
                 with _phase("shard"):
                     shards = _uniform_shards(per_dev, self.schema)
                     stacked = M.shard_batches(mesh, shards)
+                _count_put(m, "meshHandoutPuts")
                 # Two-phase sizes-then-data (SURVEY §7 hard part 6):
                 # exchange per-destination COUNTS first (a (n,n) int32
                 # collective + one host pull), size the data collective's
@@ -456,6 +517,7 @@ class MeshExchangeExec(Exec):
                      tree_flatten(out)[0][0].addressable_shards}))
                 with _phase("land"):
                     parts = _addressable_parts(out, n)
+                _count_put(m, "meshLandPuts")
                 if landed_rows is None:     # no counts pulled: deferred
                     landed_rows = [p.num_rows for p in parts]
                 row_width = _row_width(shards[0])
@@ -548,13 +610,3 @@ class MeshExchangeExec(Exec):
             ctx.cache[key] = buckets
         yield from iter(ctx.cache[key][partition])
 
-
-def _pad_shard(shard: DeviceBatch, capacity: int) -> DeviceBatch:
-    """A dense shard at a larger ``capacity`` (``_uniform_shards``): ONE
-    program per capacity. Run op by op, the pack, gather and unpack of
-    columnar/rowmove.py are some forty one-op programs, each compiled anew
-    in every process and dispatched one by one in every query."""
-    from spark_rapids_tpu.ops import kernel_cache as kc
-    return kc.lookup("mesh-pad", (capacity,), lambda: jax.jit(
-        lambda b: b.gather(jnp.arange(capacity, dtype=jnp.int32),
-                           b.num_rows)))(shard)

@@ -344,6 +344,19 @@ def test_process_counters_are_the_operators_totals(cell):
     assert second["meshLiveBytes"] == second["meshLandedBytes.dev0"]
 
 
+@pytest.mark.parametrize("branch", ["skipped", "two_phase"])
+def test_one_batched_put_a_side_an_exchange(cell, branch):
+    for v in cell[branch]["exchanges"]:
+        assert v["meshHandoutPuts"] == v["meshLandPuts"] == \
+            v["meshExchanges"] == 1
+    first, second = cell["skipped"]["process"], cell["two_phase"]["process"]
+    assert first["meshHandoutPuts"] == first["meshLandPuts"] == \
+        first["meshExchanges"] == 2 * 11
+    # ``uncounted`` stubs out ``_count_exchange`` (``meshExchanges``) alone
+    assert second["meshHandoutPuts"] == second["meshLandPuts"] == \
+        second["meshExchanges"] + 11
+
+
 def test_counters_add_no_blocking_read(cell):
     def reads(events):
         return sum(e[2] == "sync" for e in _spans(events))
