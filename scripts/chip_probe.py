@@ -2,10 +2,11 @@
 
     python scripts/chip_probe.py [sync] [link] [upload] [prefix] [slots] [rowmove]
                                  [shrinkrule] [sortpath] [sortgrid] [coalescealone]
-                                 [lategather] [--rows N,N,...]
+                                 [lategather] [meshrounds] [--rows N,N,...]
 
-With no section named it runs all eleven. One process, one chip, one JSON
-object per line, every reading on the host's clock around a
+With no section named it runs all twelve. One process, one chip (four
+for ``meshrounds``), one JSON object per line, every reading on the
+host's clock around a
 ``block_until_ready`` (on an attached chip that waits for completion):
 
 - ``sync``   one blocking host read of a device scalar — what
@@ -121,6 +122,22 @@ object per line, every reading on the host's clock around a
              names the one ``_dense_stream``'s rule takes. The forms'
              outputs are compared on the way.
 
+- ``meshrounds`` how big a round of the mesh exchange should be
+             (``mesh_exchange.MESH_ROUND_ROWS``): TPC-H Q5's lineitem at
+             SF10 as the exchange is dealt it (eight partitions of seven
+             1,048,576-row scan units and one of 196,608 rows of capacity,
+             ~158,000 live; l_orderkey, l_suppkey, l_extendedprice,
+             l_discount, resident on the first device), hash-exchanged on
+             l_orderkey over a mesh of every device there is by
+             ``MeshExchangeExec`` at bounds of 1,048,576 (a round of one
+             unit; the tail unit goes alone), 1,572,864 (one unit, the
+             tail with the unit before it), 2,097,152 (two units
+             concatenated) and 16,777,216 (the whole share in one round,
+             as before rounds); ``--rows`` picks the bounds. Each exchange
+             is timed whole, from the deal to every landed shard ready,
+             with the rounds, the largest round and the landed capacity.
+             Run it on four chips: on one the collective moves nothing.
+
 Like ``chip_smoke.py`` it refuses any backend but a TPU unless
 ``--cpu-rehearsal`` is given, which runs the control flow at a tiny size
 on the CPU and says so on every line: a CPU reading is not a device number.
@@ -139,7 +156,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 SECTIONS = ("sync", "link", "upload", "prefix", "slots", "rowmove",
             "shrinkrule", "sortpath", "sortgrid", "coalescealone",
-            "lategather")
+            "lategather", "meshrounds")
 SORTPATH_ROWS = (98304, 262144, 393216, 524288, 786432, 1048576, 1572864)
 COALESCE_ROWS = (262144, 524288, 786432, 1048576)
 LABEL = {}
@@ -1092,12 +1109,90 @@ def probe_lategather(jax, small: bool) -> None:
         del built
 
 
+def probe_meshrounds(jax, small: bool) -> None:
+    import numpy as np
+    from spark_rapids_tpu.columnar import dtypes as dt
+    from spark_rapids_tpu.columnar.host import (HostBatch, HostColumn,
+                                                host_to_device)
+    from spark_rapids_tpu.exprs.base import BoundReference
+    from spark_rapids_tpu.memory.stores import PRIORITY_SHUFFLE_OUTPUT
+    from spark_rapids_tpu.ops.base import Exec, ExecContext
+    from spark_rapids_tpu.parallel import mesh_exchange as MX
+    from spark_rapids_tpu.parallel.partitioning import HashPartitioning
+    n = len(jax.devices())
+    unit = 1 << (10 if small else 20)
+    tail_cap, tail_rows = 3 * unit // 16, 158 * unit // 1024
+    lineitem = (("l_orderkey", dt.INT64), ("l_suppkey", dt.INT64),
+                ("l_extendedprice", dt.FLOAT64), ("l_discount", dt.FLOAT64))
+    rng = np.random.default_rng(43)
+
+    def batch(rows, cap):
+        cols = [rng.integers(1, 60_000_000, rows, dtype=np.int64),
+                rng.integers(1, 100_000, rows, dtype=np.int64),
+                rng.uniform(900.0, 105_000.0, rows),
+                np.round(rng.integers(0, 11, rows) / 100.0, 2)]
+        hb = HostBatch(tuple(name for name, _ in lineitem),
+                       [HostColumn(t, c, np.ones(rows, bool))
+                        for (_, t), c in zip(lineitem, cols)])
+        return host_to_device(hb, capacity=cap)
+
+    parts = [[batch(unit, unit) for _ in range(7)]
+             + [batch(tail_rows, tail_cap)] for _ in range(2 * n)]
+
+    class Dealt(Exec):
+        schema = lineitem
+
+        def num_partitions(self, ctx):
+            return len(parts)
+
+        def execute_device(self, ctx, partition):
+            yield from parts[partition]
+
+    held = MX.MESH_ROUND_ROWS
+    reps = 2 if small else 5
+    try:
+        bounds = (unit, 3 * unit // 2, 2 * unit, 16 * unit)
+        for bound in (OPTIONS["rows"] if OPTIONS["rows"] and not small
+                      else bounds):
+            MX.MESH_ROUND_ROWS = bound
+            seen = {}
+
+            def once():
+                before = MX.counters()
+                ctx = ExecContext()
+                ex = MX.MeshExchangeExec(Dealt(), HashPartitioning(
+                    [BoundReference(0, dt.INT64)], n))
+                sess = ex._materialize(ctx)
+                landed = [h.get() for p in range(n)
+                          for h in sess.fetch_shards(p)]
+                jax.block_until_ready(landed)
+                after = MX.counters()
+                seen.update(
+                    rounds=after["meshRounds"] - before.get("meshRounds", 0),
+                    landed_capacity=sum(b.capacity for b in landed))
+                for p in range(n):
+                    for h in sess.fetch_shards(p):
+                        h.release(PRIORITY_SHUFFLE_OUTPUT)
+                sess.invalidate()
+                return []
+
+            first = timed(once, 1)[0]
+            emit("meshrounds", bound=bound, devices=n, unit=unit,
+                 rounds=seen["rounds"],
+                 largest_round=MX.counters()["meshRoundRowsMax"],
+                 landed_capacity=seen["landed_capacity"],
+                 first_s=first, exchange_ms=ms(timed(once, reps)))
+            MX.reset_counters()
+    finally:
+        MX.MESH_ROUND_ROWS = held
+
+
 PROBES = {"sync": probe_sync, "link": probe_link, "upload": probe_upload,
           "prefix": probe_prefix, "slots": probe_slots,
           "rowmove": probe_rowmove, "shrinkrule": probe_shrinkrule,
           "sortpath": probe_sortpath, "sortgrid": probe_sortgrid,
           "coalescealone": probe_coalescealone,
-          "lategather": probe_lategather}
+          "lategather": probe_lategather, "meshrounds": probe_meshrounds}
 
 
 def main(argv=None) -> int:
@@ -1110,7 +1205,8 @@ def main(argv=None) -> int:
                     help="sortpath: the sizes to read, of "
                          f"{','.join(map(str, SORTPATH_ROWS))}; "
                          "coalescealone: the members' capacities "
-                         f"(default {','.join(map(str, COALESCE_ROWS))})")
+                         f"(default {','.join(map(str, COALESCE_ROWS))}); "
+                         "meshrounds: the round bounds")
     ap.add_argument("--cpu-rehearsal", action="store_true",
                     help="control flow at a tiny size on the CPU backend")
     args = ap.parse_args(argv)

@@ -24,9 +24,15 @@ where its shard lies).
 A single chip degenerates to n=1; the tests run the real collective path
 on the virtual CPU devices they ask for (``tests/conftest.py``).
 
+A device's share goes over in rounds (``MESH_ROUND_ROWS``): a child whose
+partitions are many batches (SF10's scan units) is exchanged as shards of
+a bounded size, each round landing one more batch of every partition,
+never as one concatenation of the whole share.
+
 What one exchange costs is visible as spans of the category
 ``mesh-exchange`` (``shard``, ``pids``, ``counts``, ``collective``,
-``land``, ``unfold``; docs/observability.md) and as the counters
+``land``, ``unfold``, each with its ``round``; docs/observability.md) and
+as the counters ``meshRounds`` / ``meshRoundRowsMax``,
 ``meshLiveBytes`` / ``meshWireBytes`` / ``meshLandedBytes.dev<i>`` and
 ``meshHandoutPuts`` / ``meshLandPuts``, on the operator's ``Metrics`` and
 process-wide (:func:`counters`).
@@ -74,6 +80,22 @@ def mesh_size() -> int:
 # Module-level so tests can lower it.
 TWO_PHASE_MIN_SHARD_ROWS = 1 << 18
 
+# The most rows of capacity one round of an exchange gives a device's
+# shard (``_rounds``): a device's dealt batches go over in rounds of the
+# next batches whose capacities sum to at most this, and a batch larger
+# goes alone. A rung of ``bucket_capacity``'s ladder, so a round's
+# coalesced shard never passes it. At 1,572,864 a device's share of
+# every exchange of TPC-H Q5 at SF1 (two scan batches of 786,432 rows at
+# the most) is one round, as before rounds existed, and at SF10 a round
+# is ONE 1,048,576-row scan unit, the 196,608-row tail with the unit
+# before it. A round of two units concatenates them first, an index pass
+# and a gather over the output. SF10 lineitem's 16 dealt batches on one
+# v5e (``scripts/chip_probe.py meshrounds``, steady ms an exchange):
+# bound 1,048,576 (16 rounds) 812.8, 1,572,864 (14) 888.5, 2,097,152 (8)
+# 1,214.1; the first would split SF1's shares in two. Module-level so
+# tests can lower it.
+MESH_ROUND_ROWS = 3 << 19
+
 
 # -- counters -----------------------------------------------------------------
 # Process-wide totals beside each operator's ``Metrics``
@@ -96,11 +118,18 @@ def _add_counters(counts: Dict[str, int]) -> None:
             _COUNTERS[k] = _COUNTERS.get(k, 0) + v
 
 
+def _raise_counter(name: str, value: int) -> None:
+    """A process counter that keeps the largest value it was shown."""
+    with _COUNTER_LOCK:
+        _COUNTERS[name] = max(_COUNTERS.get(name, 0), value)
+
+
 def counters() -> Dict[str, int]:
-    """``meshExchanges``, ``meshHandoutPuts``, ``meshLandPuts``,
-    ``meshLiveBytes``, ``meshWireBytes`` and ``meshLandedBytes.dev<id>``
-    of this process. Reads what the exchanges since the last call left on
-    the device."""
+    """``meshExchanges``, ``meshRounds``, ``meshRoundRowsMax`` (the
+    largest shard capacity of any round: a maximum, not a total),
+    ``meshHandoutPuts``, ``meshLandPuts``, ``meshLiveBytes``,
+    ``meshWireBytes`` and ``meshLandedBytes.dev<id>`` of this process.
+    Reads what the exchanges since the last call left on the device."""
     while _UNREAD:
         try:
             read = _UNREAD.popleft()
@@ -124,15 +153,15 @@ def _row_width(shard: DeviceBatch) -> int:
                for x in tree_flatten(shard.columns)[0])
 
 
-def _tally(rows, landed_on: List[int], row_width: int,
+def _tally(rows, landed_on: List[int], widths: List[int],
            wire_bytes: int) -> Dict[str, int]:
-    """One exchange's counters from the rows of each landed shard and
-    the device it lies on."""
+    """One exchange's counters from the rows of each landed shard, the
+    device it lies on and the width of its row (its round's)."""
     out = {"meshLiveBytes": 0, "meshWireBytes": wire_bytes}
-    for r, d in zip(rows, landed_on):
-        out["meshLiveBytes"] += int(r) * row_width
+    for r, d, w in zip(rows, landed_on, widths):
+        out["meshLiveBytes"] += int(r) * w
         key = f"meshLandedBytes.dev{d}"
-        out[key] = out.get(key, 0) + int(r) * row_width
+        out[key] = out.get(key, 0) + int(r) * w
     return out
 
 
@@ -160,14 +189,15 @@ class _UnreadExchange:
             metrics.add(k, v)
 
 
-def _count_exchange(m, landed_rows, landed_on: List[int], row_width: int,
-                    wire_bytes: int) -> None:
-    """One exchange into the operator's and the process's counters.
-    ``landed_rows[i]`` are the rows of the shard that landed on device
-    ``landed_on[i]``: ints where the counts matrix was pulled, else
-    device scalars, read when a counter is next read and not before."""
-    tally = functools.partial(_tally, landed_on=landed_on,
-                              row_width=row_width, wire_bytes=wire_bytes)
+def _count_exchange(m, landed_rows, landed_on: List[int],
+                    widths: List[int], wire_bytes: int) -> None:
+    """One exchange, all its rounds, into the operator's and the
+    process's counters. ``landed_rows[i]`` are the rows of the shard
+    that landed on device ``landed_on[i]``, each row ``widths[i]`` bytes
+    (its round's): ints where the counts matrix was pulled, else device
+    scalars, read when a counter is next read and not before."""
+    tally = functools.partial(_tally, landed_on=landed_on, widths=widths,
+                              wire_bytes=wire_bytes)
     _add_counters({"meshExchanges": 1})
     if all(isinstance(r, int) for r in landed_rows):
         counts = tally(landed_rows)
@@ -184,19 +214,42 @@ def _count_put(m, name: str) -> None:
     """One batched transfer of a phase (``meshHandoutPuts``: the
     ``device_put`` of ``shard_batches``; ``meshLandPuts``: that of
     ``_addressable_parts``), on the operator and process-wide. Each phase
-    issues one an exchange, whatever its leaves: the ratio of either to
-    ``meshExchanges`` is 1 where the whole-tree hand-out and landing ran."""
+    issues one a round, whatever its leaves: the ratio of either to
+    ``meshRounds`` is 1 where the whole-tree hand-out and landing ran."""
     m.add(name, 1)
     _add_counters({name: 1})
 
 
-def _phase(name: str):
+def _phase(name: str, k: int):
     """A span of the category ``mesh-exchange`` (profiler annotation
-    ``mesh-exchange:<name>``). The phases are never nested in one another
-    and never enclose the pull of the child, so the category's sum is a
-    time. It is a host clock over asynchronous dispatch: a phase that
-    blocks (``counts``) also holds the wait for the child's device work."""
-    return monitoring.span(name, "mesh-exchange")
+    ``mesh-exchange:<name>``) of round ``k`` (arg ``round``). The phases
+    are never nested in one another, no span of the category encloses a
+    round, and none encloses the pull of the child, so the category's sum
+    is a time. It is a host clock over asynchronous dispatch: a phase
+    that blocks (``counts``) also holds the wait for the child's device
+    work."""
+    return monitoring.span(name, "mesh-exchange", args={"round": k})
+
+
+def _rounds(per_dev: List[List[DeviceBatch]],
+            bound: int) -> List[List[List[DeviceBatch]]]:
+    """Each device's dealt batches cut into rounds: round k holds, of
+    every device, the next batches whose capacities sum to at most
+    ``bound``; a batch larger goes alone, and a device whose batches ran
+    out gives none. At least one round, so an exchange of nothing still
+    writes each partition its empty shard."""
+    cuts = []
+    for blist in per_dev:
+        cut, rows = [], bound
+        for b in blist:
+            if rows + b.capacity > bound:
+                cut.append([])
+                rows = 0
+            cut[-1].append(b)
+            rows += b.capacity
+        cuts.append(cut)
+    k = max(1, max(len(c) for c in cuts))
+    return [[c[i] if i < len(c) else [] for c in cuts] for i in range(k)]
 
 
 def _uniform_shards(batches_per_dev: List[List[DeviceBatch]],
@@ -416,11 +469,17 @@ class MeshExchangeExec(Exec):
 
     def _materialize(self, ctx):
         """Run the collective and register each LOGICAL partition's
-        post-exchange shard as a durable stage output through the mesh
+        post-exchange shards as durable stage outputs through the mesh
         transport session (parallel/transport/mesh.py — spillable
         catalog handles). Returns None after a graceful degrade — the
         caller serves from the single-process fallback exchange
         instead.
+
+        The dealt batches go over in ROUNDS (``_rounds``): each round is
+        one pass of the phases below over shards of at most
+        ``MESH_ROUND_ROWS`` rows, and writes its landed shard as one
+        more batch of each partition, so neither the shards nor the
+        operators above ever hold a device's whole share as one batch.
 
         Partition count != mesh size no longer degrades: logical
         partitions FOLD onto devices (``device = pid // ceil(np/n)``,
@@ -463,105 +522,136 @@ class MeshExchangeExec(Exec):
         for cp in range(child.num_partitions(ctx)):
             for batch in child.execute_device_recovering(ctx, cp):
                 per_dev[cp % n].append(batch)
-        from spark_rapids_tpu import config as C
-        with timed(m, "shuffleTime"):
-            try:
-                from spark_rapids_tpu import faults
-                faults.fault_point("mesh.exchange", owner=id(self))
-                with _phase("shard"):
-                    shards = _uniform_shards(per_dev, self.schema)
-                    stacked = M.shard_batches(mesh, shards)
-                _count_put(m, "meshHandoutPuts")
-                # Two-phase sizes-then-data (SURVEY §7 hard part 6):
-                # exchange per-destination COUNTS first (a (n,n) int32
-                # collective + one host pull), size the data collective's
-                # static piece capacity to the observed max instead of
-                # the worst case — the default padding is an n-fold wire
-                # inflation at scale. n == 1 skips the phase: the
-                # collective moves nothing, so the counts sync could
-                # only cost.
-                from spark_rapids_tpu.ops import kernel_cache as kc
-                mkey = self._mesh_key(mesh)
-                with _phase("pids"):
-                    pids_fn = kc.lookup("mesh-pids", mkey,
-                                        lambda: self._pids_step(mesh), m)
-                    pids = pids_fn(stacked)
-                piece_cap = landed_rows = None
-                if n > 1 and shards[0].capacity >= \
-                        TWO_PHASE_MIN_SHARD_ROWS:
-                    with _phase("counts"):
-                        counts_fn = kc.lookup(
-                            "mesh-counts", mkey + (fold,),
-                            lambda: self._counts_step(mesh, n, fold), m)
-                        # row d: what device d receives from each peer
-                        counts = np.asarray(counts_fn(stacked, pids))
-                    landed_rows = counts.sum(axis=1).tolist()
-                    piece_cap = bucket_capacity(max(int(counts.max()), 1))
-                    if piece_cap >= shards[0].capacity:
-                        piece_cap = None  # padding wouldn't shrink
-                with _phase("collective"):
-                    step = kc.lookup(
-                        "mesh-exchange", mkey + (fold, piece_cap),
-                        lambda: self._build_step(mesh, n, fold,
-                                                 piece_capacity=piece_cap),
-                        m)
-                    out = step(stacked, pids)
-                # Where the collective left its output: one shard per
-                # mesh device — before _addressable_parts moves them all
-                # to device 0 for the single-process operator stream
-                # above. chip_smoke.py --mesh holds this to the mesh
-                # size.
-                m.add("meshExchanges", 1)
-                m.add("meshShardDevices", len(
-                    {s.device for s in
-                     tree_flatten(out)[0][0].addressable_shards}))
-                with _phase("land"):
-                    parts = _addressable_parts(out, n)
-                _count_put(m, "meshLandPuts")
-                if landed_rows is None:     # no counts pulled: deferred
-                    landed_rows = [p.num_rows for p in parts]
-                row_width = _row_width(shards[0])
-                _count_exchange(
-                    m, landed_rows,
-                    [next(iter(p.num_rows.devices())).id for p in parts],
-                    row_width,
-                    n * n * (piece_cap or shards[0].capacity) * row_width)
-            except Exception as err:
-                if not bool(ctx.conf.get(C.MESH_DEGRADE_ENABLED)):
-                    raise
-                self._degrade(ctx, err)
-                return None
+        rounds = _rounds(per_dev, MESH_ROUND_ROWS)
+        del per_dev
         # Durable stage outputs through the transport SPI: each logical
-        # partition's shard registers with the buffer catalog (bounded
+        # partition's shards register with the buffer catalog (bounded
         # by the memory ladder; CRC-framed once spilled to disk)
         # instead of pinning raw HBM in ctx.cache.
+        from spark_rapids_tpu import config as C
         from spark_rapids_tpu.parallel import transport as T
         sess = T.get_transport("mesh").open(
             ctx.conf, f"meshx-{id(self):x}", np_parts, owner=id(self),
             catalog=ctx.catalog, metrics=T.metrics_entry(ctx))
-        if fold == 1 and np_parts <= n:
-            for p in range(np_parts):
-                sess.write_shard(p, parts[p])
-        else:
-            # Unfold: split each device's received shard back into its
-            # logical partitions (the pids recompute is one murmur pass
-            # over the received rows — received shards are dense, so
-            # this is row-proportional, not capacity-proportional).
-            with _phase("unfold"):
-                for d in range(n):
-                    lo = d * fold
-                    cnt = min(np_parts - lo, fold)
-                    if cnt <= 0:
-                        continue
-                    shard = parts[d]
-                    shard_pids = self.partitioning.partition_ids(shard)
-                    live = shard.row_mask()
-                    for j in range(cnt):
-                        keep = (shard_pids == lo + j) & live
-                        sess.write_shard(lo + j, shard.compact(keep))
+        landed_rows, landed_on, widths, wire_bytes = [], [], [], 0
+        for k in range(len(rounds)):
+            # the round's batches leave the list as they go over: what
+            # device 0 holds is one round in flight and what has landed
+            batches, rounds[k] = rounds[k], None
+            with timed(m, "shuffleTime"):
+                try:
+                    parts, rows, width, wire = self._exchange_round(
+                        m, mesh, n, fold, k, batches)
+                except Exception as err:
+                    sess.invalidate()
+                    if not bool(ctx.conf.get(C.MESH_DEGRADE_ENABLED)):
+                        raise
+                    self._degrade(ctx, err)
+                    return None
+            del batches
+            landed_rows += rows
+            landed_on += [next(iter(p.num_rows.devices())).id
+                          for p in parts]
+            widths += [width] * len(parts)
+            wire_bytes += wire
+            self._write_round(sess, parts, n, np_parts, fold, k)
+        m.add("meshExchanges", 1)
+        _count_exchange(m, landed_rows, landed_on, widths, wire_bytes)
         sess.commit()
         ctx.cache[key] = sess
         return sess
+
+    def _exchange_round(self, m, mesh, n: int, fold: int, k: int,
+                        batches_per_dev: List[List[DeviceBatch]]):
+        """Round ``k``: its batches of each device as one shard, through
+        the collective, landed. Returns the landed shard of each device,
+        their live rows (ints where the counts matrix was pulled, else
+        device scalars), the row width and the bytes the collective was
+        shaped to move."""
+        from spark_rapids_tpu import faults
+        from spark_rapids_tpu.ops import kernel_cache as kc
+        faults.fault_point("mesh.exchange", owner=id(self))
+        with _phase("shard", k):
+            shards = _uniform_shards(batches_per_dev, self.schema)
+            stacked = M.shard_batches(mesh, shards)
+        _count_put(m, "meshHandoutPuts")
+        cap = shards[0].capacity
+        row_width = _row_width(shards[0])
+        del shards
+        m.add("meshRounds", 1)
+        _add_counters({"meshRounds": 1})
+        _raise_counter("meshRoundRowsMax", cap)
+        # Two-phase sizes-then-data (SURVEY §7 hard part 6): exchange
+        # per-destination COUNTS first (a (n,n) int32 collective + one
+        # host pull), size the data collective's static piece capacity
+        # to the observed max instead of the worst case — the default
+        # padding is an n-fold wire inflation at scale. n == 1 skips the
+        # phase: the collective moves nothing, so the counts sync could
+        # only cost.
+        mkey = self._mesh_key(mesh)
+        with _phase("pids", k):
+            pids_fn = kc.lookup("mesh-pids", mkey,
+                                lambda: self._pids_step(mesh), m)
+            pids = pids_fn(stacked)
+        piece_cap = landed_rows = None
+        if n > 1 and cap >= TWO_PHASE_MIN_SHARD_ROWS:
+            with _phase("counts", k):
+                counts_fn = kc.lookup(
+                    "mesh-counts", mkey + (fold,),
+                    lambda: self._counts_step(mesh, n, fold), m)
+                # row d: what device d receives from each peer
+                counts = np.asarray(counts_fn(stacked, pids))
+            landed_rows = counts.sum(axis=1).tolist()
+            piece_cap = bucket_capacity(max(int(counts.max()), 1))
+            if piece_cap >= cap:
+                piece_cap = None  # padding wouldn't shrink
+        with _phase("collective", k):
+            step = kc.lookup(
+                "mesh-exchange", mkey + (fold, piece_cap),
+                lambda: self._build_step(mesh, n, fold,
+                                         piece_capacity=piece_cap), m)
+            out = step(stacked, pids)
+        del stacked, pids
+        # Where the collective left its output: one shard per mesh
+        # device — before _addressable_parts moves them all to device 0
+        # for the single-process operator stream above. chip_smoke.py
+        # --mesh holds this to the mesh size.
+        if k == 0:
+            m.add("meshShardDevices", len(
+                {s.device for s in
+                 tree_flatten(out)[0][0].addressable_shards}))
+        with _phase("land", k):
+            parts = _addressable_parts(out, n)
+        _count_put(m, "meshLandPuts")
+        if landed_rows is None:     # no counts pulled: deferred
+            landed_rows = [p.num_rows for p in parts]
+        return (parts, landed_rows, row_width,
+                n * n * (piece_cap or cap) * row_width)
+
+    def _write_round(self, sess, parts, n: int, np_parts: int, fold: int,
+                     k: int) -> None:
+        """Round ``k``'s landed shards into the session, one more batch
+        of each logical partition."""
+        if fold == 1 and np_parts <= n:
+            for p in range(np_parts):
+                sess.write_shard(p, parts[p])
+            return
+        # Unfold: split each device's received shard back into its
+        # logical partitions (the pids recompute is one murmur pass over
+        # the received rows — received shards are dense, so this is
+        # row-proportional, not capacity-proportional).
+        with _phase("unfold", k):
+            for d in range(n):
+                lo = d * fold
+                cnt = min(np_parts - lo, fold)
+                if cnt <= 0:
+                    continue
+                shard = parts[d]
+                shard_pids = self.partitioning.partition_ids(shard)
+                live = shard.row_mask()
+                for j in range(cnt):
+                    keep = (shard_pids == lo + j) & live
+                    sess.write_shard(lo + j, shard.compact(keep))
 
     def execute_device(self, ctx, partition):
         sess = None

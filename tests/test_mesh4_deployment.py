@@ -431,7 +431,8 @@ def test_process_counters_lose_no_update_under_threads():
     def count():
         m = Metrics("MeshExchangeExec")
         for _ in range(each):
-            MX._count_exchange(m, [1, 2, 3, 4], [0, 0, 0, 0], 10, 1000)
+            MX._count_exchange(m, [1, 2, 3, 4], [0, 0, 0, 0], [10] * 4,
+                               1000)
         assert m.values["meshLiveBytes"] == each * 100
 
     def read():
